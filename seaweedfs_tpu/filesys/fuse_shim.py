@@ -28,6 +28,34 @@ from seaweedfs_tpu.util import wlog
 
 log = wlog.logger("fuse")
 
+# fuse_main_real installs its own SIGHUP/SIGINT/SIGTERM handlers and
+# ignores SIGPIPE for the life of the mount — and on the way out
+# fuse_remove_signal_handlers resets all four to SIG_DFL, for the whole
+# process. That silently drops Python's own handlers and, worse,
+# UN-ignores SIGPIPE (the interpreter starts with it ignored): the next
+# write to a socket whose peer has gone then kills the process without
+# a traceback. mount() therefore saves the four C-level dispositions
+# before the call and puts them back after it. sigaction through libc,
+# not signal.signal(): mount() may run on any thread.
+_FUSE_SIGNALS = (1, 2, 13, 15)      # SIGHUP, SIGINT, SIGPIPE, SIGTERM
+_SIGACTION_BYTES = 256              # >= sizeof(struct sigaction) anywhere
+
+
+def _save_signal_dispositions() -> dict:
+    libc = ctypes.CDLL(None, use_errno=True)
+    saved = {}
+    for sig in _FUSE_SIGNALS:
+        buf = ctypes.create_string_buffer(_SIGACTION_BYTES)
+        if libc.sigaction(sig, None, buf) == 0:
+            saved[sig] = buf
+    return saved
+
+
+def _restore_signal_dispositions(saved: dict) -> None:
+    libc = ctypes.CDLL(None, use_errno=True)
+    for sig, buf in saved.items():
+        libc.sigaction(sig, buf, None)
+
 
 def _find_libfuse() -> Optional[str]:
     name = ctypes.util.find_library("fuse")
@@ -536,9 +564,13 @@ class FuseMount:
             args += [b"-o", b"allow_other"]
         argv = (ctypes.c_char_p * len(args))(*args)
         log.info("mounting %s at %s", self.fsname, self.mountpoint)
-        self._exit_code = self.lib.fuse_main_real(
-            len(args), argv, ctypes.byref(self.ops),
-            ctypes.sizeof(self.ops), None)
+        saved = _save_signal_dispositions()
+        try:
+            self._exit_code = self.lib.fuse_main_real(
+                len(args), argv, ctypes.byref(self.ops),
+                ctypes.sizeof(self.ops), None)
+        finally:
+            _restore_signal_dispositions(saved)
         log.info("unmounted %s (exit %s)", self.mountpoint,
                  self._exit_code)
         return self._exit_code

@@ -1,59 +1,86 @@
 """ctypes bindings for the C++ GF(2^8) RS kernel (CPU baseline).
 
-The shared library is built by `make -C seaweedfs_tpu/native` (see
-Makefile); when absent, callers fall back to the numpy path in
-seaweedfs_tpu/ops/gf256.py.
+The shared library is a build artifact, never checked in: the first use
+in a process builds it from rs_cpu.cpp with `make` on the machine it
+runs on (or finds it already newer than its source), then loads it. A
+build or load that fails RAISES — with the compiler's own message —
+instead of quietly leaving every caller on the pure-Python fallbacks
+(numpy GF at a fraction of the speed, a byte-loop CRC32C). The numpy
+path stays available to callers that ask for it by name
+(`backend="numpy"`); `available()` is the question "can this host use
+the native library", asked by `backend="auto"` and the benchmarks.
 """
 
 from __future__ import annotations
 
 import ctypes
 import os
+import subprocess
+import threading
 
 import numpy as np
 
-_LIB_PATH = os.path.join(os.path.dirname(__file__), "librs_cpu.so")
+_SRC_DIR = os.path.dirname(os.path.abspath(__file__))
+_SRC_PATH = os.path.join(_SRC_DIR, "rs_cpu.cpp")
+_LIB_PATH = os.path.join(_SRC_DIR, "librs_cpu.so")
 _lib = None
-_build_attempted = False
+_load_error: "NativeUnavailable | None" = None
+_load_lock = threading.Lock()
 
 
-def _try_build() -> None:
-    """Build librs_cpu.so from source on first use if it is missing.
+class NativeUnavailable(RuntimeError):
+    """librs_cpu.so could not be built or loaded on this machine."""
 
-    The .so is not checked in (it's a build artifact); the image always
-    has g++, so a fresh checkout self-builds the native CRC/GF kernels
-    instead of silently degrading to the pure-Python fallbacks. Build
-    failures are swallowed — callers fall back as before.
-    """
-    global _build_attempted
-    if _build_attempted:
-        return
-    _build_attempted = True
-    src_dir = os.path.dirname(__file__)
-    if not os.path.exists(os.path.join(src_dir, "rs_cpu.cpp")):
-        return
-    import subprocess
+
+def stale() -> bool:
+    """True when the library is missing or older than its source."""
     try:
-        subprocess.run(
-            ["make", "-C", src_dir, "-s"],
-            check=False, timeout=120,
-            stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL)
-    # lint: swallow-ok(optional native build; loader falls back to numpy)
-    except Exception:
-        pass
+        return os.path.getmtime(_LIB_PATH) < os.path.getmtime(_SRC_PATH)
+    except OSError:
+        return not os.path.exists(_LIB_PATH)
+
+
+def ensure_built() -> str:
+    """Build librs_cpu.so from rs_cpu.cpp unless an up-to-date one is
+    there. Raises NativeUnavailable with make's output when the build
+    tools are missing or the compile fails. Returns the library path."""
+    if not stale():
+        return _LIB_PATH
+    try:
+        proc = subprocess.run(
+            ["make", "-C", _SRC_DIR, "-s"], timeout=300,
+            stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)
+    except (OSError, subprocess.TimeoutExpired) as e:
+        raise NativeUnavailable(
+            f"cannot build {_LIB_PATH}: running make failed: {e}") from e
+    if proc.returncode != 0 or not os.path.exists(_LIB_PATH):
+        raise NativeUnavailable(
+            f"cannot build {_LIB_PATH}: make exited {proc.returncode}:\n"
+            f"{proc.stdout.strip()}")
+    return _LIB_PATH
 
 
 def _load():
-    global _lib
-    if _lib is None and not os.path.exists(_LIB_PATH):
-        _try_build()
-    if _lib is None and os.path.exists(_LIB_PATH):
+    """The loaded library; builds it first if needed. A failure is
+    remembered and re-raised on every later call — one loud error per
+    caller, not one silent degradation per process."""
+    global _lib, _load_error
+    if _lib is not None:
+        return _lib
+    with _load_lock:
+        if _lib is not None:
+            return _lib
+        if _load_error is not None:
+            raise _load_error
         try:
-            lib = ctypes.CDLL(_LIB_PATH)
-        except OSError:
-            # e.g. another process is mid-build; fall back this call,
-            # retry on the next one
-            return None
+            lib = ctypes.CDLL(ensure_built())
+        except NativeUnavailable as e:
+            _load_error = e
+            raise
+        except OSError as e:
+            _load_error = NativeUnavailable(
+                f"cannot load {_LIB_PATH}: {e}")
+            raise _load_error from e
         lib.gf_linear.restype = None
         lib.gf_linear.argtypes = [
             ctypes.POINTER(ctypes.c_uint8),  # matrix [out, k]
@@ -76,14 +103,25 @@ def _load():
 
 
 def available() -> bool:
-    return _load() is not None
+    """Can this host use the native library? Builds it on first ask;
+    False (never an exception) when it cannot be built or loaded —
+    the reason is in load_error()."""
+    try:
+        _load()
+    except NativeUnavailable:
+        return False
+    return True
+
+
+def load_error() -> str:
+    """Why available() is False ('' when the library loaded)."""
+    with _load_lock:
+        return str(_load_error) if _load_error is not None else ""
 
 
 def apply_matrix(matrix: np.ndarray, shards: np.ndarray) -> np.ndarray:
     """matrix [O, K] uint8 x shards [..., K, N] uint8 -> [..., O, N]."""
     lib = _load()
-    if lib is None:
-        raise RuntimeError("librs_cpu.so not built")
     matrix = np.ascontiguousarray(matrix, dtype=np.uint8)
     shards = np.ascontiguousarray(shards, dtype=np.uint8)
     o, k = matrix.shape
@@ -106,12 +144,9 @@ def apply_matrix(matrix: np.ndarray, shards: np.ndarray) -> np.ndarray:
 
 
 def crc32(data, value: int = 0) -> int:
-    """IEEE CRC32 (zlib-compatible) of a bytes-like; native if built."""
+    """IEEE CRC32 (zlib-compatible) of a bytes-like."""
     lib = _load()
     buf = np.frombuffer(memoryview(data), dtype=np.uint8)
-    if lib is None:
-        import zlib
-        return zlib.crc32(buf, value) & 0xFFFFFFFF
     if buf.size == 0:
         return value
     return int(lib.crc32_ieee(
@@ -120,37 +155,12 @@ def crc32(data, value: int = 0) -> int:
         ctypes.c_longlong(buf.size)))
 
 
-_CRC32C_TABLE = None
-
-
-def _crc32c_py(buf: np.ndarray, value: int) -> int:
-    global _CRC32C_TABLE
-    if _CRC32C_TABLE is None:
-        tab = np.zeros(256, dtype=np.uint32)
-        for i in range(256):
-            c = i
-            for _ in range(8):
-                c = (0x82F63B78 ^ (c >> 1)) if (c & 1) else (c >> 1)
-            tab[i] = c
-        _CRC32C_TABLE = tab
-    crc = (~value) & 0xFFFFFFFF
-    tab = _CRC32C_TABLE
-    for b in buf.tobytes():
-        crc = int(tab[(crc ^ b) & 0xFF]) ^ (crc >> 8)
-    return (~crc) & 0xFFFFFFFF
-
-
 _U8P = ctypes.POINTER(ctypes.c_uint8)
 
 
 def crc32c(data, value: int = 0) -> int:
-    """Castagnoli CRC32 — the needle checksum flavor; native if built."""
+    """Castagnoli CRC32 — the needle checksum flavor."""
     lib = _load()
-    if lib is None:
-        buf = np.frombuffer(memoryview(data), dtype=np.uint8)
-        if buf.size == 0:
-            return value
-        return _crc32c_py(buf, value)
     # bytes fast path: c_char_p wraps without copying, skipping the
     # numpy round trip (~2x cheaper per call — it's on the per-needle
     # write path)

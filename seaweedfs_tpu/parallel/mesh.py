@@ -79,12 +79,7 @@ def sharded_encode(mesh: Mesh, data: np.ndarray) -> jax.Array:
 
 @functools.lru_cache(maxsize=32)
 def _rotate_fn(mesh: Mesh, shift: int):
-    try:
-        from jax import shard_map
-    except ImportError:
-        # moved between jax versions: the CPU image's 0.4.x keeps it
-        # under experimental; newer jax exports it at top level
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     dp = mesh.shape["dp"]
     perm = [(i, (i + shift) % dp) for i in range(dp)]

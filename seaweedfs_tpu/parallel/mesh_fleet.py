@@ -206,7 +206,7 @@ def _gf_local2d(m2, block):
 def _shard_mapped(mesh, fn, in_specs, out_specs):
     """shard_map fn over the mesh, P('dp', None, 'sp') for bucket
     arrays ('data'), P() for replicated matrices ('rep')."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     lut = {"data": P("dp", None, "sp"), "rep": P(), "dp": P("dp")}
@@ -414,9 +414,11 @@ class _JaxDispatch:
     def __init__(self, mesh, op: str):
         import jax
 
-        from seaweedfs_tpu.ops.rs_kernel import parity_m2_bits
+        from seaweedfs_tpu.ops.rs_kernel import (note_placement,
+                                                 parity_m2_bits)
 
         self._jax = jax
+        self._note_placement = note_placement
         self._mesh = mesh
         self._op = op
         self._data_spec, _ = _shardings(mesh)
@@ -427,6 +429,7 @@ class _JaxDispatch:
     def __call__(self, bucket: np.ndarray, aux=None):
         with _fleet._StageTimer("upload", bytes=bucket.nbytes):
             x = self._jax.device_put(bucket, self._data_spec)
+            self._note_placement(x)
             if self._op == "verify":
                 stored = self._jax.device_put(aux[0], self._data_spec)
         if self._op == "verify":

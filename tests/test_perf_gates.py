@@ -41,70 +41,94 @@ def test_data_plane_floor(tmp_path):
     assert read_rps >= 1200, f"read plane regressed: {read_rps:.0f} req/s"
 
 
-def test_ec_kernel_floor():
-    """EC encode kernel floors.
+def test_ec_kernel_floor(monkeypatch):
+    """The host EC codec is the native library, not a quiet stand-in.
 
-    Always asserts the host backend: the native AVX2 kernel measures
-    1.2-1.5 GB/s here and the numpy fallback ~0.1 GB/s, so a 0.25 GB/s
-    floor catches a silent fallback. When a real accelerator is
-    reachable (not the CPU-forced test env), additionally asserts the
-    on-device chained rate ≥ 10 GB/s (measured ~38; the north-star
-    ratio lives in bench.py, which the driver runs on TPU directly).
+    The regression class this gate exists for is a silent fall from
+    the C++ kernel to the numpy path (an order of magnitude slower).
+    Since PR 22 a backend asked for by name is used or raises, so that
+    is asserted as counts (ROADMAP C8): this host builds the library,
+    `backend="native"` reaches `rs_native.apply_matrix` exactly once
+    per encode, `backend="auto"` resolves to it too, and the bytes
+    equal numpy's. The wall-clock floor stays only as a loose guard
+    against a pure-Python regression (0.10-0.13 GB/s measured for the
+    native kernel under the six-worker suite on a loaded machine, 1.2+
+    idle). Device rates belong to the benchmark (`bench.py`), which
+    fails without a chip; none is asserted here.
     """
     from seaweedfs_tpu.native import rs_native
+    from seaweedfs_tpu.ops import gf256
     from seaweedfs_tpu.ops.rs_code import DATA_SHARDS, ReedSolomon
 
+    assert rs_native.available(), rs_native.load_error()
+    calls = []
+    real = rs_native.apply_matrix
+
+    def counted(matrix, shards):
+        calls.append(shards.shape)
+        return real(matrix, shards)
+
+    monkeypatch.setattr(rs_native, "apply_matrix", counted)
     data = np.random.default_rng(3).integers(
         0, 256, (DATA_SHARDS, 4 << 20), dtype=np.uint8)
-    backend = "native" if rs_native.available() else "numpy"
-    rs = ReedSolomon(backend=backend)
+    rs = ReedSolomon(backend="native")
     rs.encode(data[:, : 1 << 16])  # warm
     t0 = time.perf_counter()
-    rs.encode(data)
+    parity = rs.encode(data)
     dt = time.perf_counter() - t0
+    assert calls == [(DATA_SHARDS, 1 << 16), (DATA_SHARDS, 4 << 20)]
+    ReedSolomon(backend="auto").encode(data[:, : 1 << 16])
+    assert len(calls) == 3, "backend='auto' left the native library"
+    sample = slice(0, 1 << 14)
+    assert np.array_equal(
+        parity[:, sample],
+        gf256.gf_linear_numpy(rs.matrix[DATA_SHARDS:], data[:, sample]))
     gbps = data.nbytes / (1 << 30) / dt
-    if backend == "native":
-        # native measures 1.2-1.5 GB/s idle but as low as ~0.22 under
-        # heavy concurrent VM load; the numpy fallback is ~0.1 — 0.15
-        # sits between, catching the fallback without flaking on load
-        assert gbps >= 0.15, \
-            f"native EC kernel regressed: {gbps:.2f} GB/s"
-    else:
-        # no native lib in this environment: still catch a pure-python
-        # regression of the numpy path
-        assert gbps >= 0.02, \
-            f"numpy EC kernel regressed: {gbps:.3f} GB/s"
+    assert gbps >= 0.02, f"native EC kernel regressed: {gbps:.3f} GB/s"
 
-    if os.environ.get("JAX_PLATFORMS", "cpu") not in ("cpu", ""):
-        # real accelerator reachable (the TPU-attached bench runs, not
-        # the CPU-forced test suite): hold the device floor too
-        import jax
-        rs_dev = ReedSolomon(backend="jax")
-        x = jax.device_put(data)
-        rs_dev.encode(np.asarray(data[:, : 1 << 16]))  # compile
-        t0 = time.perf_counter()
-        out = rs_dev.encode(x)
-        jax.block_until_ready(out)
-        dt = time.perf_counter() - t0
-        dev_gbps = data.nbytes / (1 << 30) / dt
-        assert dev_gbps >= 10.0, \
-            f"device EC kernel regressed: {dev_gbps:.1f} GB/s"
+
+def _fleet_stage_threads(run):
+    """Run `run()` with the tracer on; returns {stage span name:
+    [thread name of each span]} for the fleet.* stage spans."""
+    from seaweedfs_tpu.stats import trace
+    trace.enable()
+    trace.clear()
+    try:
+        run()
+        events = trace.chrome_trace()["traceEvents"]
+    finally:
+        trace.disable()
+        trace.clear()
+    names = {e["tid"]: e["args"]["name"] for e in events
+             if e["ph"] == "M" and e["name"] == "thread_name"}
+    stages = {}
+    for e in events:
+        if e["ph"] == "X" and e["name"].startswith("fleet."):
+            stages.setdefault(e["name"], []).append(
+                names.get(e["tid"], "?"))
+    return stages
 
 
 def test_fleet_batched_encode_floor(tmp_path):
     """Cross-volume fleet encode vs serial per-volume encode (8×4MB,
-    native backend, best-of-3 each to shave VM-scheduler noise).
+    native backend): the fleet scheduler keeps its overlap.
 
-    The regression class: the fleet scheduler losing its overlap —
-    reader pool gone synchronous, writer lanes collapsed to one
-    serialized thread, encode pool bypassed. The achievable speedup is
-    core-bound: on ≥8 cores the reader/encoder/writer pools genuinely
-    run beside each other (target ≥1.5×); on the 2-core CI VM the
-    native kernel is memory-bandwidth-bound and the measured band is
-    only 0.9-1.3× (serial itself swings ±2× under load), so the floors
-    step down with cpu_count — loose on small VMs, real on big iron —
-    per the VM-load tolerance precedent on the kernel floor below.
+    The regression class: the reader pool gone synchronous, writer
+    lanes collapsed to one serialized thread, the encode pool bypassed.
+    That is a fact about WHERE each stage runs, so it is asserted as
+    counts from the scheduler's own stage spans (ROADMAP C8: rates
+    belong to the benchmark, counts to tier-1): every read on a
+    reader-pool thread, every host RS compute on an encode-pool
+    thread, the retire on its own thread, and the eight volumes' writes
+    on ALL the writer lanes (volume tag % lanes is deterministic) —
+    none of it on the calling thread. The fused-vs-serial wall-clock
+    ratio this test used to gate (1.5x "at >=8 cores", never met on
+    eight loaded cores: 0.22-1.34x measured under the six-worker suite)
+    is `bench.py`'s fleet sweep; here it is printed, not asserted.
+    Shard bytes stay identical to the serial path.
     """
+    import threading
+
     from seaweedfs_tpu.ec import encoder as enc
     from seaweedfs_tpu.ec import fleet
     from seaweedfs_tpu.native import rs_native
@@ -124,23 +148,42 @@ def test_fleet_batched_encode_floor(tmp_path):
         os.link(base + ".dat", twin + ".dat")
         serial_bases.append(twin)
 
-    serial_s, fused_s = [], []
-    for _ in range(3):  # alternate so load spikes hit both paths
-        t0 = time.perf_counter()
-        for base in serial_bases:
-            enc.write_ec_files(base, backend=backend)
-        serial_s.append(time.perf_counter() - t0)
-        t0 = time.perf_counter()
-        fleet.fleet_write_ec_files(fleet_bases, backend=backend)
-        fused_s.append(time.perf_counter() - t0)
-    speedup = min(serial_s) / min(fused_s)
+    t0 = time.perf_counter()
+    for base in serial_bases:
+        enc.write_ec_files(base, backend=backend)
+    serial_s = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    stages = _fleet_stage_threads(
+        lambda: fleet.fleet_write_ec_files(fleet_bases, backend=backend))
+    fused_s = time.perf_counter() - t0
+    print(f"fleet fused-vs-serial {serial_s / fused_s:.2f}x "
+          f"(serial={serial_s:.3f}s fused={fused_s:.3f}s, tracer on; "
+          "not a gate)")
 
-    ncpu = os.cpu_count() or 1
-    floor = 1.5 if ncpu >= 8 else (1.1 if ncpu >= 4 else 0.6)
-    assert speedup >= floor, \
-        f"fleet batched encode regressed: {speedup:.2f}x fused-vs-serial " \
-        f"(floor {floor}x at {ncpu} cpus; serial={min(serial_s):.3f}s " \
-        f"fused={min(fused_s):.3f}s)"
+    me = threading.current_thread().name
+    # one 10MB-row span per 4MB volume: 8 reads, 8 RS computes
+    assert len(stages["fleet.read"]) == 8
+    assert all(t.startswith("fleet-read") for t in stages["fleet.read"]), \
+        f"reads left the reader pool: {sorted(set(stages['fleet.read']))}"
+    assert len(stages["fleet.rs"]) == 8
+    assert all(t.startswith("fleet-encode") for t in stages["fleet.rs"]), \
+        f"RS compute left the encode pool: {sorted(set(stages['fleet.rs']))}"
+    assert set(stages["fleet.retire"]) == {"fleet-retire"}
+    # the set-up span (creating the 14 output files) is the caller's;
+    # every data/parity write belongs to a lane
+    lane_writes = [t for t in stages["fleet.write"] if t != me]
+    assert len(lane_writes) == 16      # 8 data-shard + 8 parity spans
+    assert set(lane_writes) == {f"fleet-write-{i}"
+                                for i in range(fleet.FLEET_WRITERS)}, \
+        f"writer lanes collapsed: {sorted(set(lane_writes))}"
+    assert stages["fleet.write"].count(me) == 1
+    for stage in ("fleet.read", "fleet.rs", "fleet.retire"):
+        assert me not in stages[stage], f"{stage} ran on the caller"
+    for fb, sb in zip(fleet_bases, serial_bases):
+        for sid in range(14):
+            with open(enc.shard_file_name(fb, sid), "rb") as f1, \
+                    open(enc.shard_file_name(sb, sid), "rb") as f2:
+                assert f1.read() == f2.read(), (fb, sid)
 
 
 def test_tracing_disabled_overhead(tmp_path):
@@ -149,12 +192,15 @@ def test_tracing_disabled_overhead(tmp_path):
     Two gates. Micro: the disabled span() fast path is one flag check
     returning a shared no-op — 200k calls must stay far under real
     span cost (generous 5 us/call ceiling vs ~0.1 us measured).
-    Macro: the 8-volume fleet encode with the tracer merely present-
-    but-disabled (today's default — the PR 1 pipeline plus dormant
-    instrumentation) must stay within noise of the same encode with
-    instrumentation stubbed out entirely (the PR 1 baseline shape),
-    best-of-3 alternated per the VM-load methodology of the fleet
-    floor above."""
+    Macro, as counts (ROADMAP C8 — the wall-clock ratio of two fleet
+    encodes this used to gate swung past its 1.6x bound on a loaded
+    machine with nothing changed): over an 8-volume fleet encode with
+    the tracer present-but-disabled, (a) every stage interval gets the
+    ONE shared no-op span — no Span object is allocated, nothing
+    reaches the ring; and (b) the number of stage intervals stays
+    per-chunk — the regression class is instrumentation gone
+    accidentally per-row or per-byte, which multiplies this count by
+    the rows (8 per volume here) or more."""
     from seaweedfs_tpu.ec import fleet
     from seaweedfs_tpu.native import rs_native
     from seaweedfs_tpu.stats import trace
@@ -166,65 +212,52 @@ def test_tracing_disabled_overhead(tmp_path):
     per_call = (time.perf_counter() - t0) / 200_000
     assert per_call < 5e-6, \
         f"disabled span() costs {per_call * 1e6:.2f} us/call"
+    assert trace.span("hot", vid=1) is trace.NOOP
 
     backend = "native" if rs_native.available() else "numpy"
     rng = np.random.default_rng(17)
     block = rng.integers(0, 256, 1 << 20, dtype=np.uint8).tobytes()
-    instrumented_bases, stubbed_bases = [], []
+    bases = []
     for v in range(8):
         base = str(tmp_path / f"i{v}")
         with open(base + ".dat", "wb") as f:
             for _ in range(8):
                 f.write(block)
-        instrumented_bases.append(base)
-        twin = str(tmp_path / f"b{v}")
-        os.link(base + ".dat", twin + ".dat")
-        stubbed_bases.append(twin)
-
-    class _NullTimer:
-        def __init__(self, *a, **kw):
-            pass
-
-        def __enter__(self):
-            return self
-
-        def __exit__(self, *exc):
-            return False
-
-        def token(self):
-            return None
+        bases.append(base)
 
     real_timer = fleet._StageTimer
+    made = []
 
-    def run_instrumented():
-        t0 = time.perf_counter()
-        fleet.fleet_write_ec_files(instrumented_bases, backend=backend)
-        instrumented_s.append(time.perf_counter() - t0)
+    class _CountingTimer(real_timer):
+        __slots__ = ()
 
-    def run_stubbed():
-        fleet._StageTimer = _NullTimer
-        try:
-            t0 = time.perf_counter()
-            fleet.fleet_write_ec_files(stubbed_bases, backend=backend)
-            stubbed_s.append(time.perf_counter() - t0)
-        finally:
-            fleet._StageTimer = real_timer
+        def __init__(self, stage, *a, **kw):
+            super().__init__(stage, *a, **kw)
+            made.append((stage, self._span))
 
-    instrumented_s, stubbed_s = [], []
-    for rep in range(3):  # alternate ORDER too: the first run of a
-        # pair eats page-cache warmup and any load spike's leading edge
-        first, second = (run_instrumented, run_stubbed) if rep % 2 \
-            else (run_stubbed, run_instrumented)
-        first()
-        second()
-    ratio = min(instrumented_s) / min(stubbed_s)
-    # within noise: single-shot fleet timings swing +-50% on shared
-    # VMs even best-of-3, so the gate catches only a real regression
-    # class (per-chunk instrumentation gone accidentally per-row/byte)
-    assert ratio <= 1.6, \
-        f"tracing-disabled fleet encode {ratio:.2f}x slower than " \
-        f"uninstrumented (instrumented={min(instrumented_s):.3f}s " \
-        f"stubbed={min(stubbed_s):.3f}s)"
+    trace.clear()
+    fleet._StageTimer = _CountingTimer
+    try:
+        fleet.fleet_write_ec_files(bases, backend=backend)
+    finally:
+        fleet._StageTimer = real_timer
+    assert made, "the fleet encode closed no stage interval at all"
+    assert all(span is trace.NOOP for _, span in made), \
+        "a disabled tracer still allocated spans"
+    assert trace.spans() == [], "a disabled tracer recorded spans"
+    # 8 volumes x 8MB, one 10MB row each: per volume one read, one RS
+    # compute, a data and a parity write; one dispatch + retire per
+    # fused batch (<= 8), one set-up write. Per-row instrumentation
+    # would multiply the per-volume terms by 8.
+    per_stage = {}
+    for stage, _ in made:
+        per_stage[stage] = per_stage.get(stage, 0) + 1
+    assert per_stage.get("read") == 8, per_stage
+    assert per_stage.get("rs") == 8, per_stage
+    assert per_stage.get("write") == 17, per_stage
+    assert 1 <= per_stage.get("dispatch", 0) <= 8, per_stage
+    assert per_stage.get("retire") == per_stage.get("dispatch"), per_stage
+    assert len(made) <= 8 + 8 + 17 + 8 + 8, per_stage
 
 
 def test_storage_engine_microbench(tmp_path):

@@ -113,18 +113,25 @@ def test_jax_vs_numpy_large_random_matrices():
         assert np.array_equal(out, gf256.gf_linear_numpy(m, data))
 
 
-def test_pallas_backend_byte_equality():
-    """The opt-in Pallas codec (interpret mode off-TPU) matches numpy
-    byte-for-byte on encode and reconstruct, including odd lane counts
-    that exercise the 128-lane padding."""
+def test_pallas_backend_byte_equality(monkeypatch):
+    """The opt-in Pallas codec matches numpy byte-for-byte on encode
+    and reconstruct, including odd lane counts that exercise the
+    128-lane padding. Off the chip the program itself never interprets
+    (test_pallas_backend_raises_off_chip), so THIS test asks for the
+    Pallas interpreter: a test-side wrapper around gf_linear_pallas."""
+    import functools
+
     import numpy as np
 
+    from seaweedfs_tpu.ops import rs_pallas
     from seaweedfs_tpu.ops.rs_code import ReedSolomon
 
+    monkeypatch.setattr(
+        rs_pallas, "gf_linear_pallas",
+        functools.partial(rs_pallas.gf_linear_pallas, interpret=True))
     rng = np.random.default_rng(5)
     ref = ReedSolomon(backend="numpy")
     pal = ReedSolomon(backend="pallas")
-    from seaweedfs_tpu.ops import rs_pallas
     lane_cases = (128, 1000, 4096 + 17,
                   rs_pallas.TILE + 257)   # crosses a tile boundary
     for lanes in lane_cases:
@@ -140,3 +147,38 @@ def test_pallas_backend_byte_equality():
     np.testing.assert_array_equal(
         pal.reconstruct_some(present, [1, 5, 11, 13], src),
         ref.reconstruct_some(present, [1, 5, 11, 13], src))
+
+
+def test_pallas_backend_raises_off_chip():
+    """No quiet interpret mode: off the TPU the Pallas codec's entry
+    compiles for the chip or raises — it never falls back to the
+    interpreter (or to another backend) by itself."""
+    import jax
+
+    assert jax.default_backend() != "tpu"
+    data = np.zeros((10, 256), dtype=np.uint8)
+    with pytest.raises(Exception) as ei:
+        ReedSolomon(backend="pallas").encode(data)
+    assert "interpret" in str(ei.value).lower()
+    # asked for by the caller, the interpreter still works
+    from seaweedfs_tpu.ops import rs_pallas
+    out = rs_pallas.gf_linear_pallas(
+        ReedSolomon().matrix[10:], data, interpret=True)
+    assert np.asarray(out).shape == (4, 256)
+
+
+def test_named_backend_is_never_substituted(monkeypatch):
+    """backend="native" with no library raises (NativeUnavailable);
+    only "auto" may settle for numpy."""
+    from seaweedfs_tpu.native import rs_native
+
+    def broken():
+        raise rs_native.NativeUnavailable("no g++ on this machine")
+
+    monkeypatch.setattr(rs_native, "_load", broken)
+    data = np.arange(10 * 64, dtype=np.uint8).reshape(10, 64)
+    with pytest.raises(rs_native.NativeUnavailable):
+        ReedSolomon(backend="native").encode(data)
+    assert not rs_native.available()
+    want = gf256.gf_linear_numpy(ReedSolomon().matrix[10:], data)
+    assert np.array_equal(ReedSolomon(backend="auto").encode(data), want)

@@ -500,9 +500,22 @@ def test_volume_move_preserves_readonly(cluster, shell):
     # about the moved copy can lag the VolumeDelete on src; reading
     # before the master catches up sees "no locations" (30s: the 5s
     # pulse can slip several periods when the single core is saturated)
-    cluster.wait_for(
-        lambda: operations.lookup(cluster.master.url, vid) == [dst],
-        timeout=30, what="master sees the move")
+    seen = []
+
+    # the shared module cluster may have handed out a REPLICATED volume
+    # (earlier tests leave some): the other replicas stay where they
+    # are, so the move is "dst in, src out", not "exactly [dst]"
+    others = [u for u in locs if u != src]
+
+    def moved():
+        seen[:] = operations.lookup(cluster.master.url, vid)
+        return sorted(seen) == sorted(others + [dst])
+    try:
+        cluster.wait_for(moved, timeout=30, what="master sees the move")
+    except TimeoutError as e:
+        raise TimeoutError(
+            f"{e}: lookup says {seen}, want {others + [dst]} "
+            f"(moved from {src})") from None
     assert operations.download(cluster.master.url, fid) == b"sealed blob"
 
 
