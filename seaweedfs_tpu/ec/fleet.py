@@ -45,7 +45,6 @@ import functools
 import os
 import queue
 import threading
-import time
 from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
@@ -62,7 +61,8 @@ from seaweedfs_tpu.stats import trace
 from seaweedfs_tpu.stats.metrics import (
     FleetDispatchBatchHistogram, FleetDispatchedBytesCounter,
     FleetMeshFallbacksCounter, FleetReaderQueueGauge,
-    FleetStageSecondsHistogram, FleetWriterBacklogGauge)
+    FleetStageSecondsHistogram, FleetWaitSecondsHistogram,
+    FleetWriterBacklogGauge)
 
 
 def mesh_fleet_or_none():
@@ -106,36 +106,28 @@ _LANE_QUEUE = 4
 # lock per call, and a stage interval closes for every chunk-sized
 # unit of work.
 _STAGE_HIST = {s: FleetStageSecondsHistogram.labels(s)
-               for s in ("read", "dispatch", "rs", "retire", "write",
-                         "verify", "upload")}
+               for s in ("read", "pack", "dispatch", "rs", "retire",
+                         "write", "verify", "upload")}
+_WAIT_HIST = {on: FleetWaitSecondsHistogram.labels(on)
+              for on in ("reader", "retire_slot", "lane_from_pack",
+                         "lane_from_retire")}
 
 
-class _StageTimer:
-    """One pipeline-stage interval: always observed into the per-stage
-    latency histogram, and additionally recorded as a trace span when
-    tracing is enabled (parented across threads via a handoff token).
-    Span allocation is gated on the trace flag so the disabled path
-    costs one histogram observe per chunk-sized unit of work."""
+class _StageTimer(trace.PhaseTimer):
+    """One pipeline-stage interval: the shared phase timer under the
+    stage's histogram child and the span name `fleet.<stage>`."""
 
-    __slots__ = ("_hist", "_span", "_t0")
+    __slots__ = ()
 
     def __init__(self, stage: str, parent: Optional[int] = None, **tags):
-        self._hist = _STAGE_HIST[stage]
-        self._span = trace.span("fleet." + stage, parent=parent, **tags) \
-            if trace.is_enabled() else trace.NOOP
+        super().__init__(_STAGE_HIST[stage], "fleet." + stage, parent,
+                         **tags)
 
-    def __enter__(self) -> "_StageTimer":
-        self._span.__enter__()
-        self._t0 = time.perf_counter()
-        return self
 
-    def __exit__(self, *exc) -> bool:
-        self._hist.observe(time.perf_counter() - self._t0)
-        return self._span.__exit__(*exc)
-
-    def token(self) -> Optional[int]:
-        """Handoff token of the underlying span (None when disabled)."""
-        return self._span.token()
+def _waiting(on: str) -> trace.PhaseTimer:
+    """Timer around one place where a scheduler thread blocks on
+    another (span `fleet.wait.<on>`)."""
+    return trace.PhaseTimer(_WAIT_HIST[on], "fleet.wait." + on)
 
 
 class TaggedPipeline:
@@ -178,8 +170,10 @@ class TaggedPipeline:
         self._retirer.start()
 
     def _put_lane(self, tag: int, fn: Callable[[], None],
-                  token: Optional[int],
+                  token: Optional[int], on: str,
                   timeout_s: Optional[float] = None) -> None:
+        """`on` names the caller's thread for the wait metric:
+        lane_from_pack or lane_from_retire."""
         lane = tag % len(self._lanes)
         # inc/dec deltas, not set(qsize): several schedulers run
         # concurrently (mesh sharding, parallel generate RPCs) and
@@ -187,7 +181,8 @@ class TaggedPipeline:
         # rather than last-write-wins one scheduler's view
         self._lane_gauges[lane].inc()
         try:
-            self._lanes[lane].put((fn, token), timeout=timeout_s)
+            with _waiting(on):
+                self._lanes[lane].put((fn, token), timeout=timeout_s)
         except queue.Full:
             self._lane_gauges[lane].dec()  # never entered the lane
             raise
@@ -199,7 +194,8 @@ class TaggedPipeline:
         queue.Full instead of blocking the caller behind a wedged
         writer — same stall contract as submit()."""
         self._raise_pending()
-        self._put_lane(tag, fn, trace.handoff(), timeout_s)
+        self._put_lane(tag, fn, trace.handoff(), "lane_from_pack",
+                       timeout_s)
 
     def submit(self, handle,
                tagged: Sequence[Tuple[int, Callable]],
@@ -211,8 +207,9 @@ class TaggedPipeline:
         dispatch-stall detection (parallel/mesh_fleet.py) — instead of
         blocking forever behind a wedged retire."""
         self._raise_pending()
-        self._retireq.put((handle, list(tagged), trace.handoff()),
-                          timeout=timeout_s)
+        item = (handle, list(tagged), trace.handoff())
+        with _waiting("retire_slot"):
+            self._retireq.put(item, timeout=timeout_s)
 
     def _retire_loop(self) -> None:
         while True:
@@ -237,13 +234,15 @@ class TaggedPipeline:
                 # resolve — for the jax backend this wait IS the device
                 # time (block_until_ready), for host backends the encode
                 # pool's compute; the lane puts after it are writer-side
-                # backpressure, also this stage's problem
+                # backpressure, also this stage's problem (told apart
+                # from the device by fleet_wait_seconds{lane_from_retire}
+                # and rs_dispatch_seconds{wait,fetch,unstage})
                 with _StageTimer("retire", parent=token,
                                  spans=len(tagged)) as st:
                     outs = handle.result()
                     for (tag, fn), out in zip(tagged, outs):
                         self._put_lane(tag, functools.partial(fn, out),
-                                       st.token())
+                                       st.token(), "lane_from_retire")
             except BaseException as e:  # surfaced on submit/drain
                 if self._exc is None:
                     self._exc = e
@@ -330,8 +329,9 @@ class _Dispatcher:
         if _failpoint._armed:
             _failpoint.hit("fleet.dispatch", op="encode")
         if self._pool is None:
-            data = arrays[0] if len(arrays) == 1 else \
-                np.concatenate(arrays, axis=0)
+            with _StageTimer("pack", spans=len(arrays)):
+                data = arrays[0] if len(arrays) == 1 else \
+                    np.concatenate(arrays, axis=0)
             rows = [a.shape[0] for a in arrays]
             handle = self._rs.encode_async(data, device=self._device)
             return _SplitHandle(handle, rows)
@@ -344,7 +344,8 @@ class _Dispatcher:
         if _failpoint._armed:
             _failpoint.hit("fleet.dispatch", op="reconstruct")
         if self._pool is None:
-            src = np.stack(arrays, axis=0)  # [B, 10, span]
+            with _StageTimer("pack", spans=len(arrays)):
+                src = np.stack(arrays, axis=0)  # [B, 10, span]
             handle = self._rs.reconstruct_some_async(
                 present, missing, src, device=self._device)
             return _UnstackHandle(handle)
@@ -553,7 +554,9 @@ def fleet_write_ec_files(base_names: Sequence[str], backend: str = "auto",
         while inflight:
             v, rows, fut = inflight.popleft()
             FleetReaderQueueGauge.dec()
-            pack.append((v, rows, fut.result()))
+            with _waiting("reader"):
+                arr = fut.result()
+            pack.append((v, rows, arr))
             acc += rows
             fill()
             if acc >= batch_rows or not inflight:
@@ -700,7 +703,9 @@ def _fleet_rebuild_group(present: List[int], missing: List[int],
         while inflight:
             item = inflight.popleft()
             FleetReaderQueueGauge.dec()
-            pack.append((item[0], item[1], item[2].result()))
+            with _waiting("reader"):
+                arr = item[2].result()
+            pack.append((item[0], item[1], arr))
             fill()
             if len(pack) >= per_batch or not inflight:
                 flush(pack)
@@ -894,7 +899,9 @@ def fleet_verify_ec_files(base_names: Sequence[str], backend: str = "auto",
         while inflight:
             item = inflight.popleft()
             FleetReaderQueueGauge.dec()
-            pack.append((item[0], item[1], item[2].result()))
+            with _waiting("reader"):
+                arr = item[2].result()
+            pack.append((item[0], item[1], arr))
             fill()
             if len(pack) >= per_batch or not inflight:
                 flush(pack)

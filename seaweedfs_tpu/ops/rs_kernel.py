@@ -32,8 +32,23 @@ import jax.numpy as jnp
 import numpy as np
 
 from seaweedfs_tpu.ops import gf256
+from seaweedfs_tpu.stats import trace
+from seaweedfs_tpu.stats.metrics import RsDispatchSecondsHistogram
 
 _BIT_SHIFTS = tuple(range(8))
+
+# The host side of one dispatch by phase. Children resolved once at
+# import: labels() takes a lock per call.
+_PHASE_HIST = {p: RsDispatchSecondsHistogram.labels(p)
+               for p in ("stage", "place", "enqueue", "wait", "fetch",
+                         "unstage")}
+
+
+def _phase(phase: str, **tags) -> trace.PhaseTimer:
+    """Timer of one dispatch phase; its span `rs.<phase>` nests under
+    whatever span the calling thread has open (fleet.dispatch,
+    fleet.retire, reads.decode, ...)."""
+    return trace.PhaseTimer(_PHASE_HIST[phase], "rs." + phase, **tags)
 
 # Where dispatched input bytes were placed: (platform, device id) ->
 # bytes, read off the arrays actually handed to the jitted programs
@@ -180,11 +195,19 @@ class PendingApply:
             return np.zeros(self._batch_shape + (o, 0), dtype=np.uint8)
         out = np.empty((o, n), dtype=np.uint8)
         for res, want, pos in self._parts:
-            out[:, pos:pos + want] = np.asarray(res)[:, :want]
+            # waiting apart from fetching: the device (a transfer's
+            # tail and the kernel) against the device->host copy
+            with _phase("wait"):
+                res.block_until_ready()
+            with _phase("fetch", bytes=res.nbytes):
+                host = np.asarray(res)
+            with _phase("unstage"):
+                out[:, pos:pos + want] = host[:, :want]
         if self._batch_shape:
-            out = np.moveaxis(
-                out.reshape(o, -1, self._lanes), 0, 1).reshape(
-                self._batch_shape + (o, self._lanes))
+            with _phase("unstage"):
+                out = np.moveaxis(
+                    out.reshape(o, -1, self._lanes), 0, 1).reshape(
+                    self._batch_shape + (o, self._lanes))
         return out
 
 
@@ -212,8 +235,9 @@ def apply_matrix_async(matrix: np.ndarray, shards,
     if n == 0:
         return PendingApply([], o, 0, batch_shape, n)
     if batch_shape:
-        flat = np.ascontiguousarray(
-            np.moveaxis(shards.reshape((-1, s, n)), 1, 0)).reshape(s, -1)
+        with _phase("stage"):
+            flat = np.ascontiguousarray(
+                np.moveaxis(shards.reshape((-1, s, n)), 1, 0)).reshape(s, -1)
     else:
         flat = shards
     parts = _submit_slabs(m2, flat, device=device)
@@ -265,21 +289,32 @@ def _submit_slabs(m2: jnp.ndarray, flat: np.ndarray, device=None):
         slab = _MIN_SLAB
         while slab < want:
             slab <<= 1
-        chunk = flat[:, pos:pos + want]
-        if want < slab:
-            padded = np.zeros((s, slab), dtype=np.uint8)
-            padded[:, :want] = chunk
-            chunk = padded
-        if device is not None:
-            x = jax.device_put(np.ascontiguousarray(chunk), device)
-        elif sharding is not None and slab % sharding.mesh.size == 0:
-            # device_put the HOST array straight onto the sharding:
-            # each device receives only its lane slice (going through
-            # device 0 first would double the interconnect traffic)
-            x = jax.device_put(np.ascontiguousarray(chunk), sharding)
-        else:
-            x = jnp.asarray(chunk)
+        on_mesh = sharding is not None and slab % sharding.mesh.size == 0
+        with _phase("stage"):
+            chunk = flat[:, pos:pos + want]
+            if want < slab:
+                padded = np.zeros((s, slab), dtype=np.uint8)
+                padded[:, :want] = chunk
+                chunk = padded
+            if device is not None or on_mesh:
+                chunk = np.ascontiguousarray(chunk)
+        # `place` is the time the call holds this thread; nothing here
+        # waits for the transfer, so its tail shows up in `wait`. On
+        # the default path the strided view goes to jnp.asarray as it
+        # is: whatever copy JAX makes of it is inside `place`.
+        with _phase("place", bytes=s * slab):
+            if device is not None:
+                x = jax.device_put(chunk, device)
+            elif on_mesh:
+                # device_put the HOST array straight onto the sharding:
+                # each device receives only its lane slice (going through
+                # device 0 first would double the interconnect traffic)
+                x = jax.device_put(chunk, sharding)
+            else:
+                x = jnp.asarray(chunk)
         note_placement(x)
-        parts.append((_gf_linear_jit(m2, x), want, pos))
+        with _phase("enqueue"):
+            res = _gf_linear_jit(m2, x)
+        parts.append((res, want, pos))
         pos += want
     return parts

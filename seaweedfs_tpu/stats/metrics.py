@@ -310,6 +310,13 @@ MetricsPushErrorCounter = REGISTRY.counter(
 FleetStageSecondsHistogram = REGISTRY.histogram(
     "SeaweedFS_fleet_stage_seconds",
     "fleet scheduler per-stage latency", ("stage",))
+# Seconds a scheduler thread blocked on another: `on` is reader (the
+# packing thread on a span read), retire_slot (on a free in-flight
+# slot), lane_from_pack / lane_from_retire (on a full writer lane, by
+# the thread that put).
+FleetWaitSecondsHistogram = REGISTRY.histogram(
+    "SeaweedFS_fleet_wait_seconds",
+    "fleet scheduler: time one thread blocked on another", ("on",))
 FleetReaderQueueGauge = REGISTRY.gauge(
     "SeaweedFS_fleet_reader_queue_depth",
     "spans prefetched by the reader pool, not yet packed")
@@ -323,6 +330,14 @@ FleetDispatchedBytesCounter = REGISTRY.counter(
 FleetWriterBacklogGauge = REGISTRY.gauge(
     "SeaweedFS_fleet_writer_lane_backlog",
     "writes queued on one writer lane", ("lane",))
+
+# Dispatch layer (ops/rs_kernel.py): one GF map's host side by phase —
+# stage (host copies before placement), place (host->device, as long as
+# it holds the caller), enqueue (the jitted call), wait (the device
+# finishing), fetch (device->host), unstage (copies into the result).
+RsDispatchSecondsHistogram = REGISTRY.histogram(
+    "SeaweedFS_rs_dispatch_seconds",
+    "RS device dispatch: host-side time by phase", ("phase",))
 
 # Unified mesh scheduler families (parallel/mesh_fleet.py): the
 # pod-scale data plane's bucket stream. `op` is the dispatch kind

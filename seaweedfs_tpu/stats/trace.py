@@ -23,6 +23,16 @@ Set SEAWEED_TRACE=1 to enable at import (how bench_profile.py turns on
 tracing inside spawned server subprocesses); in-process callers use
 enable()/disable(). `/debug/trace` on the metrics port serves the
 Chrome JSON of everything currently in the ring.
+
+`PhaseTimer` is the one way the pipeline layers (ec/fleet.py,
+ops/rs_kernel.py) time a phase: a histogram observation that is always
+taken plus a span that exists only while the ring is on.
+
+In a process that has already loaded jax, a span recorded into the ring
+is also opened as a `jax.profiler.TraceAnnotation`, so that a profiler
+trace holds the host spans on its own clock beside the device's lines.
+This module never imports jax itself: master and filer processes do not
+load it.
 """
 
 from __future__ import annotations
@@ -32,6 +42,7 @@ import itertools
 import json
 import os
 import random
+import sys
 import threading
 import time
 from collections import deque
@@ -69,6 +80,19 @@ EPOCH_OFFSET = time.time() - time.perf_counter()
 _cluster_enabled = False
 _req_ctx: "contextvars.ContextVar[Optional[object]]" = \
     contextvars.ContextVar("seaweed_trace_req", default=None)
+
+
+# jax.profiler.TraceAnnotation once this process is seen to have loaded
+# jax (looked up in sys.modules, never imported from here).
+_annotation = None
+
+
+def _profiler_annotation():
+    global _annotation
+    if _annotation is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        _annotation = getattr(profiler, "TraceAnnotation", None)
+    return _annotation
 
 
 def next_span_id() -> int:
@@ -123,7 +147,7 @@ NOOP = _NoopSpan()
 
 class Span:
     __slots__ = ("name", "tags", "id", "parent_id", "t0", "dur", "tid",
-                 "trace_id")
+                 "trace_id", "_ann")
 
     def __init__(self, name: str, parent: Optional[int], tags: dict):
         self.name = name
@@ -134,6 +158,7 @@ class Span:
         self.dur = 0.0
         self.tid = 0
         self.trace_id = 0
+        self._ann = None
 
     def __enter__(self) -> "Span":
         tid = threading.get_ident()
@@ -155,11 +180,19 @@ class Span:
                     # to the request span across the thread boundary
                     self.parent_id = ctx.span_id
         stack.append(self.id)
+        if _enabled:
+            ann = _profiler_annotation()
+            if ann is not None:
+                self._ann = ann(self.name)
+                self._ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
         self.dur = time.perf_counter() - self.t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
         stack = getattr(_tls, "stack", None)
         if stack and stack[-1] == self.id:
             stack.pop()
@@ -192,6 +225,35 @@ def span(name: str, parent: Optional[int] = None, **tags):
     if not _enabled and not _cluster_enabled:
         return NOOP
     return Span(name, parent, tags)
+
+
+class PhaseTimer:
+    """One timed phase of a pipeline layer: always observed into `hist`
+    (an already-resolved histogram child: labels() takes a lock), and
+    recorded as a span `name` only while the ring is on, nested under
+    the calling thread's open span or under the handoff token `parent`.
+    The disabled path costs two clock reads and one observe, and
+    allocates no Span."""
+
+    __slots__ = ("_hist", "_span", "_t0")
+
+    def __init__(self, hist, name: str, parent: Optional[int] = None,
+                 **tags):
+        self._hist = hist
+        self._span = Span(name, parent, tags) if _enabled else NOOP
+
+    def __enter__(self) -> "PhaseTimer":
+        self._span.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self._hist.observe(time.perf_counter() - self._t0)
+        return self._span.__exit__(*exc)
+
+    def token(self) -> Optional[int]:
+        """Handoff token of the underlying span (None when disabled)."""
+        return self._span.token()
 
 
 def active() -> bool:

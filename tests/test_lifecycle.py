@@ -434,6 +434,13 @@ def test_engine_cools_and_reheats_end_to_end(lifecycle_cluster):
     c.wait_for(lambda: not c.master.topo.lookup(vid), timeout=10,
                what="original replicas retired")
     assert c.fetch(fid).read() == b"lifecycle-blob " * 200
+    # the engine books the transition when the shell command has
+    # RETURNED, the topology changes while it still runs: wait for the
+    # last thing the engine writes before reading its ledger
+    c.wait_for(lambda: any(d["vid"] == vid and d["kind"] == "encode"
+                           and d["outcome"] == "ok"
+                           for d in engine.status()["decisions"]),
+               timeout=10, what="encode transition recorded")
     assert LifecycleTransitionsCounter.labels("encode", "ok").value >= 1
     assert engine.status()["states"]["warm"] >= 1
 
@@ -464,7 +471,9 @@ def test_engine_cools_and_reheats_end_to_end(lifecycle_cluster):
     c.wait_for(lambda: vid not in c.master.topo.ec_locations,
                timeout=10, what="ec shards retired after decode")
     assert c.fetch(fid).read() == b"lifecycle-blob " * 200
-    assert LifecycleTransitionsCounter.labels("decode", "ok").value >= 1
+    c.wait_for(lambda: LifecycleTransitionsCounter.labels(
+        "decode", "ok").value >= 1, timeout=10,
+        what="decode transition recorded")
 
 
 def test_engine_control_plane_and_shell(lifecycle_cluster):

@@ -260,6 +260,52 @@ def test_tracing_disabled_overhead(tmp_path):
     assert len(made) <= 8 + 8 + 17 + 8 + 8, per_stage
 
 
+@pytest.mark.parametrize("path", ["apply_matrix", "fleet_jax"])
+def test_phase_timers_allocate_no_span_when_tracing_is_off(path, tmp_path):
+    """The dispatch layer's phase timers (ops/rs_kernel.py) and the
+    scheduler's pack and wait timers (ec/fleet.py) on the jax backend:
+    with the tracer off they observe their histograms — and allocate no
+    Span: the process's span-id counter does not advance, the ring
+    stays empty. A count, not a time (ROADMAP C8)."""
+    from seaweedfs_tpu.ec import fleet
+    from seaweedfs_tpu.ops import rs_kernel
+    from seaweedfs_tpu.ops.rs_code import DATA_SHARDS, coding_matrix
+    from seaweedfs_tpu.stats import trace
+    from seaweedfs_tpu.stats.metrics import (
+        FleetStageSecondsHistogram, FleetWaitSecondsHistogram,
+        RsDispatchSecondsHistogram)
+
+    assert not trace.is_enabled()
+    trace.clear()
+    rng = np.random.default_rng(19)
+    place = RsDispatchSecondsHistogram.labels("place")
+    pack = FleetStageSecondsHistogram.labels("pack")
+    reader = FleetWaitSecondsHistogram.labels("reader")
+    counted = (place.count, pack.count, reader.count)
+    first_id = trace.next_span_id()
+    if path == "apply_matrix":
+        data = rng.integers(0, 256, (2, DATA_SHARDS, 4096), dtype=np.uint8)
+        rs_kernel.apply_matrix_async(
+            np.asarray(coding_matrix())[DATA_SHARDS:], data).result()
+        assert place.count == counted[0] + 1
+    else:
+        bases = []
+        for v in range(2):
+            base = str(tmp_path / f"o{v}")
+            with open(base + ".dat", "wb") as f:
+                f.write(rng.integers(0, 256, 3 * 2560 + 7,
+                                     dtype=np.uint8).tobytes())
+            bases.append(base)
+        fleet.fleet_write_ec_files(bases, backend="jax", large_block=2048,
+                                   small_block=256, chunk=2 * 2560)
+        assert place.count > counted[0]
+        assert pack.count > counted[1]
+        assert reader.count == counted[2] + 8      # 4 one-row spans each
+    assert trace.next_span_id() == first_id + 1, \
+        "a disabled tracer still allocated spans"
+    assert trace.spans() == []
+
+
 def test_storage_engine_microbench(tmp_path):
     """Raw storage-engine floors: the engine measured 36 us/write and
     17 us/read in round 4; 500/250 us floors catch an accidental

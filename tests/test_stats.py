@@ -359,3 +359,230 @@ def test_fleet_encode_shards_identical_with_tracing(tmp_path):
         pb = encoder.shard_file_name(b, sid)
         assert open(pa, "rb").read() == open(pb, "rb").read(), \
             f"shard {sid} diverged under tracing"
+
+
+# -- phase timers: the dispatch layer and the scheduler's waits ---------------
+
+_RS_PHASES = ("stage", "place", "enqueue", "wait", "fetch", "unstage")
+
+
+def _hist_counts(family, values):
+    return {v: family.labels(v).count for v in values}
+
+
+def _moved(family, values, before):
+    return {v: family.labels(v).count - before[v] for v in values}
+
+
+@pytest.mark.parametrize("placement", ["mesh", "device", "default"])
+@pytest.mark.parametrize("batched", [True, False])
+def test_rs_dispatch_phases_count_and_nest(placement, batched, monkeypatch):
+    """One apply_matrix_async(...).result() observes each phase of
+    SeaweedFS_rs_dispatch_seconds the expected number of times — place,
+    enqueue, wait and fetch once a slab; stage and unstage once a slab
+    (slice/pad, copy-out) plus once for the flatten / the moveaxis back
+    of a batched input — and its rs.* spans nest under the span the
+    caller has open. Counts only: no wall-clock assertion."""
+    import jax
+    import numpy as np
+
+    from seaweedfs_tpu.ops import rs_kernel
+    from seaweedfs_tpu.ops.rs_code import (
+        DATA_SHARDS, PARITY_SHARDS, ReedSolomon, coding_matrix)
+    from seaweedfs_tpu.stats.metrics import RsDispatchSecondsHistogram
+
+    slab = rs_kernel._MIN_SLAB
+    monkeypatch.setattr(rs_kernel, "_MAX_SLAB", slab)
+    device = None
+    if placement == "device":
+        device = jax.devices()[0]
+    elif placement == "default":
+        monkeypatch.setattr(rs_kernel, "_lane_sharding", lambda: None)
+    rng = np.random.default_rng(41)
+    shape = (3, DATA_SHARDS, 70_000) if batched else (DATA_SHARDS, 210_000)
+    data = rng.integers(0, 256, shape, dtype=np.uint8)
+    n_slabs = -(-210_000 // slab)
+    matrix = np.asarray(coding_matrix())[DATA_SHARDS:]
+
+    before = _hist_counts(RsDispatchSecondsHistogram, _RS_PHASES)
+    trace.enable()
+    with trace.span("caller") as caller:
+        got = rs_kernel.apply_matrix_async(matrix, data,
+                                           device=device).result()
+    moved = _moved(RsDispatchSecondsHistogram, _RS_PHASES, before)
+    extra = 1 if batched else 0
+    assert moved == {"stage": n_slabs + extra, "place": n_slabs,
+                     "enqueue": n_slabs, "wait": n_slabs,
+                     "fetch": n_slabs, "unstage": n_slabs + extra}
+    assert np.array_equal(got, ReedSolomon(backend="numpy").encode(data))
+
+    rs = [s for s in trace.spans() if s.name.startswith("rs.")]
+    per_name = {}
+    for s in rs:
+        per_name[s.name[3:]] = per_name.get(s.name[3:], 0) + 1
+        assert s.parent_id == caller.id, s.name
+    assert per_name == moved
+    assert [s.tags["bytes"] for s in rs if s.name == "rs.place"] == \
+        [DATA_SHARDS * slab] * n_slabs        # padding included
+    assert [s.tags["bytes"] for s in rs if s.name == "rs.fetch"] == \
+        [PARITY_SHARDS * slab] * n_slabs
+
+
+@pytest.fixture(scope="module")
+def traced_jax_fleet_encode(tmp_path_factory):
+    """One small fleet encode on the jax backend with tracing on: the
+    spans, the thread names, what the wait family counted, and the
+    volumes beside a serial numpy encode of the same bytes."""
+    import numpy as np
+
+    from seaweedfs_tpu.ec import encoder, fleet
+    from seaweedfs_tpu.stats.metrics import FleetWaitSecondsHistogram
+
+    large, small = 2048, 256
+    row = 10 * small
+    root = tmp_path_factory.mktemp("traced_fleet")
+    rng = np.random.default_rng(43)
+    bases, twins = [], []
+    for i, size in enumerate((3 * row + 123, row, 700)):
+        data = rng.integers(0, 256, size, dtype=np.uint8).tobytes()
+        for names, tag in ((bases, "f"), (twins, "s")):
+            base = str(root / f"{tag}{i}")
+            with open(base + ".dat", "wb") as f:
+                f.write(data)
+            names.append(base)
+    for twin in twins:
+        encoder.write_ec_files(twin, backend="numpy", large_block=large,
+                               small_block=small)
+    waits = ("reader", "retire_slot", "lane_from_pack", "lane_from_retire")
+    before = _hist_counts(FleetWaitSecondsHistogram, waits)
+    trace.disable()
+    trace.clear()
+    trace.enable()
+    try:
+        # two rows a dispatch, so several dispatches and retires
+        fleet.fleet_write_ec_files(bases, backend="jax", large_block=large,
+                                   small_block=small, chunk=2 * row)
+        spans = trace.spans()
+        threads = dict(trace._thread_names)
+    finally:
+        trace.disable()
+        trace.clear()
+    return {"spans": spans, "threads": threads, "bases": bases,
+            "twins": twins, "caller_tid": threading.get_ident(),
+            "waits": _moved(FleetWaitSecondsHistogram, waits, before)}
+
+
+@pytest.mark.parametrize("stage, thread, names", [
+    ("fleet.dispatch", "caller",
+     ("fleet.pack", "rs.stage", "rs.place", "rs.enqueue")),
+    ("fleet.retire", "fleet-retire",
+     ("rs.wait", "rs.fetch", "rs.unstage", "fleet.wait.lane_from_retire")),
+])
+def test_fleet_phase_spans_lie_under_their_stage(traced_jax_fleet_encode,
+                                                 stage, thread, names):
+    """The decomposition the benchmark reads: what the dispatch layer
+    and the scheduler time inside fleet.dispatch (packing thread) and
+    inside fleet.retire (retire thread) is recorded under a span of
+    that stage, on that thread."""
+    run = traced_jax_fleet_encode
+    by_id = {s.id: s for s in run["spans"]}
+    for name in names:
+        found = [s for s in run["spans"] if s.name == name]
+        assert found, f"no {name} span (got {sorted({s.name for s in run['spans']})})"
+        for s in found:
+            parent = by_id[s.parent_id]
+            assert parent.name == stage, (name, parent.name)
+            assert parent.tid == s.tid
+            if thread == "caller":
+                assert s.tid == run["caller_tid"]
+            else:
+                assert run["threads"][s.tid] == thread
+
+
+def test_fleet_reader_wait_counts_one_observation_a_span(
+        traced_jax_fleet_encode):
+    run = traced_jax_fleet_encode
+    reads = [s for s in run["spans"] if s.name == "fleet.read"]
+    assert len(reads) == 6          # rows of one volume each: 4 + 1 + 1
+    assert run["waits"]["reader"] == len(reads)
+    assert len([s for s in run["spans"]
+                if s.name == "fleet.wait.reader"]) == len(reads)
+    # one retire slot a dispatch; one lane put a data write and a parity
+    dispatches = len([s for s in run["spans"] if s.name == "fleet.dispatch"])
+    assert run["waits"]["retire_slot"] == dispatches
+    assert run["waits"]["lane_from_pack"] == len(reads)
+    assert run["waits"]["lane_from_retire"] == len(reads)
+
+
+def test_fleet_jax_shards_equal_serial_numpy_with_timers_in(
+        traced_jax_fleet_encode):
+    from seaweedfs_tpu.ec.encoder import shard_file_name
+    run = traced_jax_fleet_encode
+    for got, want in zip(run["bases"], run["twins"]):
+        for sid in range(14):
+            with open(shard_file_name(got, sid), "rb") as g, \
+                    open(shard_file_name(want, sid), "rb") as w:
+                assert g.read() == w.read(), f"shard {sid} of {got} differs"
+
+
+# -- the ring's spans in the profiler's trace ---------------------------------
+
+def test_spans_stand_in_the_profiler_trace_on_its_clock(tmp_path):
+    """With the ring on in a process that has loaded jax, a span is also
+    a TraceAnnotation: it stands on a /host: plane of the .xplane.pb,
+    and its start there is the ring's start shifted by a sync annotation
+    taken beside a host-clock reading (what benchmark/run.py does)."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    trace.enable()
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        # a host reading before each sync annotation: a delay between
+        # the two only widens the difference, so the least is the shift
+        sync_host = []
+        for _ in range(5):
+            sync_host.append(time.perf_counter())
+            with jax.profiler.TraceAnnotation("test.sync"):
+                pass
+        with trace.span("mirror.outer"):
+            with trace.span("mirror.inner"):
+                time.sleep(0.01)
+    finally:
+        jax.profiler.stop_trace()
+    ring = {s.name: s for s in trace.spans()}
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                          "*.xplane.pb"))
+    found = {"test.sync": [], "mirror.outer": [], "mirror.inner": []}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name in found:
+                    found[e.name].append((e.start_ns / 1e9,
+                                          e.duration_ns / 1e9))
+    assert [len(v) for v in found.values()] == [5, 1, 1], found
+    shift = min(at - host for (at, _), host in
+                zip(sorted(found["test.sync"]), sync_host))
+    for name in ("mirror.outer", "mirror.inner"):
+        (start, dur), = found[name]
+        assert abs(start - (ring[name].t0 + shift)) < 1e-3, name
+        assert dur >= ring[name].dur
+
+
+def test_stats_never_imports_jax():
+    """Master and filer processes load stats/ and never jax: the
+    profiler mirror looks jax up in sys.modules only."""
+    import subprocess
+    import sys
+    code = ("import sys; from seaweedfs_tpu.stats import trace, metrics; "
+            "trace.enable(); "
+            "s = trace.span('x'); s.__enter__(); s.__exit__(None, None, None); "
+            "assert 'jax' not in sys.modules; assert trace.spans()[0]._ann is None")
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=60)
