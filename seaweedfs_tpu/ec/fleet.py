@@ -7,14 +7,18 @@ reads. This module lifts the batch dimension from rows-within-a-volume
 to chunks-ACROSS-volumes (the ROADMAP "sharding, batching, async"
 directive; the BASELINE "cluster-wide ec.encode" shape):
 
-  pack      same-sized row-spans from many volumes fuse into one
-            [B, 10, small] dispatch — the `_encode_small_rows` batch
-            shape — so 64 small volumes cost a handful of dispatches
-            instead of 64 serial ones.
+  pack      row-spans from many volumes share one [10, lanes] staging
+            buffer — the layout the device wants — so 64 small
+            volumes cost a handful of dispatches instead of 64 serial
+            ones, and nothing is copied between the read and the
+            placement: the buffer IS the dispatch's input.
   feed      a bounded reader pool prefetches spans ahead of the
-            device. Spans are consumed in submission order (round-
-            robin rounds over the volumes), so per-volume row order
-            is preserved by construction while reads overlap compute.
+            device, each reader filling its span's lanes of a staging
+            buffer straight from the .dat. Spans are consumed in
+            submission order (round-robin rounds over the volumes),
+            so per-volume row order is preserved by construction
+            while reads overlap compute. The buffers are reused from
+            dispatch to dispatch and from pass to pass (`_Staging`).
   dispatch  the jax backend is async already; sync host backends
             (native/numpy) are lifted to the same handle contract by
             a small encode pool, so RS compute itself runs multi-core
@@ -61,8 +65,8 @@ from seaweedfs_tpu.stats import trace
 from seaweedfs_tpu.stats.metrics import (
     FleetDispatchBatchHistogram, FleetDispatchedBytesCounter,
     FleetMeshFallbacksCounter, FleetReaderQueueGauge,
-    FleetStageSecondsHistogram, FleetWaitSecondsHistogram,
-    FleetWriterBacklogGauge)
+    FleetStageSecondsHistogram, FleetStagingBuffersCounter,
+    FleetWaitSecondsHistogram, FleetWriterBacklogGauge)
 
 
 def mesh_fleet_or_none():
@@ -82,8 +86,9 @@ def mesh_fleet_or_none():
 FLEET_READERS = 4
 
 # Fused dispatches in flight at once — the writer-queue bound, same
-# double-buffering role as encoder.PIPELINE_DEPTH. Peak host memory is
-# ~(depth + 2) fused batches (queued + packing + retiring).
+# double-buffering role as encoder.PIPELINE_DEPTH. With the reader
+# prefetch it sets an encode pass's share of staging buffers (see
+# `_Staging`), which is the pass's peak host memory.
 FLEET_DEPTH = 2
 
 # Encode pool for synchronous host backends: ctypes/numpy release the
@@ -110,7 +115,9 @@ _STAGE_HIST = {s: FleetStageSecondsHistogram.labels(s)
                          "write", "verify", "upload")}
 _WAIT_HIST = {on: FleetWaitSecondsHistogram.labels(on)
               for on in ("reader", "retire_slot", "lane_from_pack",
-                         "lane_from_retire")}
+                         "lane_from_retire", "staging")}
+_STAGING_HANDED = {state: FleetStagingBuffersCounter.labels(state)
+                   for state in ("fresh", "reused")}
 
 
 class _StageTimer(trace.PhaseTimer):
@@ -285,13 +292,18 @@ class TaggedPipeline:
 
 class _Gathered:
     """Handle over several in-flight per-span encodes: .result() is the
-    list of per-span outputs, ordered like the spans were packed."""
+    list of per-span outputs, ordered like the spans were packed.
+    `done` runs once every one of them has resolved."""
 
-    def __init__(self, handles):
+    def __init__(self, handles, done: Optional[Callable[[], None]] = None):
         self._handles = handles
+        self._done = done
 
     def result(self) -> List[np.ndarray]:
-        return [h.result() for h in self._handles]
+        outs = [h.result() for h in self._handles]
+        if self._done is not None:
+            self._done()
+        return outs
 
 
 def _rs_staged(fn, arr: np.ndarray, parent: Optional[int]) -> np.ndarray:
@@ -306,12 +318,14 @@ class _Dispatcher:
     """Uniform async-handle dispatch over any RS backend.
 
     jax dispatches are inherently async (the device computes while the
-    host stages IO), so a fused batch is concatenated once and issued
-    as one dispatch — fewer, fuller device slabs. Host backends compute
-    synchronously instead, so each span goes to a small encode pool as
-    its own task (no concatenation copy; the GIL-free native/numpy
-    kernels genuinely run on other cores) and the handles are gathered.
-    Either way .result() yields per-span parity arrays.
+    host stages IO), so a fused batch is issued as one dispatch —
+    fewer, fuller device slabs. Host backends compute synchronously
+    instead, so each span goes to a small encode pool as its own task
+    (the GIL-free native/numpy kernels genuinely run on other cores)
+    and the handles are gathered. Either way .result() yields per-span
+    output arrays. The `pack` stage times what the packing thread does
+    to make a fused dispatch's input: a slice of the staging buffer for
+    `encode_lanes`, a stacking copy for `encode` and `reconstruct`.
     """
 
     def __init__(self, rs: ReedSolomon, device=None,
@@ -325,6 +339,26 @@ class _Dispatcher:
                 max_workers=max(1, encoders),
                 thread_name_prefix="fleet-encode")
 
+    def encode_lanes(self, buf: np.ndarray, cuts: List[Tuple[int, int]],
+                     done: Callable[[], None]):
+        """Parity of a staging buffer [10, lanes] whose spans lie at
+        `cuts` = [(lane offset, lanes)], back to back from lane 0:
+        .result() yields one [4, lanes] array a span. The jax branch
+        hands the filled lanes over as they are — no copy before the
+        dispatch layer's slab slices. `done` runs when every read of
+        `buf` on behalf of this dispatch is over (retire thread)."""
+        if _failpoint._armed:
+            _failpoint.hit("fleet.dispatch", op="encode")
+        if self._pool is None:
+            with _StageTimer("pack", spans=len(cuts)):
+                data = buf[:, :cuts[-1][0] + cuts[-1][1]]
+            handle = self._rs.encode_async(data, device=self._device)
+            return _SplitHandle(handle, [n for _, n in cuts], 1, done)
+        token = trace.handoff()
+        return _Gathered([self._pool.submit(_rs_staged, self._rs.encode,
+                                            buf[:, off:off + n], token)
+                          for off, n in cuts], done)
+
     def encode(self, arrays: List[np.ndarray]):
         if _failpoint._armed:
             _failpoint.hit("fleet.dispatch", op="encode")
@@ -334,7 +368,7 @@ class _Dispatcher:
                     np.concatenate(arrays, axis=0)
             rows = [a.shape[0] for a in arrays]
             handle = self._rs.encode_async(data, device=self._device)
-            return _SplitHandle(handle, rows)
+            return _SplitHandle(handle, rows, 0)
         token = trace.handoff()
         return _Gathered([self._pool.submit(_rs_staged, self._rs.encode,
                                             a, token)
@@ -362,21 +396,23 @@ class _Dispatcher:
 
 
 class _SplitHandle:
-    """Adapt one fused async encode handle back to per-span outputs."""
+    """Adapt one fused async encode handle back to per-span outputs:
+    views of `sizes` entries each along `axis` (lanes for a staging
+    buffer's dispatch, rows for a stacked one). `done` runs once the
+    fused result is on the host."""
 
-    def __init__(self, handle, rows: List[int]):
+    def __init__(self, handle, sizes: List[int], axis: int,
+                 done: Optional[Callable[[], None]] = None):
         self._handle = handle
-        self._rows = rows
+        self._sizes = sizes
+        self._axis = axis
+        self._done = done
 
     def result(self) -> List[np.ndarray]:
         out = self._handle.result()
-        if len(self._rows) == 1:
-            return [out]
-        parts, row = [], 0
-        for r in self._rows:
-            parts.append(out[row:row + r])
-            row += r
-        return parts
+        if self._done is not None:
+            self._done()
+        return np.split(out, np.cumsum(self._sizes)[:-1], axis=self._axis)
 
 
 class _UnstackHandle:
@@ -426,34 +462,188 @@ def _round_robin_spans(vols: List[_VolState], span_rows: int):
         pending = nxt
 
 
-def _read_span(base: str, row0: int, rows: int,
-               row_bytes: int, small_block: int) -> np.ndarray:
-    """Rows [row0, row0+rows) of one volume as [rows, 10, small],
-    zero-padded past EOF — one sequential read per span (the same
-    readinto primitive as encoder._read_padded)."""
-    with open(base + ".dat", "rb") as f:
-        buf = _encoder._read_padded(f, row0 * row_bytes, rows * row_bytes)
-    return buf.reshape(rows, DATA_SHARDS, small_block)
+class _IdleStaging:
+    """The process's staging buffers between encode passes, so that the
+    next pass (the next shell command) fills memory that is already
+    mapped instead of faulting in fresh pages. Buffers of ONE width —
+    the last pass's: a server encodes with one geometry, and odd widths
+    must not pile up. Only buffers that were filled before come here
+    (an untouched np.empty is address space, not memory), at most one
+    pass's share: that is all the memory the scheduler keeps resident
+    while idle."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._lanes = 0
+        self._bufs: List[np.ndarray] = []
+
+    def take(self, lanes: int, n: int) -> List[np.ndarray]:
+        with self._lock:
+            if lanes != self._lanes:
+                self._lanes, self._bufs = lanes, []
+            taken, self._bufs = self._bufs[:n], self._bufs[n:]
+            return taken
+
+    def give(self, lanes: int, bufs: List[np.ndarray], keep: int) -> None:
+        with self._lock:
+            if lanes == self._lanes:  # else another geometry took over
+                self._bufs.extend(bufs[:max(0, keep - len(self._bufs))])
+
+
+_IDLE_STAGING = _IdleStaging()
+
+
+class _StagedBatch:
+    """One staging buffer on its way through a pass: the spans planned
+    into it, and how many closures still have to read it."""
+
+    __slots__ = ("buf", "rows", "spans", "refs")
+
+    def __init__(self, buf: np.ndarray):
+        self.buf = buf
+        self.rows = 0
+        self.spans: List[Tuple[_VolState, int, int]] = []  # vol, lane, lanes
+        self.refs = 0
+
+
+class _Staging:
+    """One encode pass's share of staging buffers.
+
+    A staging buffer is [DATA_SHARDS, lanes] uint8 — a fused dispatch's
+    input in the layout the device wants. The readers fill it straight
+    from the .dat files, the dispatch layer places slices of it, the
+    writer lanes write the data shards out of it; then it comes round
+    again. The share is what the pipeline has in flight anyway (see
+    fleet_write_ec_files), so the one place a pass can block here —
+    `acquire`, timed as fleet.wait.staging — blocks only while buffers
+    are downstream of the packing thread, where they come back without
+    its help. Passes share nothing but the idle list, so concurrent
+    schedulers (one a device, parallel generate RPCs) cannot hold each
+    other up.
+
+    Buffers take turns (FIFO): what a pass touches, and the process
+    then keeps, is min(share, dispatches) buffers whatever the timing.
+    """
+
+    def __init__(self, lanes: int, share: int,
+                 check: Callable[[], None]):
+        self._lanes = lanes
+        self._share = share
+        self._check = check  # raises the pipeline's latched error
+        self._cond = threading.Condition()
+        self._held = _IDLE_STAGING.take(lanes, share)
+        self._handed = 0
+        self._free: deque = deque()
+
+    def acquire(self) -> np.ndarray:
+        state = "reused"
+        with _waiting("staging"), self._cond:
+            if self._handed < self._share:
+                # first round: what an earlier pass left, then new ones
+                if self._handed == len(self._held):
+                    self._held.append(np.empty(
+                        (DATA_SHARDS, self._lanes), dtype=np.uint8))
+                    state = "fresh"
+                buf = self._held[self._handed]
+            else:
+                while not self._free:
+                    # woken by unref; the timeout is for a latched
+                    # pipeline error, after which the closures that
+                    # would release a buffer are skipped
+                    self._cond.wait(0.05)
+                    self._check()
+                buf = self._free.popleft()
+            self._handed += 1
+        _STAGING_HANDED[state].inc()
+        return buf
+
+    def unref(self, batch: _StagedBatch) -> None:
+        """One reader of `batch.buf` is through; the last one frees it."""
+        with self._cond:
+            batch.refs -= 1
+            if batch.refs == 0:
+                self._free.append(batch.buf)
+                self._cond.notify()
+
+    def close(self) -> None:
+        """End of the pass, with every thread that could touch a buffer
+        joined: what was filled goes to the idle list whatever the
+        reference counts say (a pass that failed leaves them open)."""
+        with self._cond:
+            held = list(self._held)
+        _IDLE_STAGING.give(self._lanes, held, self._share)
+
+
+try:  # 16 is POSIX's floor; sysconf says -1 for "no fixed limit"
+    _IOV_MAX = max(16, os.sysconf("SC_IOV_MAX"))
+except (ValueError, OSError):
+    _IOV_MAX = 16
+
+
+def _preadv_full(fd: int, views: List[memoryview], offset: int) -> int:
+    """Fill `views` in order from `fd` at `offset`, however the kernel
+    cuts the reads; returns the bytes read, short only at EOF. Trims
+    `views` in place as it goes."""
+    total, i = 0, 0
+    while i < len(views):
+        got = os.preadv(fd, views[i:i + _IOV_MAX], offset + total)
+        if got == 0:
+            break
+        total += got
+        while i < len(views) and got >= len(views[i]):
+            got -= len(views[i])
+            i += 1
+        if got:
+            views[i] = views[i][got:]
+    return total
+
+
+def _read_span_into(base: str, row0: int, rows: int, row_bytes: int,
+                    small_block: int, buf: np.ndarray, off: int) -> None:
+    """Rows [row0, row0+rows) of one volume straight into lanes
+    [off, off + rows*small) of a staging buffer: block i of row r is
+    contiguous in the .dat and lands in buf[i, off + r*small : +small],
+    contiguous too — the [10, lanes] layout with no copy in between.
+    What lies past EOF is zeroed on EVERY use: the buffer still holds
+    an earlier dispatch's bytes."""
+    blocks = [buf[i, off + r * small_block:off + (r + 1) * small_block]
+              for r in range(rows) for i in range(DATA_SHARDS)]
+    fd = os.open(base + ".dat", os.O_RDONLY)
+    try:
+        got = _preadv_full(fd, [memoryview(b) for b in blocks],
+                           row0 * row_bytes)
+    finally:
+        os.close(fd)
+    first, part = divmod(got, small_block)
+    if first < len(blocks):
+        blocks[first][part:] = 0
+        for b in blocks[first + 1:]:
+            b[:] = 0
 
 
 def _read_span_staged(base: str, row0: int, rows: int, row_bytes: int,
-                      small_block: int, parent: Optional[int]) -> np.ndarray:
-    """_read_span on a reader-pool thread, attributed to the 'read'
-    stage and parented to the scheduler's root span."""
+                      small_block: int, buf: np.ndarray, off: int,
+                      parent: Optional[int]) -> None:
+    """_read_span_into on a reader-pool thread, attributed to the
+    'read' stage and parented to the scheduler's root span."""
     with _StageTimer("read", parent=parent, vol=os.path.basename(base)):
-        return _read_span(base, row0, rows, row_bytes, small_block)
+        _read_span_into(base, row0, rows, row_bytes, small_block, buf, off)
 
 
-def _write_data_shards(base: str, arr: np.ndarray) -> None:
+def _write_data_shards(base: str, arr: np.ndarray,
+                       done: Callable[[], None]) -> None:
+    """One span's lanes [10, n] of a staging buffer: row i is the next
+    n bytes of data shard i, one contiguous write each. `done` tells
+    the buffer that this reader of it is through."""
     for i in range(DATA_SHARDS):
-        _append_rows(base, i, [arr[r, i] for r in range(arr.shape[0])])
+        _append_rows(base, i, [arr[i]])
+    done()
 
 
 def _write_parity_span(base: str, seg: np.ndarray) -> None:
-    """One span's parity [rows, 4, small] -> append to .ec10-.ec13."""
-    for p in range(seg.shape[1]):
-        _append_rows(base, DATA_SHARDS + p,
-                     [seg[r, p] for r in range(seg.shape[0])])
+    """One span's parity [4, n] -> append to .ec10-.ec13."""
+    for p in range(seg.shape[0]):
+        _append_rows(base, DATA_SHARDS + p, [seg[p]])
 
 
 def fleet_write_ec_files(base_names: Sequence[str], backend: str = "auto",
@@ -499,12 +689,14 @@ def fleet_write_ec_files(base_names: Sequence[str], backend: str = "auto",
     alive = [v for v in vols if v.n_rows > 0]
     if not alive:
         return  # all empty: 14 empty shard files each, same as serial
-    # One fused dispatch ≈ `chunk` bytes of data rows; span size is the
-    # per-volume slice of it, so a full round across the fleet packs
-    # into one dispatch (a single volume degrades to the serial shape).
+    # One fused dispatch ≈ `chunk` bytes of data rows — one staging
+    # buffer of batch_rows rows; span size is the per-volume slice of
+    # it, so a full round across the fleet packs into one dispatch (a
+    # single volume degrades to the serial shape). A span that does not
+    # fit what is left of a buffer starts the next one.
     batch_rows = max(1, chunk // row_bytes)
     span_rows = max(1, batch_rows // len(alive))
-    spans_per_batch = -(-batch_rows // span_rows)
+    spans_per_batch = batch_rows // span_rows  # of full spans; never fewer
     prefetch = max(readers, 2 * spans_per_batch)
 
     dispatcher = _Dispatcher(ReedSolomon(backend=backend), device=device,
@@ -513,55 +705,75 @@ def fleet_write_ec_files(base_names: Sequence[str], backend: str = "auto",
     pool = ThreadPoolExecutor(max_workers=max(1, readers),
                               thread_name_prefix="fleet-read")
     pipe = TaggedPipeline(depth=depth)
+    # The pass's share of staging buffers is what the pipeline holds at
+    # once: upstream of the dispatch the prefetched spans' buffers (one
+    # more when they straddle), downstream `depth` queued dispatches
+    # and the one in the retire thread's hand, whose data-shard writes
+    # may still be queued on the lanes. Upstream never needs them all,
+    # so a pass out of buffers always has some coming back.
+    staging = _Staging(batch_rows * small_block,
+                       -(-prefetch // spans_per_batch) + 1 + depth + 1,
+                       pipe._raise_pending)
     gen = _round_robin_spans(alive, span_rows)
     inflight: deque = deque()
+    filling: Optional[_StagedBatch] = None
     root = trace.span("fleet.encode", volumes=len(alive), backend=backend)
     root.__enter__()
     token = root.token()
 
     def fill() -> None:
+        nonlocal filling
         while len(inflight) < prefetch:
             nxt = next(gen, None)
             if nxt is None:
                 break
             v, row0, rows = nxt
-            inflight.append((v, rows, pool.submit(
+            if filling is None or filling.rows + rows > batch_rows:
+                filling = _StagedBatch(staging.acquire())
+            off = filling.rows * small_block
+            filling.rows += rows
+            filling.spans.append((v, off, rows * small_block))
+            inflight.append((filling, pool.submit(
                 _read_span_staged, v.base, row0, rows, row_bytes,
-                small_block, token)))
+                small_block, filling.buf, off, token)))
             # inc/dec deltas so concurrent schedulers SUM on the
             # shared gauge instead of overwriting each other's depth
             FleetReaderQueueGauge.inc()
 
-    def flush(pack: List[Tuple[_VolState, int, np.ndarray]]) -> None:
-        with _StageTimer("dispatch", batch=len(pack)):
-            handle = dispatcher.encode([a for _, _, a in pack])
-        FleetDispatchBatchHistogram.observe(len(pack))
+    def flush(batch: _StagedBatch) -> None:
+        # the buffer is free again when the retire thread has the
+        # dispatch's result (every transfer out of it is over) AND each
+        # span's data-shard write has run on its lane
+        batch.refs = 1 + len(batch.spans)
+        release = functools.partial(staging.unref, batch)
+        with _StageTimer("dispatch", batch=len(batch.spans)):
+            handle = dispatcher.encode_lanes(
+                batch.buf, [(off, n) for _, off, n in batch.spans], release)
+        FleetDispatchBatchHistogram.observe(len(batch.spans))
         FleetDispatchedBytesCounter.inc(
-            float(sum(a.nbytes for _, _, a in pack)))
+            float(DATA_SHARDS * batch.rows * small_block))
         # data shards need no parity: straight to each volume's lane
         # (enqueued here, in pack order, so per-volume FIFO holds)
-        for v, _, arr in pack:
+        for v, off, n in batch.spans:
             pipe.write(v.tag, functools.partial(
-                _write_data_shards, v.base, arr))
+                _write_data_shards, v.base, batch.buf[:, off:off + n],
+                release))
         pipe.submit(handle, [
             (v.tag, functools.partial(_write_parity_span, v.base))
-            for v, _, _ in pack])
+            for v, _, _ in batch.spans])
 
     try:
         fill()
-        pack: List[Tuple[_VolState, int, np.ndarray]] = []
-        acc = 0
         while inflight:
-            v, rows, fut = inflight.popleft()
+            batch, fut = inflight.popleft()
             FleetReaderQueueGauge.dec()
             with _waiting("reader"):
-                arr = fut.result()
-            pack.append((v, rows, arr))
-            acc += rows
+                fut.result()
             fill()
-            if acc >= batch_rows or not inflight:
-                flush(pack)
-                pack, acc = [], 0
+            # spans are planned in order, so a batch is complete when
+            # the next span read belongs to another (or none is left)
+            if not inflight or inflight[0][0] is not batch:
+                flush(batch)
     finally:
         FleetReaderQueueGauge.dec(len(inflight))  # error path leftovers
         pool.shutdown(wait=True)
@@ -569,6 +781,7 @@ def fleet_write_ec_files(base_names: Sequence[str], backend: str = "auto",
             pipe.drain()  # may re-raise the latched pipeline error
         finally:
             dispatcher.close()
+            staging.close()
             root.__exit__(None, None, None)
 
 

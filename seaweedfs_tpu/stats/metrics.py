@@ -313,10 +313,18 @@ FleetStageSecondsHistogram = REGISTRY.histogram(
 # Seconds a scheduler thread blocked on another: `on` is reader (the
 # packing thread on a span read), retire_slot (on a free in-flight
 # slot), lane_from_pack / lane_from_retire (on a full writer lane, by
-# the thread that put).
+# the thread that put), staging (the packing thread on a free staging
+# buffer: every one of its pass's share is still read downstream).
 FleetWaitSecondsHistogram = REGISTRY.histogram(
     "SeaweedFS_fleet_wait_seconds",
     "fleet scheduler: time one thread blocked on another", ("on",))
+# Staging buffers handed to the encode scheduler's readers: `state` is
+# fresh (never written before: its first fill pays the page faults) or
+# reused (touched by an earlier dispatch of this pass or an earlier pass).
+FleetStagingBuffersCounter = REGISTRY.counter(
+    "SeaweedFS_fleet_staging_buffers_total",
+    "staging buffers handed out by the fleet encode scheduler",
+    ("state",))
 FleetReaderQueueGauge = REGISTRY.gauge(
     "SeaweedFS_fleet_reader_queue_depth",
     "spans prefetched by the reader pool, not yet packed")

@@ -302,3 +302,357 @@ def test_write_dat_file_backend_chunk_default(tmp_path):
                       large_block=LARGE, small_block=SMALL)
     with open(base + ".dat", "rb") as f:
         assert f.read() == original
+
+
+# --- the staging buffers (ISSUE 26) ------------------------------------------
+#
+# Spans are read straight into reused [10, lanes] buffers. What that can
+# break: a reused buffer holds an earlier dispatch's bytes (padding past
+# EOF), and a buffer handed out again while something still reads it.
+
+@pytest.fixture(autouse=True)
+def no_idle_staging(monkeypatch):
+    """Every test starts as a new process would: no buffer left idle by
+    an earlier pass (the idle list is the one thing passes share)."""
+    monkeypatch.setattr(fleet, "_IDLE_STAGING", fleet._IdleStaging())
+
+
+# volumes of more than 8 rows stay on the fleet's small-row path
+ROOMY = 16 * LARGE
+
+
+def _handed(state):
+    from seaweedfs_tpu.stats.metrics import FleetStagingBuffersCounter
+    return FleetStagingBuffersCounter.labels(state).value
+
+
+def _two_passes(tmp_path, backend, sizes_by_pass, **kw):
+    """Encode each list of sizes as one fleet pass, in this process and
+    with one geometry, and hold every pass to the serial numpy encode.
+    Returns (fresh, reused) handed out per pass."""
+    counts = []
+    for n, sizes in enumerate(sizes_by_pass):
+        root = tmp_path / f"pass{n}"
+        root.mkdir()
+        bases = _make_volumes(str(root), sizes, seed=20 + n)
+        twins = _serial_twin(bases)
+        for t in twins:
+            ec.write_ec_files(t, backend="numpy", large_block=ROOMY,
+                              small_block=SMALL)
+        before = _handed("fresh"), _handed("reused")
+        fleet.fleet_write_ec_files(bases, backend=backend, large_block=ROOMY,
+                                   small_block=SMALL, chunk=2 * ROW, **kw)
+        counts.append((_handed("fresh") - before[0],
+                       _handed("reused") - before[1]))
+        _assert_shards_equal(bases, twins)
+    return counts
+
+
+@pytest.mark.parametrize("backend", ["numpy", "jax"])
+def test_fleet_encode_stale_staging_bytes_never_reach_a_shard(tmp_path,
+                                                              backend):
+    """Two passes in one process: the first fills every staging buffer
+    with full rows of random bytes, the second encodes volumes whose
+    last row is short (one of 700 bytes) into the SAME buffers. The
+    padding past EOF must read as zeros, not as the first pass's data."""
+    full = [6 * ROW, 6 * ROW, 6 * ROW]
+    ragged = [3 * ROW + 123, 700, 2 * ROW + 1, ROW]
+    first, second = _two_passes(tmp_path, backend, [full, ragged])
+    assert first[0] > 0
+    assert second == (0, second[1]) and second[1] > 0, \
+        "the second pass did not run in the first pass's buffers"
+
+
+@pytest.mark.parametrize("backend", ["numpy", "jax"])
+def test_second_pass_of_one_geometry_hands_out_no_fresh_buffer(tmp_path,
+                                                               backend):
+    """The counter the benchmark's fleet_staging_reuse_share reads: a
+    pass touches min(share, dispatches) buffers whatever the timing,
+    and the next pass of the same geometry is handed only those."""
+    sizes = [9 * ROW, 9 * ROW]       # 9 dispatches of 2 rows
+    # share: 2 prefetched spans a buffer -> 2 buffers + 1, depth 2 + 1
+    first, second = _two_passes(tmp_path, backend, [sizes, sizes],
+                                readers=2)
+    assert first == (6, 3)
+    assert second == (0, 9)
+
+
+def test_short_pass_touches_only_the_buffers_it_fills(tmp_path):
+    first, second = _two_passes(tmp_path, "numpy", [[3 * ROW], [3 * ROW]])
+    assert first == (2, 0)           # 2 rows + 1 row: two dispatches
+    assert second == (0, 2)
+
+
+def test_another_geometry_drops_the_idle_buffers(tmp_path):
+    _two_passes(tmp_path, "numpy", [[4 * ROW]])
+    assert {b.shape for b in fleet._IDLE_STAGING._bufs} == \
+        {(DATA_SHARDS, 2 * SMALL)}
+    bases = _make_volumes(str(tmp_path), [4 * ROW], seed=3)
+    fleet.fleet_write_ec_files(bases, backend="numpy", large_block=ROOMY,
+                               small_block=SMALL, chunk=ROW)
+    assert {b.shape for b in fleet._IDLE_STAGING._bufs} == \
+        {(DATA_SHARDS, SMALL)}
+
+
+def test_read_span_into_zeroes_past_eof_on_every_use(tmp_path):
+    """The reader of a volume's last span owns every lane of it: bytes
+    past EOF are zeroed even where the file ends mid-block, at a block
+    boundary, or before the span's second row."""
+    rng = np.random.default_rng(31)
+    for size in (700, SMALL, 3 * SMALL + 1, ROW, ROW + 5, 2 * ROW):
+        base = str(tmp_path / f"v{size}")
+        data = rng.integers(0, 256, size, dtype=np.uint8)
+        with open(base + ".dat", "wb") as f:
+            f.write(data.tobytes())
+        buf = np.full((DATA_SHARDS, 5 * SMALL), 0xAB, dtype=np.uint8)
+        fleet._read_span_into(base, 0, 2, ROW, SMALL, buf, SMALL)
+        want = np.zeros(2 * ROW, dtype=np.uint8)
+        want[:size] = data
+        want = want.reshape(2, DATA_SHARDS, SMALL)
+        for r in range(2):
+            assert np.array_equal(
+                buf[:, (1 + r) * SMALL:(2 + r) * SMALL], want[r]), (size, r)
+        # lanes outside the span are somebody else's
+        assert (buf[:, :SMALL] == 0xAB).all()
+        assert (buf[:, 3 * SMALL:] == 0xAB).all()
+
+
+@pytest.mark.parametrize("iov_max, most", [(3, None), (1024, 100), (7, 33)])
+def test_preadv_full_survives_iov_max_and_short_reads(tmp_path, monkeypatch,
+                                                      iov_max, most):
+    """A span is rows * 10 iovecs — past IOV_MAX at small blocks — and
+    the kernel may cut any read short: the shards stay byte-identical."""
+    monkeypatch.setattr(fleet, "_IOV_MAX", iov_max)
+    calls = []
+    real = os.preadv
+
+    def preadv(fd, views, offset):
+        calls.append(len(views))
+        if most is None:
+            return real(fd, views, offset)
+        # the kernel's cut: only the first `most` bytes arrive
+        cut, room = [], most
+        for v in views:
+            if room <= 0:
+                break
+            cut.append(v[:room])
+            room -= len(cut[-1])
+        return real(fd, cut, offset)
+
+    monkeypatch.setattr(os, "preadv", preadv)
+    bases = _make_volumes(str(tmp_path), [5 * ROW + 300, 700], seed=32)
+    twins = _serial_twin(bases)
+    for t in twins:
+        ec.write_ec_files(t, backend="numpy", large_block=ROOMY,
+                          small_block=SMALL)
+    fleet.fleet_write_ec_files(bases, backend="numpy", large_block=ROOMY,
+                               small_block=SMALL, chunk=4 * ROW)
+    _assert_shards_equal(bases, twins)
+    assert max(calls) <= iov_max
+
+
+def test_staging_buffer_is_free_only_after_result_and_every_write():
+    """The release rule, alone: a buffer with a dispatch and two spans
+    comes round again only when all three readers are through."""
+    import threading
+
+    st = fleet._Staging(64, 3, lambda: None)
+    try:
+        batches = [fleet._StagedBatch(st.acquire()) for _ in range(3)]
+        for b in batches:
+            b.refs = 3
+        got = []
+        t = threading.Thread(target=lambda: got.append(st.acquire()),
+                             daemon=True)
+        t.start()
+        st.unref(batches[1])
+        st.unref(batches[1])
+        t.join(0.3)
+        assert t.is_alive() and not got, "handed out with a reader left"
+        st.unref(batches[1])
+        t.join(5)
+        assert not t.is_alive() and got[0] is batches[1].buf
+    finally:
+        st.close()
+
+
+@pytest.mark.parametrize("backend", ["numpy", "jax"])
+def test_buffer_readers_are_the_retire_thread_and_the_writer_lanes(
+        tmp_path, monkeypatch, backend):
+    """Who lets go of a buffer, and where: the retire thread once a
+    dispatch, when it has the result (every transfer out of the buffer
+    is over), and a writer lane once a span, when the data-shard write
+    has run. Nothing is released from the packing thread."""
+    import threading
+
+    by_thread = []
+    real = fleet._Staging.unref
+
+    def unref(self, batch):
+        by_thread.append(threading.current_thread().name)
+        real(self, batch)
+
+    monkeypatch.setattr(fleet._Staging, "unref", unref)
+    first, = _two_passes(tmp_path, backend,
+                         [[5 * ROW + 1, 3 * ROW, 700]])
+    dispatches, spans = int(sum(first)), 6 + 3 + 1
+    assert dispatches == 5
+    assert by_thread.count("fleet-retire") == dispatches
+    assert len([t for t in by_thread
+                if t.startswith("fleet-write-")]) == spans
+    assert len(by_thread) == dispatches + spans
+
+
+def test_staging_acquire_raises_the_latched_error_instead_of_waiting():
+    """After a pipeline error the closures that release buffers are
+    skipped: a packing thread waiting for one must see the error."""
+    def check():
+        raise OSError("disk full")
+
+    st = fleet._Staging(64, 2, check)
+    try:
+        st.acquire(), st.acquire()
+        with pytest.raises(OSError, match="disk full"):
+            st.acquire()
+    finally:
+        st.close()
+
+
+def test_buffer_is_not_handed_out_before_its_data_shard_writes(tmp_path,
+                                                               monkeypatch):
+    """With the writer lane held back on the first data-shard write,
+    the scheduler runs out of buffers and WAITS: the buffer under that
+    write is not handed to a reader until the write has run — and the
+    shards come out byte-identical once it has."""
+    import threading
+    import time
+
+    gate = threading.Event()
+    handed, held = [], []
+    real_acquire = fleet._Staging.acquire
+    real_write = fleet._write_data_shards
+
+    def acquire(self):
+        buf = real_acquire(self)
+        handed.append(id(buf))
+        return buf
+
+    def write(base, arr, done):
+        if not held:
+            held.append(id(arr.base))
+            gate.wait(30)
+        real_write(base, arr, done)
+
+    monkeypatch.setattr(fleet._Staging, "acquire", acquire)
+    monkeypatch.setattr(fleet, "_write_data_shards", write)
+    bases = _make_volumes(str(tmp_path), [24 * ROW + 9], seed=33)
+    twins = _serial_twin(bases)
+    ec.write_ec_files(twins[0], backend="numpy", large_block=ROOMY,
+                      small_block=SMALL)
+    errors = []
+
+    def run():
+        try:
+            fleet.fleet_write_ec_files(
+                bases, backend="numpy", large_block=ROOMY, small_block=SMALL,
+                chunk=2 * ROW, readers=1, depth=1)
+        except BaseException as e:  # surfaced below
+            errors.append(e)
+
+    t = threading.Thread(target=run, daemon=True)
+    t.start()
+    share = 2 + 1 + 1 + 1            # 2 prefetched spans, one a buffer
+    deadline = time.monotonic() + 10
+    while len(handed) < share and time.monotonic() < deadline:
+        time.sleep(0.01)
+    time.sleep(0.3)                  # room for a wrong hand-out
+    try:
+        assert len(handed) == share and len(set(handed)) == share
+        assert handed.count(held[0]) == 1
+    finally:
+        gate.set()
+        t.join(30)
+    assert not t.is_alive() and not errors
+    assert len(handed) == 13 and handed.count(held[0]) >= 2
+    _assert_shards_equal(bases, twins)
+
+
+def test_failed_pass_returns_every_staging_buffer(tmp_path, monkeypatch):
+    """An error latched in the pipeline skips the closures that release
+    buffers; the pass still gives back every buffer it filled, and the
+    next pass of the geometry runs in them."""
+    real = fleet._write_parity_span
+    seen = []
+
+    def failing(base, seg):
+        seen.append(base)
+        if len(seen) == 3:
+            raise OSError("no space left on device")
+        real(base, seg)
+
+    monkeypatch.setattr(fleet, "_write_parity_span", failing)
+    bases = _make_volumes(str(tmp_path), [20 * ROW, 20 * ROW], seed=34)
+    fresh0 = _handed("fresh")
+    with pytest.raises(OSError, match="no space left"):
+        fleet.fleet_write_ec_files(bases, backend="numpy", large_block=ROOMY,
+                                   small_block=SMALL, chunk=2 * ROW)
+    made = int(_handed("fresh") - fresh0)
+    assert made >= 2
+    assert len(fleet._IDLE_STAGING._bufs) == made
+    monkeypatch.setattr(fleet, "_write_parity_span", real)
+    fresh1, reused1 = _handed("fresh"), _handed("reused")
+    twins = _serial_twin(bases)
+    for t in twins:
+        ec.write_ec_files(t, backend="numpy", large_block=ROOMY,
+                          small_block=SMALL)
+    fleet.fleet_write_ec_files(bases, backend="numpy", large_block=ROOMY,
+                               small_block=SMALL, chunk=2 * ROW)
+    _assert_shards_equal(bases, twins)
+    share = 2 + 1 + 2 + 1            # 4 prefetched spans, two a buffer
+    assert _handed("fresh") - fresh1 == share - made
+    assert _handed("reused") - reused1 == 20 - (share - made)
+
+
+def test_concurrent_passes_share_nothing_but_the_idle_list(tmp_path):
+    """Several schedulers at once in one process (one a device, parallel
+    generate RPCs): each has its own share, none waits for another's
+    buffers, all stay byte-identical."""
+    import sys
+    import threading
+
+    jobs = []
+    for n in range(6):
+        root = tmp_path / f"job{n}"
+        root.mkdir()
+        bases = _make_volumes(str(root), [7 * ROW + n, 3 * ROW, 700 + n],
+                              seed=40 + n)
+        twins = _serial_twin(bases)
+        for t in twins:
+            ec.write_ec_files(t, backend="numpy", large_block=ROOMY,
+                              small_block=SMALL)
+        jobs.append((bases, twins))
+    errors = []
+
+    def run(bases):
+        try:
+            fleet.fleet_write_ec_files(bases, backend="numpy",
+                                       large_block=ROOMY, small_block=SMALL,
+                                       chunk=2 * ROW)
+        except BaseException as e:  # surfaced below
+            errors.append(e)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=run, args=(b,), daemon=True)
+                   for b, _ in jobs]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not errors and not any(t.is_alive() for t in threads)
+    for bases, twins in jobs:
+        _assert_shards_equal(bases, twins)
+    assert len(fleet._IDLE_STAGING._bufs) <= 8

@@ -281,7 +281,8 @@ def test_phase_timers_allocate_no_span_when_tracing_is_off(path, tmp_path):
     place = RsDispatchSecondsHistogram.labels("place")
     pack = FleetStageSecondsHistogram.labels("pack")
     reader = FleetWaitSecondsHistogram.labels("reader")
-    counted = (place.count, pack.count, reader.count)
+    staging = FleetWaitSecondsHistogram.labels("staging")
+    counted = (place.count, pack.count, reader.count, staging.count)
     first_id = trace.next_span_id()
     if path == "apply_matrix":
         data = rng.integers(0, 256, (2, DATA_SHARDS, 4096), dtype=np.uint8)
@@ -301,6 +302,7 @@ def test_phase_timers_allocate_no_span_when_tracing_is_off(path, tmp_path):
         assert place.count > counted[0]
         assert pack.count > counted[1]
         assert reader.count == counted[2] + 8      # 4 one-row spans each
+        assert staging.count == counted[3] + 4     # 2 spans a buffer
     assert trace.next_span_id() == first_id + 1, \
         "a disabled tracer still allocated spans"
     assert trace.spans() == []

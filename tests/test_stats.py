@@ -453,7 +453,8 @@ def traced_jax_fleet_encode(tmp_path_factory):
     for twin in twins:
         encoder.write_ec_files(twin, backend="numpy", large_block=large,
                                small_block=small)
-    waits = ("reader", "retire_slot", "lane_from_pack", "lane_from_retire")
+    waits = ("reader", "retire_slot", "lane_from_pack", "lane_from_retire",
+             "staging")
     before = _hist_counts(FleetWaitSecondsHistogram, waits)
     trace.disable()
     trace.clear()
@@ -473,6 +474,8 @@ def traced_jax_fleet_encode(tmp_path_factory):
 
 
 @pytest.mark.parametrize("stage, thread, names", [
+    ("fleet.encode", "caller",
+     ("fleet.dispatch", "fleet.wait.reader", "fleet.wait.staging")),
     ("fleet.dispatch", "caller",
      ("fleet.pack", "rs.stage", "rs.place", "rs.enqueue")),
     ("fleet.retire", "fleet-retire",
@@ -510,6 +513,10 @@ def test_fleet_reader_wait_counts_one_observation_a_span(
     # one retire slot a dispatch; one lane put a data write and a parity
     dispatches = len([s for s in run["spans"] if s.name == "fleet.dispatch"])
     assert run["waits"]["retire_slot"] == dispatches
+    # one staging buffer a dispatch, waited for where the span is planned
+    assert run["waits"]["staging"] == dispatches == 3
+    assert len([s for s in run["spans"]
+                if s.name == "fleet.wait.staging"]) == dispatches
     assert run["waits"]["lane_from_pack"] == len(reads)
     assert run["waits"]["lane_from_retire"] == len(reads)
 
