@@ -116,15 +116,26 @@ def generate_ec_shards_batch(store: Store, vids: Sequence[int],
     return bases
 
 
-def rebuild_ec_shards(store: Store, vid: int, collection: Optional[str] = None,
-                      backend: str = "auto") -> List[int]:
-    """VolumeEcShardsRebuild: regenerate missing .ecNN from >=10 local
-    ones. Returns rebuilt shard ids."""
-    base = _find_ec_base(store, vid, collection)
-    if base is None:
-        raise EcShardNotFound(f"no local ec files for volume {vid}")
-    with trace.span("store_ec.rebuild", vid=vid):
-        return encoder.rebuild_ec_files(base, backend=backend)
+def rebuild_ec_shards_batch(store: Store, vids: Sequence[int],
+                            collection: Optional[str] = None,
+                            backend: str = "auto") -> Dict[int, List[int]]:
+    """VolumeEcShardsRebuild for MANY volumes in one pass of the fleet
+    scheduler (ec/fleet.py): volumes that miss the same shards and hold
+    the same survivors share a decode matrix and their spans fuse into
+    shared RS dispatches. Every volume is located BEFORE any file is
+    written. Shard bytes are identical to `encoder.rebuild_ec_files`
+    per volume (the serial reference). Returns {vid: rebuilt shard
+    ids}."""
+    bases: Dict[int, str] = {}
+    for vid in vids:
+        base = _find_ec_base(store, vid, collection)
+        if base is None:
+            raise EcShardNotFound(f"no local ec files for volume {vid}")
+        bases[vid] = base
+    with trace.span("store_ec.rebuild_batch", volumes=len(bases)):
+        rebuilt = fleet.fleet_rebuild_ec_files(list(bases.values()),
+                                               backend=backend)
+    return {vid: rebuilt[base] for vid, base in bases.items()}
 
 
 def mount_ec_shards(store: Store, vid: int, collection: str,

@@ -809,18 +809,25 @@ class VolumeServer:
         return volume_server_pb2.VolumeEcShardsGenerateResponse()
 
     def VolumeEcShardsRebuild(self, request, context):
+        # every listed volume (an old client's volume_id alone: a list
+        # of one) in one pass of the fleet scheduler, fused by
+        # (present, missing) signature
+        vids = list(request.volume_ids) or [request.volume_id]
         try:
-            rebuilt = store_ec.rebuild_ec_shards(
-                self.store, request.volume_id,
-                collection=request.collection or None,
+            rebuilt = store_ec.rebuild_ec_shards_batch(
+                self.store, vids, collection=request.collection or None,
                 backend=request.encoder or self.ec_encoder)
         except EcShardNotFound as e:
             context.abort(grpc.StatusCode.NOT_FOUND, str(e))
-        if rebuilt:
-            # rebuilt shard bytes supersede any reconstructed spans
-            self._invalidate_volume_cache(request.volume_id, "rebuild")
+        for vid, sids in rebuilt.items():
+            if sids:
+                # rebuilt shard bytes supersede any reconstructed spans
+                self._invalidate_volume_cache(vid, "rebuild")
         return volume_server_pb2.VolumeEcShardsRebuildResponse(
-            rebuilt_shard_ids=rebuilt)
+            rebuilt_shard_ids=rebuilt[vids[0]],
+            results=[volume_server_pb2.VolumeEcShardsRebuildResult(
+                volume_id=vid, rebuilt_shard_ids=sids)
+                for vid, sids in rebuilt.items()])
 
     def VolumeEcShardsCopy(self, request, context):
         vid = request.volume_id

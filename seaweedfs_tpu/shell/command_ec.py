@@ -215,7 +215,8 @@ def _spread_ec_shards(env: CommandEnv, vid: int, collection: str,
 @command("ec.rebuild", "regenerate missing EC shards on the roomiest node")
 def ec_rebuild(env: CommandEnv, argv: List[str], out) -> None:
     p = argparse.ArgumentParser(prog="ec.rebuild")
-    p.add_argument("-collection", default="")
+    p.add_argument("-collection", default="",
+                   help="only the EC volumes of this collection")
     p.add_argument("-encoder", default="")
     args = p.parse_args(argv)
     encoder = {"tpu": "jax"}.get(args.encoder, args.encoder)
@@ -223,8 +224,18 @@ def ec_rebuild(env: CommandEnv, argv: List[str], out) -> None:
     try:
         nodes = env.collect_ec_nodes()
         collections = _ec_collections(env)  # one topology RPC for all vids
-        vids = sorted({vid for n in nodes for vid in n.shards})
-        for vid in vids:
+        # Every degraded volume of a collection goes to the rebuilder in
+        # ONE VolumeEcShardsRebuild RPC, so the server rebuilds them in
+        # one pass of the fleet scheduler (store_ec.
+        # rebuild_ec_shards_batch -> ec/fleet.py: volumes that lost the
+        # same shards share a decode matrix and their RS dispatches)
+        # instead of one serial pass a volume.
+        # collection -> vid -> missing shard ids
+        groups: Dict[str, Dict[int, List[int]]] = {}
+        for vid in sorted({vid for n in nodes for vid in n.shards}):
+            collection = collections.get(vid, "")
+            if args.collection and collection != args.collection:
+                continue
             missing = ec_common.missing_shards(nodes, vid)
             if not missing:
                 continue
@@ -233,19 +244,103 @@ def ec_rebuild(env: CommandEnv, argv: List[str], out) -> None:
                           f"{TOTAL_SHARDS - len(missing)} shards left, "
                           f"cannot rebuild\n")
                 continue
-            _rebuild_one(env, nodes, vid, missing, encoder,
-                         collections.get(vid, ""), out)
+            groups.setdefault(collection, {})[vid] = missing
+        if not groups:
+            return
+        rebuilder = ec_common.pick_rebuilder(nodes)
+        failures: List[str] = []
+        for collection in sorted(groups):
+            _rebuild_group(env, nodes, rebuilder, collection,
+                           groups[collection], encoder, out, failures)
+        if failures:
+            raise RuntimeError("ec.rebuild failed: " + "; ".join(failures))
     finally:
         env.release_lock()
 
 
-def _rebuild_one(env: CommandEnv, nodes: List[EcNode], vid: int,
-                 missing: List[int], encoder: str, collection: str,
-                 out) -> None:
-    rebuilder = ec_common.pick_rebuilder(nodes)
+def _rebuild_group(env: CommandEnv, nodes: List[EcNode], rebuilder: EcNode,
+                   collection: str, degraded: Dict[int, List[int]],
+                   encoder: str, out, failures: List[str]) -> None:
+    """pull -> ONE fused rebuild -> mount + drop the scaffolding, for
+    the degraded volumes of one collection. One volume's failure to
+    pull or mount must not strand the rest."""
+    stub = env.volume_server(rebuilder.url)
+
+    def failed(what: str) -> None:
+        failures.append(what)
+        out.write(what + "\n")
+
+    def drop(vid: int, sids: List[int]) -> None:
+        if sids:
+            stub.VolumeEcShardsDelete(
+                volume_server_pb2.VolumeEcShardsDeleteRequest(
+                    volume_id=vid, collection=collection, shard_ids=sids))
+
+    def drop_quietly(vid: int, sids: List[int]) -> None:
+        """After a failure that is already reported: the scaffolding
+        must not stay on the rebuilder."""
+        try:
+            drop(vid, sids)
+        # lint: swallow-ok(best effort: the failure before it is the one reported)
+        except Exception:
+            pass
+
+    pulled: Dict[int, List[int]] = {}
+    with trace.span("shell.ec_rebuild.pull", rebuilder=rebuilder.url,
+                    volumes=len(degraded)):
+        for vid in degraded:
+            try:
+                pulled[vid] = _pull_survivors(env, nodes, rebuilder, vid,
+                                              collection)
+            except Exception as e:
+                failed(f"volume {vid}: pull failed: {e}")
+    vids = list(pulled)
+    if not vids:
+        return
+    try:
+        with trace.span("shell.ec_rebuild.rebuild", rebuilder=rebuilder.url,
+                        volumes=len(vids)):
+            resp = stub.VolumeEcShardsRebuild(
+                volume_server_pb2.VolumeEcShardsRebuildRequest(
+                    volume_id=vids[0], volume_ids=vids,
+                    collection=collection, encoder=encoder))
+    except Exception as e:
+        failed(f"volumes {vids}: rebuild failed: {e}")
+        for vid in vids:
+            drop_quietly(vid, pulled[vid])
+        return
+    # a server from before volume_ids rebuilt volume_id alone
+    rebuilt = {r.volume_id: list(r.rebuilt_shard_ids)
+               for r in resp.results} or \
+        {vids[0]: list(resp.rebuilt_shard_ids)}
+    for vid in vids:
+        missing = degraded[vid]
+        # the scaffolding: pulled copies, plus shards the local rebuild
+        # regenerated that other nodes still hold (would be duplicates)
+        scaffolding = sorted(set(pulled[vid]) |
+                             (set(rebuilt.get(vid, ())) - set(missing)))
+        try:
+            if vid not in rebuilt:
+                raise RuntimeError(f"{rebuilder.url} did not rebuild it")
+            stub.VolumeEcShardsMount(
+                volume_server_pb2.VolumeEcShardsMountRequest(
+                    volume_id=vid, collection=collection,
+                    shard_ids=missing))
+            drop(vid, scaffolding)
+        except Exception as e:
+            failed(f"volume {vid}: ec.rebuild failed: {e}")
+            drop_quietly(vid, scaffolding)
+            continue
+        out.write(f"volume {vid}: rebuilt shards {missing} on "
+                  f"{rebuilder.url}\n")
+
+
+def _pull_survivors(env: CommandEnv, nodes: List[EcNode], rebuilder: EcNode,
+                    vid: int, collection: str) -> List[int]:
+    """Copy enough foreign shards of `vid` (files only, no mount) for
+    the rebuilder to hold >= 10; returns the shard ids pulled."""
     local = rebuilder.shards.get(vid, ShardBits(0))
-    # pull enough foreign shards (files only, no mount) to reach >=10
-    pulled = []
+    pulled: List[int] = []
     for n in nodes:
         if n.url == rebuilder.url:
             continue
@@ -261,22 +356,7 @@ def _rebuild_one(env: CommandEnv, nodes: List[EcNode], vid: int,
                     copy_ecj_file=not local.count and not pulled,
                     source_data_node=n.url))
             pulled.append(sid)
-    resp = env.volume_server(rebuilder.url).VolumeEcShardsRebuild(
-        volume_server_pb2.VolumeEcShardsRebuildRequest(
-            volume_id=vid, collection=collection, encoder=encoder))
-    env.volume_server(rebuilder.url).VolumeEcShardsMount(
-        volume_server_pb2.VolumeEcShardsMountRequest(
-            volume_id=vid, collection=collection, shard_ids=missing))
-    # drop the scaffolding: pulled copies, plus shards the local rebuild
-    # regenerated that other nodes still hold (would be duplicates)
-    to_delete = sorted(set(pulled) |
-                       (set(resp.rebuilt_shard_ids) - set(missing)))
-    if to_delete:
-        env.volume_server(rebuilder.url).VolumeEcShardsDelete(
-            volume_server_pb2.VolumeEcShardsDeleteRequest(
-                volume_id=vid, collection=collection, shard_ids=to_delete))
-    out.write(f"volume {vid}: rebuilt shards {missing} on "
-              f"{rebuilder.url}\n")
+    return pulled
 
 
 def _ec_collections(env: CommandEnv) -> Dict[int, str]:

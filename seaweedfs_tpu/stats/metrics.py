@@ -335,6 +335,16 @@ FleetDispatchBatchHistogram = REGISTRY.histogram(
 FleetDispatchedBytesCounter = REGISTRY.counter(
     "SeaweedFS_fleet_dispatched_bytes_total",
     "data bytes through fused RS dispatches")
+FleetRebuildVolumesCounter = REGISTRY.counter(
+    "SeaweedFS_fleet_rebuild_volumes_total",
+    "volumes whose missing shards a fleet rebuild pass wrote")
+FleetRebuildGroupsCounter = REGISTRY.counter(
+    "SeaweedFS_fleet_rebuild_groups_total",
+    "(present, missing) signatures of fleet rebuild passes: the "
+    "volumes of one share its decode matrix and its dispatches")
+FleetRebuiltBytesCounter = REGISTRY.counter(
+    "SeaweedFS_fleet_rebuilt_bytes_total",
+    "bytes appended to rebuilt shard files by fleet rebuild passes")
 FleetWriterBacklogGauge = REGISTRY.gauge(
     "SeaweedFS_fleet_writer_lane_backlog",
     "writes queued on one writer lane", ("lane",))

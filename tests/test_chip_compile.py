@@ -69,12 +69,16 @@ def test_described_device_is_the_kind_the_peaks_table_knows(topo):
     assert bench.device_peaks(kind)["hbm_gbps"] == 819.0
 
 
+# matrix rows: 4 = the parity matrix of ec.encode, 2 = the decode matrix
+# of ec.rebuild after a loss of two shards (benchmark cell
+# node-repair.rebuild)
+@pytest.mark.parametrize("rows", [4, 2])
 @pytest.mark.parametrize("lanes", [LANES_MIN, LANES_MAX])
-def test_gf_linear_compiles_for_the_chip(one_chip, lanes):
+def test_gf_linear_compiles_for_the_chip(one_chip, lanes, rows):
     import jax
     from seaweedfs_tpu.ops.rs_kernel import gf_linear
     compiled = jax.jit(gf_linear).lower(
-        _m2(one_chip), _data(one_chip, lanes)).compile()
+        _m2(one_chip, rows), _data(one_chip, lanes)).compile()
     assert compiled is not None
     assert compiled.memory_analysis() is not None
 

@@ -19,6 +19,7 @@ from seaweedfs_tpu import ec
 from seaweedfs_tpu.ec import fleet, store_ec
 from seaweedfs_tpu.ec.encoder import shard_file_name
 from seaweedfs_tpu.ops.rs_code import ReedSolomon, DATA_SHARDS, TOTAL_SHARDS
+from seaweedfs_tpu.stats.metrics import FleetRebuildGroupsCounter
 
 LARGE = 2048
 SMALL = 256
@@ -146,6 +147,85 @@ def test_fleet_rebuild_byte_identical(tmp_path):
             with open(shard_file_name(base, sid), "rb") as f:
                 assert f.read() == originals[(base, sid)], \
                     f"shard {sid} of {base}"
+
+
+GIB_SHARD = -(-(1 << 30) // DATA_SHARDS)
+
+
+@pytest.mark.parametrize("chunk, sizes, span, per_batch", [
+    # the cell node-repair.rebuild: both volumes in every dispatch
+    (128 << 20, [GIB_SHARD] * 2, 6316129, 2),
+    # the one-volume_id request
+    (128 << 20, [GIB_SHARD], 11930465, 1),
+    # the deployment's published scale: spans stop at a small block and
+    # a dispatch stacks as many as fill a chunk, not one a volume
+    (128 << 20, [GIB_SHARD] * 128, 1042468, 12),
+    # small volumes: whole shards, all stacked
+    (128 << 20, [40_000] * 32, 40_000, 32),
+    # one big volume among small ones
+    (128 << 20, [GIB_SHARD] + [40_000] * 127, 1042468, 12),
+    # host backends' chunk
+    (16 << 20, [GIB_SHARD] * 2, 1042468, 1),
+    # a chunk below a small block a row (tests): one span a dispatch
+    (512, [700] * 3, 50, 1),
+    (1, [0], 1, 1),
+])
+def test_rebuild_span_rule(chunk, sizes, span, per_batch):
+    """`chunk` is the input bytes of ALL ten rows of one stacked
+    dispatch, as in encode."""
+    assert fleet._stacked_spans(chunk, sizes) == (span, per_batch)
+    assert DATA_SHARDS * span * per_batch <= max(chunk, DATA_SHARDS)
+    assert span <= max(1, max(sizes)) and per_batch <= len(sizes)
+
+
+def test_rebuild_of_many_volumes_in_one_group_keeps_its_spans_wide(
+        tmp_path, monkeypatch):
+    """48 volumes that lost the same shards are ONE group. Their spans
+    stop narrowing at a small block (here 512 bytes), so the pass makes
+    a bounded number of reads (ten opens each) and dispatches: without
+    the floor it would cut every shard into spans of 51 bytes, 24 times
+    the reads."""
+    small_block = 512
+    monkeypatch.setattr(fleet, "SMALL_BLOCK_SIZE", small_block)
+    n, chunk = 48, DATA_SHARDS * 4 * small_block
+    bases = _make_volumes(str(tmp_path), [5 * ROW] * n, seed=9)
+    fleet.fleet_write_ec_files(bases, backend="numpy", large_block=LARGE,
+                               small_block=SMALL)
+    shard_size = os.path.getsize(shard_file_name(bases[0], 0))
+    assert shard_size == 5 * SMALL
+    originals = {(b, sid): open(shard_file_name(b, sid), "rb").read()
+                 for b in bases for sid in (0, 3)}
+    for base in bases:
+        for sid in (0, 3):
+            os.remove(shard_file_name(base, sid))
+    reads, batches = [], []
+    read, reconstruct = fleet._read_present_span, \
+        fleet._Dispatcher.reconstruct
+
+    def counted_read(*args):
+        reads.append(args[4])
+        return read(*args)
+
+    def counted_reconstruct(self, present, missing, arrays):
+        batches.append(len(arrays))
+        return reconstruct(self, present, missing, arrays)
+
+    monkeypatch.setattr(fleet, "_read_present_span", counted_read)
+    monkeypatch.setattr(fleet._Dispatcher, "reconstruct",
+                        counted_reconstruct)
+    groups = FleetRebuildGroupsCounter.labels().value
+    rebuilt = fleet.fleet_rebuild_ec_files(bases, backend="numpy",
+                                           chunk=chunk)
+    assert FleetRebuildGroupsCounter.labels().value - groups == 1
+    assert rebuilt == {b: [0, 3] for b in bases}
+    for (base, sid), want in originals.items():
+        with open(shard_file_name(base, sid), "rb") as f:
+            assert f.read() == want, f"shard {sid} of {base}"
+    span, per_batch = fleet._stacked_spans(chunk, [shard_size] * n)
+    assert (span, per_batch) == (427, 4)
+    assert set(reads) == {span}
+    assert len(reads) == n * 3                  # three spans a shard
+    assert batches == [per_batch] * (n * 3 // per_batch)
 
 
 def test_rebuild_wanted_partial(tmp_path):

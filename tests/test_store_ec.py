@@ -82,7 +82,7 @@ def test_rebuild_restores_shard_files(store):
     for sid in (1, 13):
         ecv.unmount_shard(sid)
         os.remove(shard_file_name(base, sid))
-    rebuilt = store_ec.rebuild_ec_shards(store, 3)
+    rebuilt = store_ec.rebuild_ec_shards_batch(store, [3])[3]
     assert sorted(rebuilt) == [1, 13]
     for sid in (1, 13):
         got = hashlib.sha256(
@@ -159,7 +159,7 @@ def test_collection_volumes_resolve_without_collection_arg(store):
     ecv.small_block, ecv.large_block = SMALL, SMALL << 8
     os.remove(shard_file_name(base, 4))
     ecv.unmount_shard(4)
-    assert store_ec.rebuild_ec_shards(store, 8) == [4]
+    assert store_ec.rebuild_ec_shards_batch(store, [8]) == {8: [4]}
     store_ec.unmount_ec_shards(store, 8, range(14))
     store_ec.ec_shards_to_volume(store, 8, small_block=SMALL,
                                  large_block=SMALL << 8)
