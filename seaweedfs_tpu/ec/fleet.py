@@ -19,6 +19,9 @@ directive; the BASELINE "cluster-wide ec.encode" shape):
             so per-volume row order is preserved by construction
             while reads overlap compute. The buffers are reused from
             dispatch to dispatch and from pass to pass (`_Staging`).
+            A rebuild pass runs the same loop (`_staged_pass`): its
+            readers fill the buffer's ten rows from the ten surviving
+            shard files.
   dispatch  the jax backend is async already; sync host backends
             (native/numpy) are lifted to the same handle contract by
             a small encode pool, so RS compute itself runs multi-core
@@ -90,8 +93,8 @@ FLEET_READERS = 4
 
 # Fused dispatches in flight at once — the writer-queue bound, same
 # double-buffering role as encoder.PIPELINE_DEPTH. With the reader
-# prefetch it sets an encode pass's share of staging buffers (see
-# `_Staging`), which is the pass's peak host memory.
+# prefetch it sets a pass's share of staging buffers (see `_Staging`),
+# which is an encode or rebuild pass's peak host memory.
 FLEET_DEPTH = 2
 
 # Encode pool for synchronous host backends: ctypes/numpy release the
@@ -328,7 +331,8 @@ class _Dispatcher:
     and the handles are gathered. Either way .result() yields per-span
     output arrays. The `pack` stage times what the packing thread does
     to make a fused dispatch's input: a slice of the staging buffer for
-    `encode_lanes`, a stacking copy for `encode` and `reconstruct`.
+    `encode_lanes` and `reconstruct_lanes`, a stacking copy for
+    `encode` (verify's entry).
     """
 
     def __init__(self, rs: ReedSolomon, device=None,
@@ -342,25 +346,45 @@ class _Dispatcher:
                 max_workers=max(1, encoders),
                 thread_name_prefix="fleet-encode")
 
-    def encode_lanes(self, buf: np.ndarray, cuts: List[Tuple[int, int]],
-                     done: Callable[[], None]):
-        """Parity of a staging buffer [10, lanes] whose spans lie at
-        `cuts` = [(lane offset, lanes)], back to back from lane 0:
-        .result() yields one [4, lanes] array a span. The jax branch
-        hands the filled lanes over as they are — no copy before the
-        dispatch layer's slab slices. `done` runs when every read of
-        `buf` on behalf of this dispatch is over (retire thread)."""
+    def _lanes(self, op: str, apply_async, apply, buf: np.ndarray,
+               cuts: List[Tuple[int, int]], done: Callable[[], None]):
+        """One GF map over a staging buffer [10, lanes] whose spans lie
+        at `cuts` = [(lane offset, lanes)], back to back from lane 0:
+        .result() yields one [rows out, lanes] array a span. The jax
+        branch hands the filled lanes over as they are, a 2-D view: no
+        copy before the dispatch layer's slab slices, none after its
+        fetch. Host backends get one pool task a span, each a view.
+        `done` runs when every read of `buf` on behalf of this dispatch
+        is over (retire thread)."""
         if _failpoint._armed:
-            _failpoint.hit("fleet.dispatch", op="encode")
+            _failpoint.hit("fleet.dispatch", op=op)
         if self._pool is None:
             with _StageTimer("pack", spans=len(cuts)):
                 data = buf[:, :cuts[-1][0] + cuts[-1][1]]
-            handle = self._rs.encode_async(data, device=self._device)
+            handle = apply_async(data, device=self._device)
             return _SplitHandle(handle, [n for _, n in cuts], 1, done)
         token = trace.handoff()
-        return _Gathered([self._pool.submit(_rs_staged, self._rs.encode,
+        return _Gathered([self._pool.submit(_rs_staged, apply,
                                             buf[:, off:off + n], token)
                           for off, n in cuts], done)
+
+    def encode_lanes(self, buf: np.ndarray, cuts: List[Tuple[int, int]],
+                     done: Callable[[], None]):
+        """Parity [4, lanes] of every span of a staging buffer."""
+        return self._lanes("encode", self._rs.encode_async,
+                           self._rs.encode, buf, cuts, done)
+
+    def reconstruct_lanes(self, present, missing, buf: np.ndarray,
+                          cuts: List[Tuple[int, int]],
+                          done: Callable[[], None]):
+        """Shards `missing` [len(missing), lanes] of every span of a
+        staging buffer whose rows are the first ten of `present`."""
+        return self._lanes(
+            "reconstruct",
+            functools.partial(self._rs.reconstruct_some_async, present,
+                              missing),
+            functools.partial(self._rs.reconstruct_some, present, missing),
+            buf, cuts, done)
 
     def encode(self, arrays: List[np.ndarray]):
         if _failpoint._armed:
@@ -376,22 +400,6 @@ class _Dispatcher:
         return _Gathered([self._pool.submit(_rs_staged, self._rs.encode,
                                             a, token)
                           for a in arrays])
-
-    def reconstruct(self, present, missing, arrays: List[np.ndarray]):
-        if _failpoint._armed:
-            _failpoint.hit("fleet.dispatch", op="reconstruct")
-        if self._pool is None:
-            with _StageTimer("pack", spans=len(arrays)):
-                src = np.stack(arrays, axis=0)  # [B, 10, span]
-            handle = self._rs.reconstruct_some_async(
-                present, missing, src, device=self._device)
-            return _UnstackHandle(handle)
-        token = trace.handoff()
-        return _Gathered([self._pool.submit(
-            _rs_staged,
-            functools.partial(self._rs.reconstruct_some, present, missing),
-            a, token)
-            for a in arrays])
 
     def close(self) -> None:
         if self._pool is not None:
@@ -416,17 +424,6 @@ class _SplitHandle:
         if self._done is not None:
             self._done()
         return np.split(out, np.cumsum(self._sizes)[:-1], axis=self._axis)
-
-
-class _UnstackHandle:
-    """Adapt one fused [B, ...] reconstruct handle to per-item outputs."""
-
-    def __init__(self, handle):
-        self._handle = handle
-
-    def result(self) -> List[np.ndarray]:
-        out = self._handle.result()
-        return [out[i] for i in range(out.shape[0])]
 
 
 class _VolState:
@@ -466,31 +463,43 @@ def _round_robin_spans(vols: List[_VolState], span_rows: int):
 
 
 class _IdleStaging:
-    """The process's staging buffers between encode passes, so that the
-    next pass (the next shell command) fills memory that is already
-    mapped instead of faulting in fresh pages. Buffers of ONE width —
-    the last pass's: a server encodes with one geometry, and odd widths
-    must not pile up. Only buffers that were filled before come here
-    (an untouched np.empty is address space, not memory), at most one
+    """The process's staging buffers between passes, so that the next
+    pass (the next shell command) fills memory that is already mapped
+    instead of faulting in fresh pages. Buffers are kept by CAPACITY,
+    [10, capacity] with ONE capacity at a time, and a pass borrows
+    `[:, :lanes]` views of them: an encode pass's width is set by its
+    chunk, a rebuild pass's by the largest shard it was given, and a
+    server that alternates them (or rebuilds volumes of another size
+    every command) must not throw its pages away each time. The
+    capacity is the widest pass so far, rounded up to a small block —
+    an encode pass's width as it is — and only grows, so odd widths
+    cannot pile up. Only buffers that were filled before come here (an
+    untouched np.empty is address space, not memory), at most one
     pass's share: that is all the memory the scheduler keeps resident
     while idle."""
 
     def __init__(self):
         self._lock = threading.Lock()
-        self._lanes = 0
+        self._capacity = 0
         self._bufs: List[np.ndarray] = []
 
-    def take(self, lanes: int, n: int) -> List[np.ndarray]:
+    def take(self, lanes: int, n: int) -> Tuple[int, List[np.ndarray]]:
+        """(capacity a pass of `lanes` makes its new buffers with, up to
+        `n` idle buffers of that capacity)."""
         with self._lock:
-            if lanes != self._lanes:
-                self._lanes, self._bufs = lanes, []
+            if lanes > self._capacity:  # what is idle is too narrow
+                self._capacity = -(-lanes // SMALL_BLOCK_SIZE) \
+                    * SMALL_BLOCK_SIZE
+                self._bufs = []
             taken, self._bufs = self._bufs[:n], self._bufs[n:]
-            return taken
+            return self._capacity, taken
 
-    def give(self, lanes: int, bufs: List[np.ndarray], keep: int) -> None:
+    def give(self, bufs: List[np.ndarray], keep: int) -> None:
         with self._lock:
-            if lanes == self._lanes:  # else another geometry took over
-                self._bufs.extend(bufs[:max(0, keep - len(self._bufs))])
+            room = max(0, keep - len(self._bufs))
+            # narrower ones: a wider pass came by since they were made
+            self._bufs.extend([b for b in bufs
+                               if b.shape[1] == self._capacity][:room])
 
 
 _IDLE_STAGING = _IdleStaging()
@@ -500,27 +509,29 @@ class _StagedBatch:
     """One staging buffer on its way through a pass: the spans planned
     into it, and how many closures still have to read it."""
 
-    __slots__ = ("buf", "rows", "spans", "refs")
+    __slots__ = ("buf", "used", "spans", "refs")
 
     def __init__(self, buf: np.ndarray):
         self.buf = buf
-        self.rows = 0
-        self.spans: List[Tuple[_VolState, int, int]] = []  # vol, lane, lanes
+        self.used = 0  # lanes planned so far
+        # vol, first lane, lanes of the span that go out to its files
+        self.spans: List[Tuple[_VolState, int, int]] = []
         self.refs = 0
 
 
 class _Staging:
-    """One encode pass's share of staging buffers.
+    """One encode or rebuild pass's share of staging buffers.
 
     A staging buffer is [DATA_SHARDS, lanes] uint8 — a fused dispatch's
     input in the layout the device wants. The readers fill it straight
-    from the .dat files, the dispatch layer places slices of it, the
+    from the .dat files (encode) or the surviving shard files
+    (rebuild), the dispatch layer places slices of it, an encode pass's
     writer lanes write the data shards out of it; then it comes round
     again. The share is what the pipeline has in flight anyway (see
-    fleet_write_ec_files), so the one place a pass can block here —
-    `acquire`, timed as fleet.wait.staging — blocks only while buffers
-    are downstream of the packing thread, where they come back without
-    its help. Passes share nothing but the idle list, so concurrent
+    _staged_pass), so the one place a pass can block here — `acquire`,
+    timed as fleet.wait.staging — blocks only while buffers are
+    downstream of the packing thread, where they come back without its
+    help. Passes share nothing but the idle list, so concurrent
     schedulers (one a device, parallel generate RPCs) cannot hold each
     other up.
 
@@ -534,7 +545,7 @@ class _Staging:
         self._share = share
         self._check = check  # raises the pipeline's latched error
         self._cond = threading.Condition()
-        self._held = _IDLE_STAGING.take(lanes, share)
+        self._capacity, self._held = _IDLE_STAGING.take(lanes, share)
         self._handed = 0
         self._free: deque = deque()
 
@@ -545,9 +556,9 @@ class _Staging:
                 # first round: what an earlier pass left, then new ones
                 if self._handed == len(self._held):
                     self._held.append(np.empty(
-                        (DATA_SHARDS, self._lanes), dtype=np.uint8))
+                        (DATA_SHARDS, self._capacity), dtype=np.uint8))
                     state = "fresh"
-                buf = self._held[self._handed]
+                buf = self._held[self._handed][:, :self._lanes]
             else:
                 while not self._free:
                     # woken by unref; the timeout is for a latched
@@ -574,7 +585,7 @@ class _Staging:
         reference counts say (a pass that failed leaves them open)."""
         with self._cond:
             held = list(self._held)
-        _IDLE_STAGING.give(self._lanes, held, self._share)
+        _IDLE_STAGING.give(held, self._share)
 
 
 try:  # 16 is POSIX's floor; sysconf says -1 for "no fixed limit"
@@ -624,13 +635,13 @@ def _read_span_into(base: str, row0: int, rows: int, row_bytes: int,
             b[:] = 0
 
 
-def _read_span_staged(base: str, row0: int, rows: int, row_bytes: int,
-                      small_block: int, buf: np.ndarray, off: int,
-                      parent: Optional[int]) -> None:
-    """_read_span_into on a reader-pool thread, attributed to the
-    'read' stage and parented to the scheduler's root span."""
+def _read_staged(read, base: str, buf: np.ndarray, off: int,
+                 parent: Optional[int]) -> None:
+    """One planned span's `read(buf, off)` on a reader-pool thread,
+    attributed to the 'read' stage and parented to the scheduler's root
+    span."""
     with _StageTimer("read", parent=parent, vol=os.path.basename(base)):
-        _read_span_into(base, row0, rows, row_bytes, small_block, buf, off)
+        read(buf, off)
 
 
 def _write_data_shards(base: str, arr: np.ndarray,
@@ -695,66 +706,21 @@ def fleet_write_ec_files(base_names: Sequence[str], backend: str = "auto",
     # One fused dispatch ≈ `chunk` bytes of data rows — one staging
     # buffer of batch_rows rows; span size is the per-volume slice of
     # it, so a full round across the fleet packs into one dispatch (a
-    # single volume degrades to the serial shape). A span that does not
-    # fit what is left of a buffer starts the next one.
+    # single volume degrades to the serial shape).
     batch_rows = max(1, chunk // row_bytes)
     span_rows = max(1, batch_rows // len(alive))
-    spans_per_batch = batch_rows // span_rows  # of full spans; never fewer
-    prefetch = max(readers, 2 * spans_per_batch)
 
-    dispatcher = _Dispatcher(ReedSolomon(backend=backend), device=device,
-                             encoders=encoders)
-    # lint: thread-ok(per-pass reader pool; work items are explicit, no ambient request state)
-    pool = ThreadPoolExecutor(max_workers=max(1, readers),
-                              thread_name_prefix="fleet-read")
-    pipe = TaggedPipeline(depth=depth)
-    # The pass's share of staging buffers is what the pipeline holds at
-    # once: upstream of the dispatch the prefetched spans' buffers (one
-    # more when they straddle), downstream `depth` queued dispatches
-    # and the one in the retire thread's hand, whose data-shard writes
-    # may still be queued on the lanes. Upstream never needs them all,
-    # so a pass out of buffers always has some coming back.
-    staging = _Staging(batch_rows * small_block,
-                       -(-prefetch // spans_per_batch) + 1 + depth + 1,
-                       pipe._raise_pending)
-    gen = _round_robin_spans(alive, span_rows)
-    inflight: deque = deque()
-    filling: Optional[_StagedBatch] = None
-    root = trace.span("fleet.encode", volumes=len(alive), backend=backend)
-    root.__enter__()
-    token = root.token()
+    def plan():
+        for v, row0, rows in _round_robin_spans(alive, span_rows):
+            n = rows * small_block
+            yield v, n, n, functools.partial(
+                _read_span_into, v.base, row0, rows, row_bytes, small_block)
 
-    def fill() -> None:
-        nonlocal filling
-        while len(inflight) < prefetch:
-            nxt = next(gen, None)
-            if nxt is None:
-                break
-            v, row0, rows = nxt
-            if filling is None or filling.rows + rows > batch_rows:
-                filling = _StagedBatch(staging.acquire())
-            off = filling.rows * small_block
-            filling.rows += rows
-            filling.spans.append((v, off, rows * small_block))
-            inflight.append((filling, pool.submit(
-                _read_span_staged, v.base, row0, rows, row_bytes,
-                small_block, filling.buf, off, token)))
-            # inc/dec deltas so concurrent schedulers SUM on the
-            # shared gauge instead of overwriting each other's depth
-            FleetReaderQueueGauge.inc()
-
-    def flush(batch: _StagedBatch) -> None:
-        # the buffer is free again when the retire thread has the
-        # dispatch's result (every transfer out of it is over) AND each
-        # span's data-shard write has run on its lane
-        batch.refs = 1 + len(batch.spans)
-        release = functools.partial(staging.unref, batch)
+    def flush(batch: _StagedBatch, dispatcher: _Dispatcher,
+              pipe: TaggedPipeline, release: Callable[[], None]) -> None:
         with _StageTimer("dispatch", batch=len(batch.spans)):
             handle = dispatcher.encode_lanes(
                 batch.buf, [(off, n) for _, off, n in batch.spans], release)
-        FleetDispatchBatchHistogram.observe(len(batch.spans))
-        FleetDispatchedBytesCounter.inc(
-            float(DATA_SHARDS * batch.rows * small_block))
         # data shards need no parity: straight to each volume's lane
         # (enqueued here, in pack order, so per-volume FIFO holds)
         for v, off, n in batch.spans:
@@ -764,6 +730,72 @@ def fleet_write_ec_files(base_names: Sequence[str], backend: str = "auto",
         pipe.submit(handle, [
             (v.tag, functools.partial(_write_parity_span, v.base))
             for v, _, _ in batch.spans])
+
+    # a buffer is free again when the retire thread has the dispatch's
+    # result (every transfer out of it is over) AND each span's
+    # data-shard write has run on its lane
+    _staged_pass(trace.span("fleet.encode", volumes=len(alive),
+                            backend=backend),
+                 backend, device, encoders, readers, depth,
+                 lanes=batch_rows * small_block,
+                 per_buffer=batch_rows // span_rows, plan=plan(),
+                 flush=flush, refs=lambda batch: 1 + len(batch.spans))
+
+
+def _staged_pass(root, backend: str, device, encoders: int, readers: int,
+                 depth: int, *, lanes: int, per_buffer: int, plan, flush,
+                 refs: Callable[[_StagedBatch], int]) -> None:
+    """The loop an encode and a rebuild pass share, under the span
+    `root`: plan spans into staging buffers of `lanes`, have the reader
+    pool fill them ahead of the device, dispatch a buffer when its last
+    span is read, retire through a TaggedPipeline.
+
+    `plan` yields (vol, width, n, read) in submission order: the span
+    takes `width` lanes of a buffer, `n` of them go out to the volume's
+    files, and `read(buf, off)` fills them on a reader thread ('read').
+    A span that does not fit what is left of a buffer starts the next
+    one; `per_buffer` is how many full spans a buffer holds (never
+    fewer). `flush(batch, dispatcher, pipe, release)` issues a complete
+    batch's dispatch and queues its writes; `refs(batch)` is how many
+    times what it queues will call `release`, the last of which frees
+    the buffer."""
+    dispatcher = _Dispatcher(ReedSolomon(backend=backend), device=device,
+                             encoders=encoders)
+    # lint: thread-ok(per-pass reader pool; work items are explicit, no ambient request state)
+    pool = ThreadPoolExecutor(max_workers=max(1, readers),
+                              thread_name_prefix="fleet-read")
+    pipe = TaggedPipeline(depth=depth)
+    prefetch = max(readers, 2 * per_buffer)
+    # The pass's share of staging buffers is what the pipeline holds at
+    # once: upstream of the dispatch the prefetched spans' buffers (one
+    # more when they straddle), downstream `depth` queued dispatches
+    # and the one in the retire thread's hand, whose data-shard writes
+    # may still be queued on the lanes. Upstream never needs them all,
+    # so a pass out of buffers always has some coming back.
+    staging = _Staging(lanes, -(-prefetch // per_buffer) + 1 + depth + 1,
+                       pipe._raise_pending)
+    inflight: deque = deque()
+    filling: Optional[_StagedBatch] = None
+    root.__enter__()
+    token = root.token()
+
+    def fill() -> None:
+        nonlocal filling
+        while len(inflight) < prefetch:
+            nxt = next(plan, None)
+            if nxt is None:
+                break
+            v, width, n, read = nxt
+            if filling is None or filling.used + width > lanes:
+                filling = _StagedBatch(staging.acquire())
+            off = filling.used
+            filling.used += width
+            filling.spans.append((v, off, n))
+            inflight.append((filling, pool.submit(
+                _read_staged, read, v.base, filling.buf, off, token)))
+            # inc/dec deltas so concurrent schedulers SUM on the
+            # shared gauge instead of overwriting each other's depth
+            FleetReaderQueueGauge.inc()
 
     try:
         fill()
@@ -776,7 +808,12 @@ def fleet_write_ec_files(base_names: Sequence[str], backend: str = "auto",
             # spans are planned in order, so a batch is complete when
             # the next span read belongs to another (or none is left)
             if not inflight or inflight[0][0] is not batch:
-                flush(batch)
+                batch.refs = refs(batch)
+                flush(batch, dispatcher, pipe,
+                      functools.partial(staging.unref, batch))
+                FleetDispatchBatchHistogram.observe(len(batch.spans))
+                FleetDispatchedBytesCounter.inc(
+                    float(DATA_SHARDS * batch.used))
     finally:
         FleetReaderQueueGauge.dec(len(inflight))  # error path leftovers
         pool.shutdown(wait=True)
@@ -800,10 +837,11 @@ def fleet_rebuild_ec_files(base_names: Sequence[str], backend: str = "auto",
     """Cross-volume batched `rebuild_ec_files`.
 
     Volumes sharing a (present, missing) signature share one decode
-    matrix, so their shard chunks fuse into single [B, 10, span]
-    reconstruct dispatches — the rebuild-side twin of
-    `fleet_write_ec_files`. Tail spans are zero-padded to the bucket
-    width (GF maps send 0 to 0) and trimmed on writeback. Returns
+    matrix, so their shard chunks lie side by side in one staging
+    buffer and fuse into single [10, B * span] reconstruct dispatches —
+    the rebuild-side twin of `fleet_write_ec_files`, on the same loop
+    and the same reused buffers. Tail spans are zero-padded to the span
+    width and trimmed on writeback. Returns
     {base_name: rebuilt shard ids} (empty list where nothing was
     missing).
     """
@@ -855,13 +893,13 @@ def _unlinked_on_failure(paths: List[str]):
 
 def _stacked_spans(chunk: int, shard_sizes: Sequence[int]) -> Tuple[int, int]:
     """(span, per_batch) of a rebuild pass: the width of one volume's
-    span, in bytes of ONE shard row, and how many spans stack into one
-    [B, 10, span] dispatch. `chunk` means what it means in
+    span, in bytes of ONE shard row, and how many spans lie side by
+    side in one [10, B * span] dispatch. `chunk` means what it means in
     `fleet_write_ec_files`: the input bytes of ALL ten rows of one
     fused dispatch. A span is every volume's equal share of a chunk,
     but no narrower than a small block: a span costs ten opens and
-    reads whatever its width, so a group of 128 volumes stacks 12 spans
-    of 1 MiB a dispatch, not 128 of 100 KiB. It is at most the largest
+    reads whatever its width, so a group of 128 volumes puts 12 spans
+    of 1 MiB in a dispatch, not 128 of 100 KiB. It is at most the largest
     shard (small volumes must not read and compute chunk-sized slabs of
     zero padding per 100KB shard), and the largest shard is cut into
     EQUAL spans: a last span of mostly padding costs the packing thread
@@ -884,20 +922,41 @@ def _write_rebuilt_span(base: str, missing: List[int], valid: int,
     FleetRebuiltBytesCounter.inc(float(len(missing) * valid))
 
 
+def _read_present_span_into(base: str, present: List[int], shard_size: int,
+                            offset: int, span: int, buf: np.ndarray,
+                            off: int) -> None:
+    """Bytes [offset, offset + span) of the first 10 present shards
+    straight into lanes [off, off + span) of a staging buffer, row r
+    from shard present[r]: one read a shard file, nothing in between.
+    What lies past the shard's end, or past a survivor that is shorter
+    than it should be, is zeroed on EVERY use: the buffer still holds an
+    earlier dispatch's bytes, possibly another volume's. (The map works
+    column by column and the columns past the end are trimmed on the
+    way to the file, so this keeps a dispatch's input a function of the
+    files alone, and a short survivor reading as zeros.)"""
+    want = min(span, max(shard_size - offset, 0))
+    for row, sid in enumerate(present[:DATA_SHARDS]):
+        lanes = buf[row, off:off + span]
+        got = 0
+        if want > 0:
+            fd = os.open(shard_file_name(base, sid), os.O_RDONLY)
+            try:
+                got = _preadv_full(fd, [memoryview(lanes[:want])], offset)
+            finally:
+                os.close(fd)
+        lanes[got:] = 0
+
+
 def _read_present_span(base: str, present: List[int], shard_size: int,
                        offset: int, span: int,
                        parent: Optional[int] = None) -> np.ndarray:
     """[10, span] slice at `offset` of the first 10 present shards,
-    zero-padded past shard end."""
-    with _StageTimer("read", parent=parent, vol=os.path.basename(base)):
-        src = np.zeros((DATA_SHARDS, span), dtype=np.uint8)
-        want = min(span, max(shard_size - offset, 0))
-        if want > 0:
-            for row, sid in enumerate(present[:DATA_SHARDS]):
-                with open(shard_file_name(base, sid), "rb") as f:
-                    f.seek(offset)
-                    f.readinto(memoryview(src[row])[:want])
-        return src
+    zero-padded past shard end, in an array of its own (verify)."""
+    src = np.empty((DATA_SHARDS, span), dtype=np.uint8)
+    _read_staged(functools.partial(_read_present_span_into, base, present,
+                                   shard_size, offset, span),
+                 base, src, 0, parent)
+    return src
 
 
 def _fleet_rebuild_group(present: List[int], missing: List[int],
@@ -909,77 +968,39 @@ def _fleet_rebuild_group(present: List[int], missing: List[int],
         for base, _ in members:
             for sid in missing:
                 open(shard_file_name(base, sid), "wb").close()
-    # Uniform span width so spans from different volumes stack into one
-    # [B, 10, span] dispatch of ~chunk input bytes, an encode dispatch's
-    # size.
+    # Uniform span width, per_batch spans side by side in a staging
+    # buffer: one [10, per_batch * span] dispatch of ~chunk input bytes,
+    # an encode dispatch's size.
     span, per_batch = _stacked_spans(chunk, [size for _, size in members])
-    vols = [(_VolState(base, size, -(-size // span), tag), size)
+    vols = [_VolState(base, size, -(-size // span), tag)
             for tag, (base, size) in enumerate(members)]
 
-    def gen_spans():
-        for v, row0, rows in _round_robin_spans([v for v, _ in vols], 1):
-            yield v, row0 * span
+    def plan():
+        for v, row0, _rows in _round_robin_spans(vols, 1):
+            offset = row0 * span
+            yield v, span, min(span, v.dat_size - offset), functools.partial(
+                _read_present_span_into, v.base, present, v.dat_size,
+                offset, span)
 
-    dispatcher = _Dispatcher(ReedSolomon(backend=backend), device=device,
-                             encoders=encoders)
-    # lint: thread-ok(per-pass reader pool; work items are explicit, no ambient request state)
-    pool = ThreadPoolExecutor(max_workers=max(1, readers),
-                              thread_name_prefix="fleet-read")
-    pipe = TaggedPipeline(depth=depth)
-    gen = gen_spans()
-    inflight: deque = deque()
-    prefetch = max(readers, 2 * per_batch)
-    root = trace.span("fleet.rebuild", volumes=len(members),
-                      backend=backend, groups=groups, present=present,
-                      missing=missing)
-    root.__enter__()
-    token = root.token()
-
-    def fill() -> None:
-        while len(inflight) < prefetch:
-            nxt = next(gen, None)
-            if nxt is None:
-                break
-            v, offset = nxt
-            inflight.append((v, offset, pool.submit(
-                _read_present_span, v.base, present, v.dat_size,
-                offset, span, token)))
-            FleetReaderQueueGauge.inc()  # delta: concurrent-safe sum
-
-    def flush(pack) -> None:
-        with _StageTimer("dispatch", batch=len(pack)):
-            handle = dispatcher.reconstruct(present, missing,
-                                            [a for _, _, a in pack])
-        FleetDispatchBatchHistogram.observe(len(pack))
-        FleetDispatchedBytesCounter.inc(
-            float(sum(a.nbytes for _, _, a in pack)))
+    def flush(batch: _StagedBatch, dispatcher: _Dispatcher,
+              pipe: TaggedPipeline, release: Callable[[], None]) -> None:
+        with _StageTimer("dispatch", batch=len(batch.spans)):
+            handle = dispatcher.reconstruct_lanes(
+                present, missing, batch.buf,
+                [(off, span) for _, off, _ in batch.spans], release)
         pipe.submit(handle, [
-            (v.tag, functools.partial(_write_rebuilt_span, v.base,
-                                      missing,
-                                      min(span, v.dat_size - offset)))
-            for v, offset, _ in pack])
+            (v.tag, functools.partial(_write_rebuilt_span, v.base, missing,
+                                      valid))
+            for v, _, valid in batch.spans])
 
-    try:
-        fill()
-        pack = []
-        while inflight:
-            item = inflight.popleft()
-            FleetReaderQueueGauge.dec()
-            with _waiting("reader"):
-                arr = item[2].result()
-            pack.append((item[0], item[1], arr))
-            fill()
-            if len(pack) >= per_batch or not inflight:
-                flush(pack)
-                pack = []
-    finally:
-        FleetReaderQueueGauge.dec(len(inflight))  # error path leftovers
-        pool.shutdown(wait=True)
-        try:
-            pipe.drain()  # may re-raise the latched pipeline error
-        finally:
-            dispatcher.close()
-            root.__exit__(None, None, None)
+    # a rebuild writes nothing out of a buffer: it is free again when
+    # the retire thread has the dispatch's result
+    _staged_pass(trace.span("fleet.rebuild", volumes=len(members),
+                            backend=backend, groups=groups, present=present,
+                            missing=missing),
+                 backend, device, encoders, readers, depth,
+                 lanes=per_batch * span, per_buffer=per_batch, plan=plan(),
+                 flush=flush, refs=lambda batch: 1)
 
 
 # --- fleet verify ------------------------------------------------------------

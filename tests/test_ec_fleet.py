@@ -10,6 +10,7 @@ pipeline depth > 1.
 """
 
 import filecmp
+import functools
 import os
 
 import numpy as np
@@ -199,19 +200,19 @@ def test_rebuild_of_many_volumes_in_one_group_keeps_its_spans_wide(
         for sid in (0, 3):
             os.remove(shard_file_name(base, sid))
     reads, batches = [], []
-    read, reconstruct = fleet._read_present_span, \
-        fleet._Dispatcher.reconstruct
+    read, reconstruct = fleet._read_present_span_into, \
+        fleet._Dispatcher.reconstruct_lanes
 
     def counted_read(*args):
         reads.append(args[4])
         return read(*args)
 
-    def counted_reconstruct(self, present, missing, arrays):
-        batches.append(len(arrays))
-        return reconstruct(self, present, missing, arrays)
+    def counted_reconstruct(self, present, missing, buf, cuts, done):
+        batches.append(len(cuts))
+        return reconstruct(self, present, missing, buf, cuts, done)
 
-    monkeypatch.setattr(fleet, "_read_present_span", counted_read)
-    monkeypatch.setattr(fleet._Dispatcher, "reconstruct",
+    monkeypatch.setattr(fleet, "_read_present_span_into", counted_read)
+    monkeypatch.setattr(fleet._Dispatcher, "reconstruct_lanes",
                         counted_reconstruct)
     groups = FleetRebuildGroupsCounter.labels().value
     rebuilt = fleet.fleet_rebuild_ec_files(bases, backend="numpy",
@@ -384,11 +385,12 @@ def test_write_dat_file_backend_chunk_default(tmp_path):
         assert f.read() == original
 
 
-# --- the staging buffers (ISSUE 26) ------------------------------------------
+# --- the staging buffers (ISSUE 26: encode; ISSUE 28: rebuild) ---------------
 #
 # Spans are read straight into reused [10, lanes] buffers. What that can
 # break: a reused buffer holds an earlier dispatch's bytes (padding past
-# EOF), and a buffer handed out again while something still reads it.
+# EOF, or past a shard's end), and a buffer handed out again while
+# something still reads it.
 
 @pytest.fixture(autouse=True)
 def no_idle_staging(monkeypatch):
@@ -428,33 +430,114 @@ def _two_passes(tmp_path, backend, sizes_by_pass, **kw):
     return counts
 
 
+LOST = (0, 3)                    # two data shards: a true inverse
+# a rebuild's span rule floors spans at a small block; 128 lets volumes
+# of a few KB be cut into many spans, two side by side in a buffer
+REBUILD_FLOOR = 128
+REBUILD_CHUNK = DATA_SHARDS * 2 * 700   # spans of ~700 bytes, two a buffer
+
+
+def _lose_and_twin(root, sizes, seed):
+    """Volumes of `sizes` encoded by the serial encoder, shards LOST
+    removed; beside each a twin of the 12 survivors that the serial
+    numpy rebuild has already repaired. Returns (bases, twins)."""
+    bases = _make_volumes(str(root), sizes, seed=seed)
+    twins = []
+    for base in bases:
+        ec.write_ec_files(base, backend="numpy", large_block=ROOMY,
+                          small_block=SMALL)
+        for sid in LOST:
+            os.remove(shard_file_name(base, sid))
+        twin = base + ".serial"
+        for sid in range(TOTAL_SHARDS):
+            if sid not in LOST:
+                os.link(shard_file_name(base, sid),
+                        shard_file_name(twin, sid))
+        assert ec.rebuild_ec_files(twin, backend="numpy") == list(LOST)
+        twins.append(twin)
+    return bases, twins
+
+
+def _rebuild_passes(tmp_path, monkeypatch, backend, sizes_by_pass, **kw):
+    """Rebuild each list of sizes as one fleet pass (one group: every
+    volume lost the same shards), in this process, and hold every pass
+    to the serial numpy rebuild. Returns (fresh, reused) per pass."""
+    monkeypatch.setattr(fleet, "SMALL_BLOCK_SIZE", REBUILD_FLOOR)
+    counts = []
+    for n, sizes in enumerate(sizes_by_pass):
+        root = tmp_path / f"rebuild{n}"
+        root.mkdir()
+        bases, twins = _lose_and_twin(root, sizes, 50 + n)
+        before = _handed("fresh"), _handed("reused")
+        rebuilt = fleet.fleet_rebuild_ec_files(bases, backend=backend,
+                                               chunk=REBUILD_CHUNK, **kw)
+        counts.append((_handed("fresh") - before[0],
+                       _handed("reused") - before[1]))
+        assert rebuilt == {b: list(LOST) for b in bases}
+        _assert_shards_equal(bases, twins)
+    return counts
+
+
+def _passes(kind, tmp_path, monkeypatch, backend, sizes_by_pass, **kw):
+    if kind == "rebuild":
+        return _rebuild_passes(tmp_path, monkeypatch, backend,
+                               sizes_by_pass, **kw)
+    return _two_passes(tmp_path, backend, sizes_by_pass, **kw)
+
+
+# first pass: every buffer full of random bytes; second: the ragged one
+STALE = {
+    # volumes whose last row is short (one of 700 bytes)
+    "encode": ([6 * ROW, 6 * ROW, 6 * ROW],
+               [3 * ROW + 123, 700, 2 * ROW + 1, ROW]),
+    # a group of two volumes of unequal shard size (40 and 14 rows of
+    # 256 bytes, spans of 683): the short one's last span, 169 bytes,
+    # lies in a buffer beside a full span of the long one and after a
+    # full one of its own, and neither shard is a multiple of the span
+    "rebuild": ([40 * ROW, 40 * ROW], [40 * ROW, 13 * ROW + 77]),
+}
+
+
 @pytest.mark.parametrize("backend", ["numpy", "jax"])
-def test_fleet_encode_stale_staging_bytes_never_reach_a_shard(tmp_path,
-                                                              backend):
+@pytest.mark.parametrize("kind", sorted(STALE))
+def test_stale_staging_bytes_never_reach_a_shard(tmp_path, monkeypatch,
+                                                 kind, backend):
     """Two passes in one process: the first fills every staging buffer
-    with full rows of random bytes, the second encodes volumes whose
-    last row is short (one of 700 bytes) into the SAME buffers. The
-    padding past EOF must read as zeros, not as the first pass's data."""
-    full = [6 * ROW, 6 * ROW, 6 * ROW]
-    ragged = [3 * ROW + 123, 700, 2 * ROW + 1, ROW]
-    first, second = _two_passes(tmp_path, backend, [full, ragged])
+    with full spans of random bytes, the second runs ragged volumes in
+    the SAME buffers. The padding past EOF (encode) or past the shard's
+    end (rebuild) must read as zeros, not as the first pass's data, and
+    no byte of it may reach a file."""
+    first, second = _passes(kind, tmp_path, monkeypatch, backend,
+                            STALE[kind])
     assert first[0] > 0
     assert second == (0, second[1]) and second[1] > 0, \
         "the second pass did not run in the first pass's buffers"
+    if kind == "rebuild":
+        span, per_batch = fleet._stacked_spans(
+            REBUILD_CHUNK, [40 * SMALL, 14 * SMALL])
+        assert (span, per_batch) == (683, 2)
+        assert (40 * SMALL) % span and (14 * SMALL) % span == 169
+        assert second[1] > first[0]      # buffers came round within it
 
 
 @pytest.mark.parametrize("backend", ["numpy", "jax"])
-def test_second_pass_of_one_geometry_hands_out_no_fresh_buffer(tmp_path,
-                                                               backend):
+@pytest.mark.parametrize("kind, sizes, first_counts, second_counts", [
+    # 9 dispatches of 2 rows; share: 2 prefetched spans a buffer -> 2
+    # buffers + 1, depth 2 + 1
+    ("encode", [9 * ROW, 9 * ROW], (6, 3), (0, 9)),
+    # 15 spans of 683 a volume, two a buffer: 15 dispatches, same share
+    ("rebuild", [40 * ROW, 40 * ROW], (6, 9), (0, 15)),
+])
+def test_second_pass_of_one_geometry_hands_out_no_fresh_buffer(
+        tmp_path, monkeypatch, kind, sizes, first_counts, second_counts,
+        backend):
     """The counter the benchmark's fleet_staging_reuse_share reads: a
     pass touches min(share, dispatches) buffers whatever the timing,
     and the next pass of the same geometry is handed only those."""
-    sizes = [9 * ROW, 9 * ROW]       # 9 dispatches of 2 rows
-    # share: 2 prefetched spans a buffer -> 2 buffers + 1, depth 2 + 1
-    first, second = _two_passes(tmp_path, backend, [sizes, sizes],
-                                readers=2)
-    assert first == (6, 3)
-    assert second == (0, 9)
+    first, second = _passes(kind, tmp_path, monkeypatch, backend,
+                            [sizes, sizes], readers=2)
+    assert first == first_counts
+    assert second == second_counts
 
 
 def test_short_pass_touches_only_the_buffers_it_fills(tmp_path):
@@ -463,15 +546,63 @@ def test_short_pass_touches_only_the_buffers_it_fills(tmp_path):
     assert second == (0, 2)
 
 
-def test_another_geometry_drops_the_idle_buffers(tmp_path):
-    _two_passes(tmp_path, "numpy", [[4 * ROW]])
-    assert {b.shape for b in fleet._IDLE_STAGING._bufs} == \
-        {(DATA_SHARDS, 2 * SMALL)}
+def _idle_shapes():
+    return [b.shape for b in fleet._IDLE_STAGING._bufs]
+
+
+def test_a_narrower_pass_borrows_the_idle_buffers(tmp_path, monkeypatch):
+    """Buffers are kept by capacity (a width rounded up to a small
+    block) and lent as [:, :lanes] views: a pass of a narrower geometry
+    runs in the wider pass's buffers and leaves them as they were."""
+    monkeypatch.setattr(fleet, "SMALL_BLOCK_SIZE", 3 * SMALL)
+    _two_passes(tmp_path, "numpy", [[4 * ROW]])          # 2 * SMALL lanes
+    assert _idle_shapes() == [(DATA_SHARDS, 3 * SMALL)] * 2
+    kept = [id(b) for b in fleet._IDLE_STAGING._bufs]
+    bases = _make_volumes(str(tmp_path), [4 * ROW], seed=3)
+    twins = _serial_twin(bases)
+    ec.write_ec_files(twins[0], backend="numpy", large_block=ROOMY,
+                      small_block=SMALL)
+    fresh = _handed("fresh")
+    fleet.fleet_write_ec_files(bases, backend="numpy", large_block=ROOMY,
+                               small_block=SMALL, chunk=ROW)  # SMALL lanes
+    _assert_shards_equal(bases, twins)
+    # four dispatches of one row in the two buffers kept, two new ones
+    # of the same capacity beside them
+    assert _handed("fresh") - fresh == 2
+    assert _idle_shapes() == [(DATA_SHARDS, 3 * SMALL)] * 4
+    assert [id(b) for b in fleet._IDLE_STAGING._bufs][:2] == kept
+
+
+def test_a_wider_pass_replaces_the_idle_buffers(tmp_path, monkeypatch):
+    """One capacity at a time, the widest so far: what is idle and too
+    narrow goes when a wider pass comes, so odd widths never pile up."""
+    monkeypatch.setattr(fleet, "SMALL_BLOCK_SIZE", SMALL)
     bases = _make_volumes(str(tmp_path), [4 * ROW], seed=3)
     fleet.fleet_write_ec_files(bases, backend="numpy", large_block=ROOMY,
                                small_block=SMALL, chunk=ROW)
-    assert {b.shape for b in fleet._IDLE_STAGING._bufs} == \
-        {(DATA_SHARDS, SMALL)}
+    assert _idle_shapes() == [(DATA_SHARDS, SMALL)] * 4
+    first, = _two_passes(tmp_path, "numpy", [[4 * ROW]])  # 2 * SMALL lanes
+    assert first == (2, 0)
+    assert _idle_shapes() == [(DATA_SHARDS, 2 * SMALL)] * 2
+
+
+@pytest.mark.parametrize("backend", ["numpy", "jax"])
+def test_alternating_encode_and_rebuild_passes_keep_their_buffers(
+        tmp_path, monkeypatch, backend):
+    """A server that encodes, rebuilds, encodes, rebuilds: the rebuild's
+    width follows its largest shard (1,366 lanes here, an encode's 512),
+    so its first pass makes the buffers wider, once. After that neither
+    pass is handed a fresh buffer."""
+    monkeypatch.setattr(fleet, "SMALL_BLOCK_SIZE", REBUILD_FLOOR)
+    sizes = [40 * ROW, 40 * ROW]
+    counts = []
+    for n, kind in enumerate(["encode", "rebuild", "encode", "rebuild"]):
+        root = tmp_path / f"round{n}"
+        root.mkdir()
+        counts += _passes(kind, root, monkeypatch, backend, [sizes])
+    share = 6
+    assert [fresh for fresh, _ in counts] == [share, share, 0, 0]
+    assert _idle_shapes() == [(DATA_SHARDS, 11 * REBUILD_FLOOR)] * share
 
 
 def test_read_span_into_zeroes_past_eof_on_every_use(tmp_path):
@@ -495,6 +626,48 @@ def test_read_span_into_zeroes_past_eof_on_every_use(tmp_path):
         # lanes outside the span are somebody else's
         assert (buf[:, :SMALL] == 0xAB).all()
         assert (buf[:, 3 * SMALL:] == 0xAB).all()
+
+
+@pytest.mark.parametrize("case, offset, sizes, want", [
+    # the span ends inside the shard: all of it read
+    ("inside", 100, [1000] * DATA_SHARDS, [300] * DATA_SHARDS),
+    # the shard ends inside the span
+    ("last_span", 900, [1000] * DATA_SHARDS, [100] * DATA_SHARDS),
+    # nothing of the shard is left at this offset
+    ("past_the_end", 1000, [1000] * DATA_SHARDS, [0] * DATA_SHARDS),
+    # one survivor is shorter than the others say it should be
+    ("short_survivor", 600, [1000] * 4 + [750] + [1000] * 5,
+     [300] * 4 + [150] + [300] * 5),
+])
+def test_read_present_span_into_zeroes_past_the_shard_end_on_every_use(
+        tmp_path, case, offset, sizes, want):
+    """A rebuild span's reader owns every lane of its span in a dirty
+    buffer: what it does not fill from a survivor it zeroes, and it
+    touches no lane of another span."""
+    rng = np.random.default_rng(35)
+    base, span, off = str(tmp_path / "v"), 300, 200
+    present = [1, 2, 4, 5, 6, 7, 8, 9, 10, 11, 12, 13]
+    data = {}
+    for sid, size in zip(present, sizes):
+        data[sid] = rng.integers(1, 256, size, dtype=np.uint8)
+        with open(shard_file_name(base, sid), "wb") as f:
+            f.write(data[sid].tobytes())
+    buf = np.full((DATA_SHARDS, 4 * span), 0xAB, dtype=np.uint8)
+    for _ in range(2):                   # every use, not the first alone
+        buf[:, off:off + span] = 0xCD
+        fleet._read_present_span_into(base, present, sizes[0], offset, span,
+                                      buf, off)
+        for row, sid in enumerate(present[:DATA_SHARDS]):
+            n = want[row]
+            assert np.array_equal(buf[row, off:off + n],
+                                  data[sid][offset:offset + n]), (case, row)
+            assert not buf[row, off + n:off + span].any(), (case, row)
+        assert (buf[:, :off] == 0xAB).all()
+        assert (buf[:, off + span:] == 0xAB).all()
+    # verify's reader is the same one over an array of its own
+    assert np.array_equal(
+        fleet._read_present_span(base, present, sizes[0], offset, span),
+        buf[:, off:off + span])
 
 
 @pytest.mark.parametrize("iov_max, most", [(3, None), (1024, 100), (7, 33)])
@@ -557,12 +730,20 @@ def test_staging_buffer_is_free_only_after_result_and_every_write():
 
 
 @pytest.mark.parametrize("backend", ["numpy", "jax"])
+@pytest.mark.parametrize("kind, sizes, dispatches, writes", [
+    # every span's data-shard write reads the buffer too
+    ("encode", [5 * ROW + 1, 3 * ROW, 700], 5, 6 + 3 + 1),
+    # a rebuild writes nothing out of the buffer
+    ("rebuild", [40 * ROW, 13 * ROW + 77], 11, 0),
+])
 def test_buffer_readers_are_the_retire_thread_and_the_writer_lanes(
-        tmp_path, monkeypatch, backend):
+        tmp_path, monkeypatch, kind, sizes, dispatches, writes, backend):
     """Who lets go of a buffer, and where: the retire thread once a
     dispatch, when it has the result (every transfer out of the buffer
-    is over), and a writer lane once a span, when the data-shard write
-    has run. Nothing is released from the packing thread."""
+    is over), and, in an encode pass, a writer lane once a span, when
+    the data-shard write has run. Nothing is released from the packing
+    thread, so a buffer is never free before its result is on the
+    host."""
     import threading
 
     by_thread = []
@@ -573,14 +754,12 @@ def test_buffer_readers_are_the_retire_thread_and_the_writer_lanes(
         real(self, batch)
 
     monkeypatch.setattr(fleet._Staging, "unref", unref)
-    first, = _two_passes(tmp_path, backend,
-                         [[5 * ROW + 1, 3 * ROW, 700]])
-    dispatches, spans = int(sum(first)), 6 + 3 + 1
-    assert dispatches == 5
+    first, = _passes(kind, tmp_path, monkeypatch, backend, [sizes])
+    assert int(sum(first)) == dispatches
     assert by_thread.count("fleet-retire") == dispatches
     assert len([t for t in by_thread
-                if t.startswith("fleet-write-")]) == spans
-    assert len(by_thread) == dispatches + spans
+                if t.startswith("fleet-write-")]) == writes
+    assert len(by_thread) == dispatches + writes
 
 
 def test_staging_acquire_raises_the_latched_error_instead_of_waiting():
@@ -614,7 +793,7 @@ def test_buffer_is_not_handed_out_before_its_data_shard_writes(tmp_path,
 
     def acquire(self):
         buf = real_acquire(self)
-        handed.append(id(buf))
+        handed.append(id(buf.base))      # lent as a view of the kept one
         return buf
 
     def write(base, arr, done):
@@ -657,75 +836,164 @@ def test_buffer_is_not_handed_out_before_its_data_shard_writes(tmp_path,
     _assert_shards_equal(bases, twins)
 
 
-def test_failed_pass_returns_every_staging_buffer(tmp_path, monkeypatch):
+def test_rebuild_buffer_is_not_handed_out_before_its_result(tmp_path,
+                                                            monkeypatch):
+    """With the first dispatch's compute held back, the pass runs out of
+    buffers and WAITS: the buffer under that dispatch goes to no reader
+    until the retire thread has its result — and the rebuilt shards
+    come out byte-identical once it has."""
+    import threading
+    import time
+
+    gate = threading.Event()
+    handed, held = [], []
+    real_acquire = fleet._Staging.acquire
+    real_apply = ReedSolomon.reconstruct_some
+
+    def acquire(self):
+        buf = real_acquire(self)
+        handed.append(id(buf.base))
+        return buf
+
+    def apply(self, present, wanted, shard_data):
+        if not held:
+            held.append(id(shard_data.base))
+            gate.wait(30)
+        return real_apply(self, present, wanted, shard_data)
+
+    monkeypatch.setattr(fleet._Staging, "acquire", acquire)
+    monkeypatch.setattr(ReedSolomon, "reconstruct_some", apply)
+    monkeypatch.setattr(fleet, "SMALL_BLOCK_SIZE", REBUILD_FLOOR)
+    bases, twins = _lose_and_twin(tmp_path, [40 * ROW, 40 * ROW], 36)
+    errors = []
+
+    def run():
+        try:
+            fleet.fleet_rebuild_ec_files(bases, backend="numpy",
+                                         chunk=REBUILD_CHUNK, readers=1,
+                                         depth=1)
+        except BaseException as e:  # surfaced below
+            errors.append(e)
+
+    t = threading.Thread(target=run, daemon=True)
+    t.start()
+    share = 2 + 1 + 1 + 1            # 4 prefetched spans, two a buffer
+    deadline = time.monotonic() + 10
+    while len(handed) < share and time.monotonic() < deadline:
+        time.sleep(0.01)
+    time.sleep(0.3)                  # room for a wrong hand-out
+    try:
+        assert len(handed) == share and len(set(handed)) == share
+        assert handed.count(held[0]) == 1
+    finally:
+        gate.set()
+        t.join(30)
+    assert not t.is_alive() and not errors
+    assert len(handed) == 15 and handed.count(held[0]) >= 2
+    _assert_shards_equal(bases, twins)
+
+
+@pytest.mark.parametrize("kind", ["encode", "rebuild"])
+def test_failed_pass_returns_every_staging_buffer(tmp_path, monkeypatch,
+                                                  kind):
     """An error latched in the pipeline skips the closures that release
-    buffers; the pass still gives back every buffer it filled, and the
-    next pass of the geometry runs in them."""
-    real = fleet._write_parity_span
+    buffers: the packing thread, out of buffers, is told the error and
+    does not wait; the pass still gives back every buffer it filled (a
+    rebuild pass unlinks its outputs besides), and the next pass of the
+    geometry runs in them."""
+    writer = {"encode": "_write_parity_span",
+              "rebuild": "_write_rebuilt_span"}[kind]
+    real = getattr(fleet, writer)
     seen = []
 
-    def failing(base, seg):
+    def failing(base, *args):
         seen.append(base)
         if len(seen) == 3:
             raise OSError("no space left on device")
-        real(base, seg)
+        real(base, *args)
 
-    monkeypatch.setattr(fleet, "_write_parity_span", failing)
-    bases = _make_volumes(str(tmp_path), [20 * ROW, 20 * ROW], seed=34)
+    def run(bases):
+        if kind == "encode":
+            fleet.fleet_write_ec_files(bases, backend="numpy",
+                                       large_block=ROOMY, small_block=SMALL,
+                                       chunk=2 * ROW)
+        else:
+            fleet.fleet_rebuild_ec_files(bases, backend="numpy",
+                                         chunk=REBUILD_CHUNK)
+
+    monkeypatch.setattr(fleet, "SMALL_BLOCK_SIZE", REBUILD_FLOOR)
+    sizes = {"encode": [20 * ROW, 20 * ROW], "rebuild": [54 * ROW] * 2}[kind]
+    if kind == "encode":
+        bases = _make_volumes(str(tmp_path), sizes, seed=34)
+        twins = _serial_twin(bases)
+        for t in twins:
+            ec.write_ec_files(t, backend="numpy", large_block=ROOMY,
+                              small_block=SMALL)
+    else:
+        bases, twins = _lose_and_twin(tmp_path, sizes, 34)
+    monkeypatch.setattr(fleet, writer, failing)
     fresh0 = _handed("fresh")
     with pytest.raises(OSError, match="no space left"):
-        fleet.fleet_write_ec_files(bases, backend="numpy", large_block=ROOMY,
-                                   small_block=SMALL, chunk=2 * ROW)
+        run(bases)
     made = int(_handed("fresh") - fresh0)
     assert made >= 2
     assert len(fleet._IDLE_STAGING._bufs) == made
-    monkeypatch.setattr(fleet, "_write_parity_span", real)
+    if kind == "rebuild":
+        for base in bases:
+            for sid in LOST:
+                assert not os.path.exists(shard_file_name(base, sid))
+    monkeypatch.setattr(fleet, writer, real)
     fresh1, reused1 = _handed("fresh"), _handed("reused")
-    twins = _serial_twin(bases)
-    for t in twins:
-        ec.write_ec_files(t, backend="numpy", large_block=ROOMY,
-                          small_block=SMALL)
-    fleet.fleet_write_ec_files(bases, backend="numpy", large_block=ROOMY,
-                               small_block=SMALL, chunk=2 * ROW)
+    run(bases)
     _assert_shards_equal(bases, twins)
     share = 2 + 1 + 2 + 1            # 4 prefetched spans, two a buffer
     assert _handed("fresh") - fresh1 == share - made
     assert _handed("reused") - reused1 == 20 - (share - made)
 
 
-def test_concurrent_passes_share_nothing_but_the_idle_list(tmp_path):
+def test_concurrent_passes_share_nothing_but_the_idle_list(tmp_path,
+                                                          monkeypatch):
     """Several schedulers at once in one process (one a device, parallel
-    generate RPCs): each has its own share, none waits for another's
-    buffers, all stay byte-identical."""
+    generate and rebuild RPCs), of two widths: each has its own share,
+    none waits for another's buffers, all stay byte-identical, and what
+    they leave idle is of one capacity."""
     import sys
     import threading
 
+    monkeypatch.setattr(fleet, "SMALL_BLOCK_SIZE", REBUILD_FLOOR)
     jobs = []
-    for n in range(6):
+    for n in range(8):
         root = tmp_path / f"job{n}"
         root.mkdir()
-        bases = _make_volumes(str(root), [7 * ROW + n, 3 * ROW, 700 + n],
-                              seed=40 + n)
-        twins = _serial_twin(bases)
-        for t in twins:
-            ec.write_ec_files(t, backend="numpy", large_block=ROOMY,
-                              small_block=SMALL)
-        jobs.append((bases, twins))
+        if n % 2:
+            bases, twins = _lose_and_twin(
+                root, [20 * ROW + n, 7 * ROW, 700 + n], 40 + n)
+            run = functools.partial(fleet.fleet_rebuild_ec_files, bases,
+                                    backend="numpy", chunk=REBUILD_CHUNK)
+        else:
+            bases = _make_volumes(str(root), [7 * ROW + n, 3 * ROW, 700 + n],
+                                  seed=40 + n)
+            twins = _serial_twin(bases)
+            for t in twins:
+                ec.write_ec_files(t, backend="numpy", large_block=ROOMY,
+                                  small_block=SMALL)
+            run = functools.partial(fleet.fleet_write_ec_files, bases,
+                                    backend="numpy", large_block=ROOMY,
+                                    small_block=SMALL, chunk=2 * ROW)
+        jobs.append((bases, twins, run))
     errors = []
 
-    def run(bases):
+    def guarded(run):
         try:
-            fleet.fleet_write_ec_files(bases, backend="numpy",
-                                       large_block=ROOMY, small_block=SMALL,
-                                       chunk=2 * ROW)
+            run()
         except BaseException as e:  # surfaced below
             errors.append(e)
 
     interval = sys.getswitchinterval()
     sys.setswitchinterval(1e-5)
     try:
-        threads = [threading.Thread(target=run, args=(b,), daemon=True)
-                   for b, _ in jobs]
+        threads = [threading.Thread(target=guarded, args=(run,), daemon=True)
+                   for _, _, run in jobs]
         for t in threads:
             t.start()
         for t in threads:
@@ -733,6 +1001,7 @@ def test_concurrent_passes_share_nothing_but_the_idle_list(tmp_path):
     finally:
         sys.setswitchinterval(interval)
     assert not errors and not any(t.is_alive() for t in threads)
-    for bases, twins in jobs:
+    for bases, twins, _ in jobs:
         _assert_shards_equal(bases, twins)
     assert len(fleet._IDLE_STAGING._bufs) <= 8
+    assert len(set(_idle_shapes())) <= 1

@@ -102,7 +102,7 @@ def _serial_reference(tmp_path, vid, survivors):
     """`encoder.rebuild_ec_files(backend="numpy")` over a copy of the
     surviving files: shard id -> bytes of all 14."""
     d = tmp_path / f"serial-{vid}"
-    d.mkdir()
+    d.mkdir(parents=True)
     base = str(d / str(vid))
     for sid, path in survivors.items():
         shutil.copyfile(path, shard_file_name(base, sid))
@@ -192,8 +192,8 @@ def test_served_rebuild_restores_every_shard(one_server, tmp_path, case,
 @pytest.mark.parametrize("backend", ["numpy", "jax"])
 def test_a_shard_size_that_is_no_multiple_of_the_span(one_server, tmp_path,
                                                       monkeypatch, backend):
-    """The last span of a shard is zero-padded to the span's width for
-    the stacked dispatch and trimmed on the way to the file."""
+    """The last span of a shard is zero-padded to the span's width in
+    the staging buffer and trimmed on the way to the file."""
     for name in ("DEFAULT_CHUNK", "DEFAULT_CHUNK_JAX"):
         monkeypatch.setattr(encoder, name, 1_000_003)
     # spans this narrow stack only under a floor to match
@@ -359,7 +359,7 @@ def test_a_pass_that_fails_half_way_mounts_nothing(one_server, monkeypatch):
     # many dispatches a pass; the failpoint is armed by the first
     for name in ("DEFAULT_CHUNK", "DEFAULT_CHUNK_JAX"):
         monkeypatch.setattr(encoder, name, 1_000_003)
-    reconstruct = fleet._Dispatcher.reconstruct
+    reconstruct = fleet._Dispatcher.reconstruct_lanes
 
     def reconstruct_then_arm(self, *args):
         handle = reconstruct(self, *args)
@@ -367,7 +367,7 @@ def test_a_pass_that_fails_half_way_mounts_nothing(one_server, monkeypatch):
                       match={"op": "reconstruct"})
         return handle
 
-    monkeypatch.setattr(fleet._Dispatcher, "reconstruct",
+    monkeypatch.setattr(fleet._Dispatcher, "reconstruct_lanes",
                         reconstruct_then_arm)
     try:
         with pytest.raises(CommandError) as e:
@@ -389,6 +389,29 @@ def test_a_pass_that_fails_half_way_mounts_nothing(one_server, monkeypatch):
         assert f"volume {vid}: rebuilt shards [0, 3] on " in out
         _wait_registered(c, {vid: TOTAL_SHARDS})
         assert _read_all(_mounted_files(c, vid)) == before[vid]
+
+
+STAGING = 'SeaweedFS_fleet_staging_buffers_total{state="%s"}'
+
+
+@pytest.mark.parametrize("backend", ["numpy", "jax"])
+def test_a_second_rebuild_command_runs_in_the_first_one_s_buffers(
+        one_server, tmp_path, monkeypatch, backend):
+    """Each round of repair is one shell command, so one fleet pass. The
+    pass reads the survivors into staging buffers that the server keeps:
+    the second command is handed only buffers the first one filled."""
+    monkeypatch.setattr(fleet, "_IDLE_STAGING", fleet._IdleStaging())
+    c = one_server
+    vids = _encode_volumes(c, 2, backend)
+    losses = {v: [0, 3] for v in vids}
+    fresh, reused = (_sample(c, STAGING % s) for s in ("fresh", "reused"))
+    _lose_rebuild_compare(c, tmp_path / "first", losses, backend)
+    fresh1, reused1 = (_sample(c, STAGING % s) for s in ("fresh", "reused"))
+    assert (fresh1 - fresh) + (reused1 - reused) >= 1
+    _lose_rebuild_compare(c, tmp_path / "second", losses, backend)
+    assert _sample(c, STAGING % "reused") - reused1 == \
+        (fresh1 - fresh) + (reused1 - reused)
+    assert _sample(c, STAGING % "fresh") == fresh1
 
 
 def test_the_pass_is_traced_stage_by_stage(one_server, tmp_path):
