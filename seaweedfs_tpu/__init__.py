@@ -8,7 +8,7 @@ cluster services (master / volume server / filer / gateways) are fresh
 Python+C++ implementations of the same architecture.
 
 Layer map (mirrors SURVEY.md §1):
-  ops/       GF(2^8) math + JAX/Pallas RS kernels (the TPU compute path)
+  ops/       GF(2^8) math + the JAX RS kernel (the TPU compute path)
   parallel/  device-mesh sharding, streaming host<->HBM pipeline
   storage/   on-disk formats: needle, .idx, superblock, volume engine
   ec/        erasure-coding pipeline: .ec00-.ec13 / .ecx / .ecj, locate math

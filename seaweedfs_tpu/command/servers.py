@@ -247,7 +247,7 @@ def _volume_parser() -> argparse.ArgumentParser:
                    help="re-scrub every N seconds (0 = only on demand "
                         "via volume.scrub / the master scheduler)")
     p.add_argument("-ec.encoder", dest="ec_encoder", default="auto",
-                   choices=["auto", "jax", "native", "numpy", "pallas"])
+                   choices=["auto", "jax", "native", "numpy"])
     p.add_argument("-ec.mesh", dest="ec_mesh", action="store_true",
                    default=False,
                    help="run batched EC encode/verify/decode on the "

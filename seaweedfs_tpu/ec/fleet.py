@@ -1,55 +1,49 @@
 """Cross-volume batched EC scheduler — the fleet encoder.
 
-`ec/encoder.py` encodes ONE volume at a time: every chunk is its own
+`ec/encoder.py` works on ONE volume at a time: every chunk is its own
 RS dispatch and a single reader thread feeds the device, so a fleet of
-volumes serializes on dispatch latency and on that one thread's disk
-reads. This module lifts the batch dimension from rows-within-a-volume
-to chunks-ACROSS-volumes (the ROADMAP "sharding, batching, async"
-directive; the BASELINE "cluster-wide ec.encode" shape):
+volumes serializes on dispatch latency and on that thread's disk reads.
+This module batches chunks ACROSS volumes. Its three passes — encode
+(`fleet_write_ec_files`), rebuild (`fleet_rebuild_ec_files`), verify
+(`fleet_verify_ec_files`, the scrub) — are each a plan of spans and a
+flush for ONE loop, `_staged_pass`:
 
-  pack      row-spans from many volumes share one [10, lanes] staging
-            buffer — the layout the device wants — so 64 small
-            volumes cost a handful of dispatches instead of 64 serial
-            ones, and nothing is copied between the read and the
-            placement: the buffer IS the dispatch's input.
-  feed      a bounded reader pool prefetches spans ahead of the
-            device, each reader filling its span's lanes of a staging
-            buffer straight from the .dat. Spans are consumed in
-            submission order (round-robin rounds over the volumes),
-            so per-volume row order is preserved by construction
-            while reads overlap compute. The buffers are reused from
-            dispatch to dispatch and from pass to pass (`_Staging`).
-            A rebuild pass runs the same loop (`_staged_pass`): its
-            readers fill the buffer's ten rows from the ten surviving
-            shard files.
+  pack      spans from many volumes share one [10, lanes] staging
+            buffer — the layout the device wants — so 64 small volumes
+            cost a handful of dispatches, and nothing is copied between
+            the read and the placement: the buffer IS the dispatch's
+            input, reused from dispatch to dispatch and from pass to
+            pass (`_Staging`).
+  feed      a bounded reader pool prefetches spans ahead of the device,
+            each reader filling its span's lanes straight from the .dat
+            (encode), the ten surviving shard files (rebuild) or the
+            ten data shard files (verify). Spans are consumed in
+            submission order (round-robin rounds over the volumes), so
+            per-volume order holds while reads overlap compute.
   dispatch  the jax backend is async already; sync host backends
-            (native/numpy) are lifted to the same handle contract by
-            a small encode pool, so RS compute itself runs multi-core
-            and overlaps the reader and writer threads.
-  retire    a tagged completion queue — the FIFO discipline of
-            `encoder._EncodePipeline`, generalized from one (handle,
-            writeback) pair to per-volume tags — fans each dispatch's
-            parity out to many volumes' .ecNN files. A single retire
-            thread awaits dispatches strictly in submission order and
-            hands every volume's writes to that volume's writer LANE
-            (per-volume FIFO, parallel across volumes), so the ~9
-            bytes written per 10 read don't serialize behind one
-            thread the way the per-volume pipeline's do.
+            (native/numpy) are lifted to the same handle contract by a
+            small encode pool, so RS compute runs multi-core and
+            overlaps the reader and writer threads.
+  retire    a tagged completion queue: one retire thread awaits
+            dispatches strictly in submission order and hands every
+            volume's output to that volume's writer LANE (per-volume
+            FIFO, parallel across volumes): encoded parity and rebuilt
+            shards are appended to the .ecNN files, a verify's parity
+            is compared with the stored .ec10-13.
 
 Volumes that need large-row striping (> 10 * large_block bytes) fall
 back to the per-volume `write_ec_files` path; everything else is
-byte-identical to it (uniform small rows — the same on-disk layout
-contract `parallel.sharded_write_ec_files` relies on).
+byte-identical to it (uniform small rows).
 
-Sharding the fleet across a device mesh (one scheduler per device,
-volumes dealt by size) lives in `parallel/mesh.py`:
-`fleet_write_ec_files_sharded`.
+The same passes over a device mesh: `parallel/mesh_fleet.py`; one of
+these schedulers per device: `parallel.fleet_write_ec_files_sharded`.
 """
 
 from __future__ import annotations
 
 import contextlib
 import functools
+import itertools
 import os
 import queue
 import threading
@@ -94,7 +88,7 @@ FLEET_READERS = 4
 # Fused dispatches in flight at once — the writer-queue bound, same
 # double-buffering role as encoder.PIPELINE_DEPTH. With the reader
 # prefetch it sets a pass's share of staging buffers (see `_Staging`),
-# which is an encode or rebuild pass's peak host memory.
+# which is a pass's peak host memory.
 FLEET_DEPTH = 2
 
 # Encode pool for synchronous host backends: ctypes/numpy release the
@@ -301,14 +295,13 @@ class _Gathered:
     list of per-span outputs, ordered like the spans were packed.
     `done` runs once every one of them has resolved."""
 
-    def __init__(self, handles, done: Optional[Callable[[], None]] = None):
+    def __init__(self, handles, done: Callable[[], None]):
         self._handles = handles
         self._done = done
 
     def result(self) -> List[np.ndarray]:
         outs = [h.result() for h in self._handles]
-        if self._done is not None:
-            self._done()
+        self._done()
         return outs
 
 
@@ -329,10 +322,8 @@ class _Dispatcher:
     instead, so each span goes to a small encode pool as its own task
     (the GIL-free native/numpy kernels genuinely run on other cores)
     and the handles are gathered. Either way .result() yields per-span
-    output arrays. The `pack` stage times what the packing thread does
-    to make a fused dispatch's input: a slice of the staging buffer for
-    `encode_lanes` and `reconstruct_lanes`, a stacking copy for
-    `encode` (verify's entry).
+    output arrays, and the input is a slice of the staging buffer
+    (taking it is all the `pack` stage times).
     """
 
     def __init__(self, rs: ReedSolomon, device=None,
@@ -362,7 +353,7 @@ class _Dispatcher:
             with _StageTimer("pack", spans=len(cuts)):
                 data = buf[:, :cuts[-1][0] + cuts[-1][1]]
             handle = apply_async(data, device=self._device)
-            return _SplitHandle(handle, [n for _, n in cuts], 1, done)
+            return _SplitHandle(handle, [n for _, n in cuts], done)
         token = trace.handoff()
         return _Gathered([self._pool.submit(_rs_staged, apply,
                                             buf[:, off:off + n], token)
@@ -386,44 +377,25 @@ class _Dispatcher:
             functools.partial(self._rs.reconstruct_some, present, missing),
             buf, cuts, done)
 
-    def encode(self, arrays: List[np.ndarray]):
-        if _failpoint._armed:
-            _failpoint.hit("fleet.dispatch", op="encode")
-        if self._pool is None:
-            with _StageTimer("pack", spans=len(arrays)):
-                data = arrays[0] if len(arrays) == 1 else \
-                    np.concatenate(arrays, axis=0)
-            rows = [a.shape[0] for a in arrays]
-            handle = self._rs.encode_async(data, device=self._device)
-            return _SplitHandle(handle, rows, 0)
-        token = trace.handoff()
-        return _Gathered([self._pool.submit(_rs_staged, self._rs.encode,
-                                            a, token)
-                          for a in arrays])
-
     def close(self) -> None:
         if self._pool is not None:
             self._pool.shutdown(wait=True)
 
 
 class _SplitHandle:
-    """Adapt one fused async encode handle back to per-span outputs:
-    views of `sizes` entries each along `axis` (lanes for a staging
-    buffer's dispatch, rows for a stacked one). `done` runs once the
-    fused result is on the host."""
+    """Adapt one fused async handle over a staging buffer's lanes back
+    to per-span outputs: views of `sizes` lanes each. `done` runs once
+    the fused result is on the host."""
 
-    def __init__(self, handle, sizes: List[int], axis: int,
-                 done: Optional[Callable[[], None]] = None):
+    def __init__(self, handle, sizes: List[int], done: Callable[[], None]):
         self._handle = handle
         self._sizes = sizes
-        self._axis = axis
         self._done = done
 
     def result(self) -> List[np.ndarray]:
         out = self._handle.result()
-        if self._done is not None:
-            self._done()
-        return np.split(out, np.cumsum(self._sizes)[:-1], axis=self._axis)
+        self._done()
+        return np.split(out, np.cumsum(self._sizes)[:-1], axis=1)
 
 
 class _VolState:
@@ -520,20 +492,20 @@ class _StagedBatch:
 
 
 class _Staging:
-    """One encode or rebuild pass's share of staging buffers.
+    """One pass's share of staging buffers.
 
     A staging buffer is [DATA_SHARDS, lanes] uint8 — a fused dispatch's
     input in the layout the device wants. The readers fill it straight
-    from the .dat files (encode) or the surviving shard files
-    (rebuild), the dispatch layer places slices of it, an encode pass's
-    writer lanes write the data shards out of it; then it comes round
-    again. The share is what the pipeline has in flight anyway (see
-    _staged_pass), so the one place a pass can block here — `acquire`,
-    timed as fleet.wait.staging — blocks only while buffers are
-    downstream of the packing thread, where they come back without its
-    help. Passes share nothing but the idle list, so concurrent
-    schedulers (one a device, parallel generate RPCs) cannot hold each
-    other up.
+    from the .dat files (encode), the surviving shard files (rebuild)
+    or the data shard files (verify), the dispatch layer places slices
+    of it, an encode pass's writer lanes write the data shards out of
+    it; then it comes round again. The share is what the pipeline has
+    in flight anyway (see _staged_pass), so the one place a pass can
+    block here — `acquire`, timed as fleet.wait.staging — blocks only
+    while buffers are downstream of the packing thread, where they come
+    back without its help. Passes share nothing but the idle list, so
+    concurrent schedulers (one a device, parallel generate RPCs) cannot
+    hold each other up.
 
     Buffers take turns (FIFO): what a pass touches, and the process
     then keeps, is min(share, dispatches) buffers whatever the timing.
@@ -745,8 +717,8 @@ def fleet_write_ec_files(base_names: Sequence[str], backend: str = "auto",
 def _staged_pass(root, backend: str, device, encoders: int, readers: int,
                  depth: int, *, lanes: int, per_buffer: int, plan, flush,
                  refs: Callable[[_StagedBatch], int]) -> None:
-    """The loop an encode and a rebuild pass share, under the span
-    `root`: plan spans into staging buffers of `lanes`, have the reader
+    """The loop an encode, a rebuild and a verify pass share, under the
+    span `root`: plan spans into staging buffers of `lanes`, have the reader
     pool fill them ahead of the device, dispatch a buffer when its last
     span is read, retire through a TaggedPipeline.
 
@@ -892,11 +864,11 @@ def _unlinked_on_failure(paths: List[str]):
 
 
 def _stacked_spans(chunk: int, shard_sizes: Sequence[int]) -> Tuple[int, int]:
-    """(span, per_batch) of a rebuild pass: the width of one volume's
-    span, in bytes of ONE shard row, and how many spans lie side by
-    side in one [10, B * span] dispatch. `chunk` means what it means in
-    `fleet_write_ec_files`: the input bytes of ALL ten rows of one
-    fused dispatch. A span is every volume's equal share of a chunk,
+    """(span, per_batch) of a rebuild or verify pass: the width of one
+    volume's span, in bytes of ONE shard row, and how many spans lie
+    side by side in one [10, B * span] dispatch. `chunk` means what it
+    means in `fleet_write_ec_files`: the input bytes of ALL ten rows of
+    one fused dispatch. A span is every volume's equal share of a chunk,
     but no narrower than a small block: a span costs ten opens and
     reads whatever its width, so a group of 128 volumes puts 12 spans
     of 1 MiB in a dispatch, not 128 of 100 KiB. It is at most the largest
@@ -945,18 +917,6 @@ def _read_present_span_into(base: str, present: List[int], shard_size: int,
             finally:
                 os.close(fd)
         lanes[got:] = 0
-
-
-def _read_present_span(base: str, present: List[int], shard_size: int,
-                       offset: int, span: int,
-                       parent: Optional[int] = None) -> np.ndarray:
-    """[10, span] slice at `offset` of the first 10 present shards,
-    zero-padded past shard end, in an array of its own (verify)."""
-    src = np.empty((DATA_SHARDS, span), dtype=np.uint8)
-    _read_staged(functools.partial(_read_present_span_into, base, present,
-                                   shard_size, offset, span),
-                 base, src, 0, parent)
-    return src
 
 
 def _fleet_rebuild_group(present: List[int], missing: List[int],
@@ -1027,8 +987,7 @@ class VerifyResult:
 
     @property
     def clean(self) -> bool:
-        return self.verified and not self.parity_mismatch \
-            and not self.missing
+        return self.verified and not (self.parity_mismatch or self.missing)
 
 
 def fleet_verify_ec_files(base_names: Sequence[str], backend: str = "auto",
@@ -1040,164 +999,102 @@ def fleet_verify_ec_files(base_names: Sequence[str], backend: str = "auto",
                           throttler=None) -> Dict[str, "VerifyResult"]:
     """Verify EC stripe consistency for MANY volumes in one fused pass.
 
-    The scrub scanner's compute path: data shards are re-encoded
-    through the same fleet dispatcher as `fleet_write_ec_files` —
-    spans from all volumes fuse into shared [B, 10, span] RS
-    dispatches — and the recomputed parity is compared byte-for-byte
-    against the stored .ec10-13, so verification throughput rides the
-    TPU/mesh encode path instead of a host loop. Nothing on disk is
-    touched; mismatches are reported per parity shard for the repair
-    planner to classify (a corrupt DATA shard surfaces here as all
-    four parity shards disagreeing at the same offsets — see
-    scrub/planner.py).
-
+    The scrub scanner's compute path, on the encode and rebuild passes'
+    loop: the data shards are re-encoded in shared [10, B * span]
+    dispatches over the reused staging buffers, and the parity is
+    compared byte-for-byte against the stored .ec10-13. Nothing on disk
+    is touched; mismatches are reported per parity shard for the repair
+    planner to classify (a corrupt DATA shard surfaces as all four
+    parity shards disagreeing at the same offsets — scrub/planner.py).
     `throttler` (util.throttler.Throttler) paces the read side so a
     background scrub stays inside its IO budget.
     """
     if chunk is None:
         chunk = default_chunk_for(backend)
     results: Dict[str, VerifyResult] = {}
-    fleet: List[Tuple[str, int, List[int]]] = []  # (base, size, parity ids)
+    sized: List[Tuple[str, int]] = []  # (base, shard size) to verify
     for base in base_names:
-        r = VerifyResult()
-        results[base] = r
+        r = results[base] = VerifyResult()
         present = [i for i in range(TOTAL_SHARDS)
                    if os.path.exists(shard_file_name(base, i))]
         r.missing = [i for i in range(TOTAL_SHARDS) if i not in present]
-        data_present = [i for i in present if i < DATA_SHARDS]
-        parity_present = [i for i in present if i >= DATA_SHARDS]
-        if len(data_present) < DATA_SHARDS or not parity_present:
+        parity = [i for i in present if i >= DATA_SHARDS]
+        if any(i < DATA_SHARDS for i in r.missing) or not parity:
             # can't re-encode without every data shard (or compare
             # without any parity): known damage, rebuild's job
             r.verified = False
             continue
-        r.parity_checked = parity_present
-        shard_size = os.path.getsize(shard_file_name(base, 0))
-        fleet.append((base, shard_size, parity_present))
-    if not fleet:
-        return results
-    # span: the per-volume slice of one ~chunk-sized fused dispatch,
-    # capped at the largest shard so small fleets don't read (and
-    # RS-encode) chunk-sized slabs of zero padding per 100KB shard
-    span = max(1, min(chunk // max(1, len(fleet)),
-                      max(size for _, size, _ in fleet)))
-    vols = [(_VolState(base, size, -(-size // span) if size else 0, tag),
-             parity)
-            for tag, (base, size, parity) in enumerate(fleet)]
+        r.parity_checked = parity
+        sized.append((base, os.path.getsize(shard_file_name(base, 0))))
+    if not any(size for _, size in sized):
+        return results  # nothing to read: no pool, no buffer
+    span, per_batch = _stacked_spans(chunk, [size for _, size in sized])
+    vols = [_VolState(base, size, -(-size // span), tag)
+            for tag, (base, size) in enumerate(sized)]
 
-    def gen_spans():
-        for v, row0, _rows in _round_robin_spans([v for v, _ in vols], 1):
-            yield v, row0 * span
-
-    parity_by_tag = {v.tag: parity for v, parity in vols}
-    dispatcher = _Dispatcher(ReedSolomon(backend=backend), device=device,
-                             encoders=encoders)
-    # lint: thread-ok(per-pass reader pool; work items are explicit, no ambient request state)
-    pool = ThreadPoolExecutor(max_workers=max(1, readers),
-                              thread_name_prefix="fleet-read")
-    pipe = TaggedPipeline(depth=depth)
-    gen = gen_spans()
-    inflight: deque = deque()
-    per_batch = len(fleet)
-    prefetch = max(readers, 2 * per_batch)
-    root = trace.span("fleet.verify", volumes=len(fleet), backend=backend)
-    root.__enter__()
-    token = root.token()
-    data_present = list(range(DATA_SHARDS))
-
-    def fill() -> None:
-        while len(inflight) < prefetch:
-            nxt = next(gen, None)
-            if nxt is None:
-                break
-            v, offset = nxt
+    def plan():
+        for v, row0, _rows in _round_robin_spans(vols, 1):
+            offset = row0 * span
             if throttler is not None:
-                # pace on the read side: one span costs 10 data reads
-                # plus the parity reads the compare will issue
-                throttler.maybe_slowdown(
-                    (DATA_SHARDS + len(parity_by_tag[v.tag])) * span)
-            inflight.append((v, offset, pool.submit(
-                _read_present_span, v.base, data_present, v.dat_size,
-                offset, span, token)))
-            FleetReaderQueueGauge.inc()  # delta: concurrent-safe sum
+                # paced on the packing thread, which pulls the plan: a
+                # span costs 10 data reads plus the compare's parity reads
+                throttler.maybe_slowdown(span * (
+                    DATA_SHARDS + len(results[v.base].parity_checked)))
+            yield v, span, min(span, v.dat_size - offset), functools.partial(
+                _read_present_span_into, v.base, range(DATA_SHARDS),
+                v.dat_size, offset, span)
 
-    # parity fds cached per volume for the whole pass: each volume's
-    # compares run FIFO on ITS writer lane (single reader per fd), and
-    # per-span open/close would cost thousands of syscalls per volume
-    # once large fleets shrink the span. Populated INSIDE the
-    # try/finally below: an open() racing a concurrent shard delete
-    # must still tear down the pools/span and close earlier fds.
-    parity_fds: Dict[str, Dict[int, object]] = {}
+    # open for the whole pass: a volume's compares run FIFO on ITS
+    # writer lane (one reader a file), and per-span open/close would cost
+    # thousands of syscalls per volume once large fleets shrink the span
+    parity_files: Dict[Tuple[str, int], object] = {}
 
     def compare(v: _VolState, offset: int, out: np.ndarray) -> None:
-        """Runs on v's writer lane: recomputed parity [1, 4, span] (or
-        [4, span] from the host pool) vs the stored parity slices."""
+        """On v's writer lane: parity [4, span] against the stored one."""
         with _StageTimer("verify", vol=os.path.basename(v.base)):
-            parity = out[0] if out.ndim == 3 else out
             valid = min(span, v.dat_size - offset)
             r = results[v.base]
-            for sid in parity_by_tag[v.tag]:
-                f = parity_fds[v.base][sid]
+            for sid in r.parity_checked:
+                f = parity_files[v.base, sid]
                 f.seek(offset)
-                stored = f.read(valid)
-                stored_arr = np.frombuffer(stored, dtype=np.uint8)
-                row = parity[sid - DATA_SHARDS][:len(stored_arr)]
-                diff = np.nonzero(row != stored_arr)[0]
-                if len(diff):
+                stored = np.frombuffer(f.read(valid), dtype=np.uint8)
+                diff = np.nonzero(
+                    out[sid - DATA_SHARDS, :len(stored)] != stored)[0]
+                # a truncated parity shard lacks bytes the data shards say
+                # should exist: each is a mismatch, not a free pass
+                bad = len(diff) + valid - len(stored)
+                if bad:
                     r.parity_mismatch[sid] = \
-                        r.parity_mismatch.get(sid, 0) + len(diff)
-                    # spans retire in offset order on this volume's
-                    # lane, so the first recorded hit is the lowest
-                    r.first_mismatch.setdefault(sid, offset + int(diff[0]))
-                if len(stored_arr) < valid:
-                    # a truncated parity shard is missing bytes the
-                    # data shards say should exist: every absent byte
-                    # is a mismatch, not a free pass
-                    r.parity_mismatch[sid] = \
-                        r.parity_mismatch.get(sid, 0) + \
-                        (valid - len(stored_arr))
-                    r.first_mismatch.setdefault(
-                        sid, offset + len(stored_arr))
+                        r.parity_mismatch.get(sid, 0) + bad
+                    # spans retire in offset order on this volume's lane:
+                    # the first recorded hit is the lowest
+                    r.first_mismatch.setdefault(sid, offset + (
+                        int(diff[0]) if len(diff) else len(stored)))
             r.bytes_verified += DATA_SHARDS * valid
             r.spans += 1
 
-    def flush(pack) -> None:
-        with _StageTimer("dispatch", batch=len(pack)):
-            handle = dispatcher.encode(
-                [a[np.newaxis] for _, _, a in pack])
-        FleetDispatchBatchHistogram.observe(len(pack))
-        FleetDispatchedBytesCounter.inc(
-            float(sum(a.nbytes for _, _, a in pack)))
-        pipe.submit(handle, [
-            (v.tag, functools.partial(compare, v, offset))
-            for v, offset, _ in pack])
+    # batches are flushed in the plan's order: a volume's spans in turn
+    offsets = [itertools.count(0, span) for _ in vols]
 
-    try:
-        for v, parity in vols:
-            fds = parity_fds[v.base] = {}
-            for sid in parity:  # incremental: no fd lost to a partial
-                fds[sid] = open(shard_file_name(v.base, sid), "rb")
-        fill()
-        pack = []
-        while inflight:
-            item = inflight.popleft()
-            FleetReaderQueueGauge.dec()
-            with _waiting("reader"):
-                arr = item[2].result()
-            pack.append((item[0], item[1], arr))
-            fill()
-            if len(pack) >= per_batch or not inflight:
-                flush(pack)
-                pack = []
-    finally:
-        FleetReaderQueueGauge.dec(len(inflight))  # error path leftovers
-        pool.shutdown(wait=True)
-        try:
-            pipe.drain()  # may re-raise the latched pipeline error
-        finally:
-            dispatcher.close()
-            for fds in parity_fds.values():
-                for f in fds.values():
-                    f.close()
-            root.__exit__(None, None, None)
+    def flush(batch: _StagedBatch, dispatcher: _Dispatcher,
+              pipe: TaggedPipeline, release: Callable[[], None]) -> None:
+        with _StageTimer("dispatch", batch=len(batch.spans)):
+            handle = dispatcher.encode_lanes(
+                batch.buf, [(off, span) for _, off, _ in batch.spans],
+                release)
+        pipe.submit(handle, [
+            (v.tag, functools.partial(compare, v, next(offsets[v.tag])))
+            for v, _, _ in batch.spans])
+
+    with contextlib.ExitStack() as opened:
+        for v in vols:  # one by one: a failed open() closes the earlier
+            for sid in results[v.base].parity_checked:
+                parity_files[v.base, sid] = opened.enter_context(
+                    open(shard_file_name(v.base, sid), "rb"))
+        # like a rebuild, a verify reads nothing out of a dispatched buffer
+        _staged_pass(trace.span("fleet.verify", volumes=len(vols),
+                                backend=backend),
+                     backend, device, encoders, readers, depth,
+                     lanes=per_batch * span, per_buffer=per_batch,
+                     plan=plan(), flush=flush, refs=lambda batch: 1)
     return results

@@ -10,9 +10,6 @@ Backends — a backend asked for by name is used or the call raises; only
   - "jax":   bit-matrix matmul on the default JAX backend (the TPU on a
              chip host, the CPU under JAX_PLATFORMS=cpu) — see
              seaweedfs_tpu/ops/rs_kernel.py
-  - "pallas": fused Pallas TPU kernel (ops/rs_pallas.py) — opt-in,
-             byte-identical; compiles for the chip or raises (speed on
-             the attached chip: not measured)
   - "numpy": table-gather encoder on host (the plain reference)
   - "native": C++ shared library (seaweedfs_tpu/native), built from
              source on first use; raises if it cannot be built
@@ -65,7 +62,7 @@ class ReedSolomon:
             raise ValueError("bad shard counts")
         if data_shards + parity_shards > 256:
             raise ValueError("too many shards for GF(2^8)")
-        if backend not in ("auto", "jax", "numpy", "native", "pallas"):
+        if backend not in ("auto", "jax", "numpy", "native"):
             raise ValueError(f"unknown RS backend {backend!r}")
         self.data_shards = data_shards
         self.parity_shards = parity_shards
@@ -104,9 +101,6 @@ class ReedSolomon:
         if self.backend == "jax":
             from seaweedfs_tpu.ops import rs_kernel
             return rs_kernel.apply_matrix(matrix, shards)
-        if self.backend == "pallas":
-            from seaweedfs_tpu.ops import rs_pallas
-            return rs_pallas.apply_matrix(matrix, shards)
         if self.backend in ("auto", "native"):
             from seaweedfs_tpu.native import rs_native
             # asked for by name, a library that cannot be built raises

@@ -118,13 +118,11 @@ def gf_linear_gemm(m2: jnp.ndarray, shards: jnp.ndarray) -> jnp.ndarray:
     Exact, not approximate: every bit-plane dot product sums at most
     S*8 <= 112 ones (RS(10,4) maps), far inside float32's exact-integer
     range, so truncating the accumulator to int32 parity reproduces the
-    int32 einsum bit for bit. XLA's CPU backend tiles f32 GEMMs far
-    better than int8/int32 einsums (~1.4x measured on the forced
-    8-device rig); the pod-scale mesh data plane
+    int32 einsum bit for bit. The pod-scale mesh data plane
     (parallel/mesh_fleet.py) runs its per-device blocks through this
-    entry. The host fleet/serial dispatches keep the int path — their
-    slab shapes are tuned around it (migrating them is a ROADMAP
-    follow-up, gated on re-baselining BENCH.md).
+    entry; the fleet and serial dispatches keep the int path. Which of
+    the two is faster: not measured on the chip (ROADMAP C2 waits for
+    the four-chip cell).
     """
     in_bits = bits_expand(shards).astype(jnp.float32)
     acc = jnp.einsum("os,...sn->...on", m2.astype(jnp.float32), in_bits)

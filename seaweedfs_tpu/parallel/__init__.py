@@ -22,10 +22,8 @@ host gRPC).
 from seaweedfs_tpu.parallel.mesh import (
     make_mesh,
     sharded_encode,
-    sharded_write_ec_files,
     ec_pipeline_step,
     rotate_shards,
-    volume_shard_matrix,
     round_robin_by_size,
     fleet_write_ec_files_sharded,
 )
@@ -42,9 +40,9 @@ from seaweedfs_tpu.parallel.mesh_fleet import (
     sharded_reconstruct,
 )
 
-__all__ = ["make_mesh", "sharded_encode", "sharded_write_ec_files",
-           "ec_pipeline_step", "rotate_shards", "volume_shard_matrix",
-           "round_robin_by_size", "fleet_write_ec_files_sharded",
+__all__ = ["make_mesh", "sharded_encode", "ec_pipeline_step",
+           "rotate_shards", "round_robin_by_size",
+           "fleet_write_ec_files_sharded",
            "MeshError", "MeshDispatchTimeout", "MeshUnavailable",
            "MeshVerifyMismatch", "mesh_write_ec_files",
            "mesh_verify_ec_files", "mesh_rebuild_ec_files",

@@ -152,121 +152,6 @@ def _decode_bits(drop: Tuple[int, int]):
     return m2_bits(rs._decode_matrix(present[:DATA_SHARDS], drop))
 
 
-# -- many-volumes-over-the-mesh encode (BASELINE config 4 shape) -------------
-
-# Lane window per sharded dispatch: bounds host memory at
-# dp * DATA_SHARDS * _WINDOW_LANES bytes and keeps the number of
-# distinct XLA shapes small (full windows share one compile).
-_WINDOW_LANES = 64 << 20
-
-def volume_shard_matrix(dat_path: str, small_block: int) -> np.ndarray:
-    """A volume's .dat as its shard-content matrix [D, n_rows*small_block].
-
-    Row r of the .dat is dat[r*D*sb : (r+1)*D*sb]; shard i's slice of
-    that row is its i-th sb-sized block (reference ec_encoder.go row
-    striping). Stacking rows per shard gives exactly the bytes of
-    .ec00..ec09 — a pure reshape, no compute."""
-    raw = np.fromfile(dat_path, dtype=np.uint8)
-    row_bytes = DATA_SHARDS * small_block
-    n_rows = -(-len(raw) // row_bytes)   # 0 rows for an empty .dat
-    padded = np.zeros(n_rows * row_bytes, dtype=np.uint8)
-    padded[: len(raw)] = raw
-    rows = padded.reshape(n_rows, DATA_SHARDS, small_block)
-    return np.ascontiguousarray(
-        np.moveaxis(rows, 0, 1)).reshape(DATA_SHARDS, n_rows * small_block)
-
-
-def sharded_write_ec_files(mesh: Mesh, base_names: Sequence[str],
-                           small_block: int = 1 << 20) -> None:
-    """Encode MANY volumes in one mesh-sharded dispatch and write each
-    volume's .ec00-.ec13.
-
-    The BASELINE config-4 shape: the volume batch rides the dp axis,
-    each volume's byte lanes ride sp — the cluster-wide `ec.encode`
-    cron that the reference fans out over gRPC
-    (shell/command_ec_encode.go:92-160) becomes one XLA program over
-    the mesh. Volumes under 10*large_block use uniform small rows, so
-    this matches write_ec_files' on-disk layout byte-for-byte.
-    """
-    from seaweedfs_tpu.ec.encoder import (
-        LARGE_BLOCK_SIZE, TOTAL_SHARDS as _TS, shard_file_name)
-
-    if not base_names:
-        return
-    dat_sizes = {}
-    for b in base_names:
-        dat_sizes[b] = os.path.getsize(b + ".dat")
-        if dat_sizes[b] > DATA_SHARDS * LARGE_BLOCK_SIZE:
-            raise ValueError(
-                f"{b}.dat exceeds {DATA_SHARDS}x{LARGE_BLOCK_SIZE} bytes: "
-                "large-row striping required — use write_ec_files")
-    dp, sp = mesh.shape["dp"], mesh.shape["sp"]
-    row_bytes = DATA_SHARDS * small_block
-    shard_rows = {b: -(-dat_sizes[b] // row_bytes) for b in base_names}
-
-    # Group volumes by size (desc) into dp-sized batches so lane
-    # padding only stretches to the largest volume IN THE GROUP, then
-    # stream each group through fixed lane WINDOWS: peak host memory is
-    # dp * 10 * window bytes regardless of volume or batch size (the
-    # review finding: a size-skewed batch must not allocate
-    # n_vols x max_volume bytes).
-    window_rows = max(1, _WINDOW_LANES // small_block)
-    ordered = sorted(base_names, key=lambda b: shard_rows[b], reverse=True)
-    # a volume's 14 output fds stay open for its whole group (= its
-    # whole active life in the pass): per-window "ab" reopens cost 14
-    # open/close syscall pairs per volume per window (the fd-churn
-    # satellite finding). Volumes outside the current group only need
-    # their files truncated, which creating the group fds does anyway.
-    for base in base_names:                      # fresh output files
-        for i in range(_TS):
-            open(shard_file_name(base, i), "wb").close()
-    for g0 in range(0, len(ordered), dp):
-        group = ordered[g0:g0 + dp]
-        max_rows = shard_rows[group[0]]
-        fds = {}
-        try:
-            for base in group:
-                fds[base] = [open(shard_file_name(base, i), "r+b")
-                             for i in range(_TS)]
-            for w0 in range(0, max_rows, window_rows):
-                rows = min(window_rows, max_rows - w0)
-                lanes = -(-(rows * small_block) // sp) * sp
-                data = np.zeros((dp, DATA_SHARDS, lanes), dtype=np.uint8)
-                for v, base in enumerate(group):
-                    v_rows = min(max(shard_rows[base] - w0, 0), rows)
-                    if v_rows == 0:
-                        continue
-                    # read rows [w0, w0+v_rows) straight from the .dat:
-                    # one sequential read, reshaped to shard-major
-                    start = w0 * row_bytes
-                    want = v_rows * row_bytes
-                    with open(base + ".dat", "rb") as f:
-                        f.seek(start)
-                        raw = f.read(min(want,
-                                         max(dat_sizes[base] - start, 0)))
-                    buf = np.zeros(want, dtype=np.uint8)
-                    buf[: len(raw)] = np.frombuffer(raw, dtype=np.uint8)
-                    m = np.ascontiguousarray(np.moveaxis(
-                        buf.reshape(v_rows, DATA_SHARDS, small_block),
-                        0, 1)).reshape(DATA_SHARDS, v_rows * small_block)
-                    data[v, :, : m.shape[1]] = m
-                    for i in range(DATA_SHARDS):  # systematic data shards
-                        fds[base][i].write(m[i].tobytes())
-                parity = np.asarray(sharded_encode(mesh, data))
-                for v, base in enumerate(group):
-                    v_lanes = min(max(shard_rows[base] - w0, 0),
-                                  rows) * small_block
-                    if v_lanes == 0:
-                        continue
-                    for p in range(parity.shape[1]):
-                        fds[base][DATA_SHARDS + p].write(
-                            parity[v, p, : v_lanes].tobytes())
-        finally:
-            for group_fds in fds.values():
-                for f in group_fds:
-                    f.close()
-
-
 # -- fleet scheduler sharded over the devices (ec/fleet.py) ------------------
 
 def round_robin_by_size(base_names: Sequence[str],

@@ -662,9 +662,9 @@ def _read_shard_rows(base: str, sids: Sequence[int], shard_size: int,
                      parent: Optional[int],
                      fds: Optional[_FdCache] = None) -> np.ndarray:
     """[len(sids), lanes] slice at `offset` of the named shard files,
-    zero-padded past `shard_size` (the generalization of
-    fleet._read_present_span to an arbitrary row set — the rebuild
-    check reads ALL present rows, not just the decode's ten). With an
+    zero-padded past `shard_size`, in an array of its own (any row
+    set: the rebuild check reads ALL present rows, not just the
+    decode's ten). With an
     _FdCache the rows fill via os.preadv on cached fds; without one
     (host-fleet callers) each file opens per call as before."""
     with _fleet._StageTimer("read", parent=parent,
@@ -687,8 +687,8 @@ def _read_span_matrix(base: str, row0: int, rows: int, row_bytes: int,
                       small_block: int,
                       parent: Optional[int]) -> np.ndarray:
     """Rows [row0, row0+rows) of one .dat as the shard-major
-    [DATA_SHARDS, rows*small_block] matrix (volume_shard_matrix's
-    layout, windowed) — zero-padded past EOF."""
+    [DATA_SHARDS, rows*small_block] matrix (row i is the span's bytes
+    of .ec0i) — zero-padded past EOF."""
     with _fleet._StageTimer("read", parent=parent,
                             vol=os.path.basename(base)):
         with open(base + ".dat", "rb") as f:

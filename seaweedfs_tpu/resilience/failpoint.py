@@ -18,8 +18,9 @@ Sites in this tree (each passes labels the arming spec can match on):
                     short simulates a torn write
   rpc.call          rpc.make_stub, before every outbound gRPC
                     (`method`)
-  fleet.dispatch    ec/fleet._Dispatcher, before every fused RS
-                    dispatch (`op`)
+  fleet.dispatch    ec/fleet._Dispatcher._lanes, before every fused
+                    RS dispatch of an encode, rebuild or verify pass
+                    (`op`: encode | reconstruct)
 
 Arming:
 

@@ -204,7 +204,7 @@ class VolumeServer:
         return f"{self.ip}:{self.port}"
 
     def start(self) -> None:
-        if self.ec_encoder in ("jax", "pallas"):
+        if self.ec_encoder == "jax":
             # device codec: place the persistent compile cache before
             # the first dispatch compiles anything (one owner, see
             # util/compile_cache.py). Host codecs never import jax.

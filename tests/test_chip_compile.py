@@ -91,16 +91,6 @@ def test_gf_linear_gemm_compiles_for_the_chip(one_chip):
     assert compiled is not None
 
 
-@pytest.mark.parametrize("lanes", [32768, LANES_MAX])
-def test_pallas_call_compiles_for_the_chip(one_chip, lanes):
-    """The Pallas entry at (o, s, n) = (4, 10, lanes), interpret OFF:
-    a Mosaic kernel (`tpu_custom_call`) in the compiled program."""
-    from seaweedfs_tpu.ops import rs_pallas
-    call = rs_pallas._build_call(4, 10, lanes, False)
-    compiled = call.lower(_m2(one_chip), _data(one_chip, lanes)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
 def test_mesh_gf_program_compiles_for_the_2x2_mesh(topo):
     """mesh_fleet's shard_map GF program on a (dp, sp) = (2, 2) Mesh of
     the described devices: matrix replicated, bucket sharded

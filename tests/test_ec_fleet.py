@@ -21,6 +21,7 @@ from seaweedfs_tpu.ec import fleet, store_ec
 from seaweedfs_tpu.ec.encoder import shard_file_name
 from seaweedfs_tpu.ops.rs_code import ReedSolomon, DATA_SHARDS, TOTAL_SHARDS
 from seaweedfs_tpu.stats.metrics import FleetRebuildGroupsCounter
+from tests.test_scrub import _flip_byte
 
 LARGE = 2048
 SMALL = 256
@@ -437,15 +438,22 @@ REBUILD_FLOOR = 128
 REBUILD_CHUNK = DATA_SHARDS * 2 * 700   # spans of ~700 bytes, two a buffer
 
 
+def _encoded(root, sizes, seed):
+    """Volumes of `sizes` with the serial numpy encoder's 14 files."""
+    bases = _make_volumes(str(root), sizes, seed=seed)
+    for base in bases:
+        ec.write_ec_files(base, backend="numpy", large_block=ROOMY,
+                          small_block=SMALL)
+    return bases
+
+
 def _lose_and_twin(root, sizes, seed):
     """Volumes of `sizes` encoded by the serial encoder, shards LOST
     removed; beside each a twin of the 12 survivors that the serial
     numpy rebuild has already repaired. Returns (bases, twins)."""
-    bases = _make_volumes(str(root), sizes, seed=seed)
+    bases = _encoded(root, sizes, seed)
     twins = []
     for base in bases:
-        ec.write_ec_files(base, backend="numpy", large_block=ROOMY,
-                          small_block=SMALL)
         for sid in LOST:
             os.remove(shard_file_name(base, sid))
         twin = base + ".serial"
@@ -641,8 +649,8 @@ def test_read_span_into_zeroes_past_eof_on_every_use(tmp_path):
 ])
 def test_read_present_span_into_zeroes_past_the_shard_end_on_every_use(
         tmp_path, case, offset, sizes, want):
-    """A rebuild span's reader owns every lane of its span in a dirty
-    buffer: what it does not fill from a survivor it zeroes, and it
+    """A rebuild or verify span's reader owns every lane of its span in
+    a dirty buffer: what it does not fill from a survivor it zeroes, and it
     touches no lane of another span."""
     rng = np.random.default_rng(35)
     base, span, off = str(tmp_path / "v"), 300, 200
@@ -664,10 +672,6 @@ def test_read_present_span_into_zeroes_past_the_shard_end_on_every_use(
             assert not buf[row, off + n:off + span].any(), (case, row)
         assert (buf[:, :off] == 0xAB).all()
         assert (buf[:, off + span:] == 0xAB).all()
-    # verify's reader is the same one over an array of its own
-    assert np.array_equal(
-        fleet._read_present_span(base, present, sizes[0], offset, span),
-        buf[:, off:off + span])
 
 
 @pytest.mark.parametrize("iov_max, most", [(3, None), (1024, 100), (7, 33)])
@@ -1005,3 +1009,223 @@ def test_concurrent_passes_share_nothing_but_the_idle_list(tmp_path,
         _assert_shards_equal(bases, twins)
     assert len(fleet._IDLE_STAGING._bufs) <= 8
     assert len(set(_idle_shapes())) <= 1
+
+
+# --- verify on the staged loop ------------------------------------------------
+# The scrub's pass is a plan and a flush for `_staged_pass`, like encode
+# and rebuild: same span rule as rebuild, same reused buffers, the same
+# 2-D view into the dispatch layer.
+
+VERIFY_FIELDS = ("parity_mismatch", "first_mismatch", "missing",
+                 "parity_checked", "bytes_verified", "verified")
+
+
+def _flip(base, sid, offset):
+    _flip_byte(shard_file_name(base, sid), offset)
+
+
+def _plain_verify(base):
+    """The reference: whole shards, one numpy encode, one compare."""
+    shards = {}
+    for sid in range(TOTAL_SHARDS):
+        path = shard_file_name(base, sid)
+        if os.path.exists(path):
+            shards[sid] = np.fromfile(path, dtype=np.uint8)
+    want = {"missing": [s for s in range(TOTAL_SHARDS) if s not in shards],
+            "parity_checked": [s for s in shards if s >= DATA_SHARDS],
+            "parity_mismatch": {}, "first_mismatch": {},
+            "bytes_verified": DATA_SHARDS * len(shards[0]), "verified": True}
+    parity = ReedSolomon(backend="numpy").encode(
+        np.stack([shards[i] for i in range(DATA_SHARDS)]))
+    for sid in want["parity_checked"]:
+        diff = np.nonzero(parity[sid - DATA_SHARDS] != shards[sid])[0]
+        if len(diff):
+            want["parity_mismatch"][sid] = len(diff)
+            want["first_mismatch"][sid] = int(diff[0])
+    return want
+
+
+@pytest.mark.parametrize("backend", ["numpy", "native", "jax"])
+def test_verify_equals_serial_on_every_backend(tmp_path, monkeypatch,
+                                               backend):
+    """Three volumes of unequal size — one shorter than a span, one not
+    a multiple of it — over several buffers, the shorter ones' last
+    spans beside spans of a later round: every field of the result but
+    `spans` is what a plain whole-shard compare finds."""
+    if backend == "native":
+        from seaweedfs_tpu.native import rs_native
+        if not rs_native.available():
+            pytest.skip("native lib not built")
+    monkeypatch.setattr(fleet, "SMALL_BLOCK_SIZE", REBUILD_FLOOR)
+    bases = _encoded(tmp_path, [40 * ROW, 13 * ROW + 77, 200], 60)
+    sizes = [os.path.getsize(shard_file_name(b, 0)) for b in bases]
+    span, per_batch = fleet._stacked_spans(REBUILD_CHUNK, sizes)
+    assert (span, per_batch) == (466, 3)
+    assert sizes[2] < span and sizes[1] % span and sizes[0] > 3 * span
+    _flip(bases[0], 12, 5 * span + 17)         # a parity shard
+    _flip(bases[0], 12, 5 * span + 18)
+    _flip(bases[1], 3, sizes[1] - 1)           # a data shard's last byte
+    got = fleet.fleet_verify_ec_files(bases, backend=backend,
+                                      chunk=REBUILD_CHUNK)
+    for base, size in zip(bases, sizes):
+        want = _plain_verify(base)
+        assert {f: getattr(got[base], f) for f in VERIFY_FIELDS} == want
+        assert got[base].spans == -(-size // span)
+    assert got[bases[0]].parity_mismatch == {12: 2}
+    assert sorted(got[bases[1]].parity_mismatch) == [10, 11, 12, 13]
+    assert got[bases[2]].clean
+
+
+@pytest.mark.parametrize("case", ["parity-last-lane", "parity-first-lane",
+                                  "data-shard"])
+def test_verify_finds_damage_on_span_and_buffer_edges(tmp_path, monkeypatch,
+                                                      case):
+    """Two volumes, two spans a buffer: volume 0's span k lies in lanes
+    [0, span) of buffer k, volume 1's in [span, 2 * span). One flipped
+    byte on an edge is found at its offset, once, in the right shard."""
+    monkeypatch.setattr(fleet, "SMALL_BLOCK_SIZE", REBUILD_FLOOR)
+    bases = _encoded(tmp_path, [40 * ROW, 40 * ROW], 61)
+    span, per_batch = fleet._stacked_spans(REBUILD_CHUNK, [40 * SMALL] * 2)
+    assert (span, per_batch) == (683, 2)
+    hit, sid, offset = {
+        # the last lane of a span that another volume's span follows
+        "parity-last-lane": (0, 11, span - 1),
+        # the first lane of the next buffer
+        "parity-first-lane": (0, 12, span),
+        # the last lane of a buffer, in a data shard: all four parity
+        # shards disagree there
+        "data-shard": (1, 4, 2 * span - 1),
+    }[case]
+    _flip(bases[hit], sid, offset)
+    got = fleet.fleet_verify_ec_files(bases, backend="numpy",
+                                      chunk=REBUILD_CHUNK)
+    shards = [sid] if sid >= DATA_SHARDS else [10, 11, 12, 13]
+    assert got[bases[hit]].parity_mismatch == {s: 1 for s in shards}
+    assert got[bases[hit]].first_mismatch == {s: offset for s in shards}
+    assert got[bases[1 - hit]].clean
+    assert all(r.spans == 15 and r.bytes_verified == 400 * ROW // 10
+               for r in got.values())
+
+
+def test_verify_dispatches_views_of_reused_staging(tmp_path, monkeypatch):
+    """A scrub after an encode makes no staging buffer: every buffer it
+    is handed is one the encode pass filled, and what reaches the
+    dispatch layer is a 2-D view of one of them — no stacked copy."""
+    from seaweedfs_tpu.ops import rs_kernel
+
+    monkeypatch.setattr(fleet, "SMALL_BLOCK_SIZE", REBUILD_FLOOR)
+    bases = _make_volumes(str(tmp_path), [6 * ROW] * 3, seed=62)
+    fleet.fleet_write_ec_files(bases, backend="jax", large_block=ROOMY,
+                               small_block=SMALL, chunk=2 * ROW)
+    idle = list(fleet._IDLE_STAGING._bufs)
+    assert len(idle) == 6 and _idle_shapes() == [(DATA_SHARDS, 512)] * 6
+    seen = []
+    real = rs_kernel.apply_matrix_async
+
+    def recording(matrix, shards, device=None):
+        seen.append(shards)
+        return real(matrix, shards, device=device)
+
+    monkeypatch.setattr(rs_kernel, "apply_matrix_async", recording)
+    fresh, reused = _handed("fresh"), _handed("reused")
+    chunk = DATA_SHARDS * 512
+    span, per_batch = fleet._stacked_spans(chunk, [6 * SMALL] * 3)
+    assert (span, per_batch) == (154, 3)       # 30 spans, 10 dispatches
+    got = fleet.fleet_verify_ec_files(bases, backend="jax", chunk=chunk)
+    assert all(r.clean and r.spans == 10 for r in got.values())
+    assert _handed("fresh") == fresh
+    assert _handed("reused") - reused == 10
+    assert [id(b) for b in fleet._IDLE_STAGING._bufs] == \
+        [id(b) for b in idle]
+    assert len(seen) == 10
+    for arr in seen:
+        assert arr.shape == (DATA_SHARDS, per_batch * span)
+        assert sum(np.shares_memory(arr, b) for b in idle) == 1
+
+
+@pytest.mark.parametrize("n", [1, 2, 128])
+def test_verify_span_rule_is_the_rebuild_rule(tmp_path, monkeypatch, n):
+    """`chunk` means in a verify pass what it means in the other two:
+    the input bytes of all ten rows of one fused dispatch. Files of
+    zeros are a valid stripe, so the pass runs and finds them clean."""
+    small_block, size = 512, 1280
+    monkeypatch.setattr(fleet, "SMALL_BLOCK_SIZE", small_block)
+    chunk = DATA_SHARDS * 4 * small_block
+    bases = [str(tmp_path / f"{v}") for v in range(n)]
+    for base in bases:
+        for sid in range(TOTAL_SHARDS):
+            with open(shard_file_name(base, sid), "wb") as f:
+                f.truncate(size)
+    span, per_batch = fleet._stacked_spans(chunk, [size] * n)
+    assert (span, per_batch) == {1: (1280, 1), 2: (640, 2),
+                                 128: (427, 4)}[n]
+    batches = []
+    encode_lanes = fleet._Dispatcher.encode_lanes
+
+    def counted(self, buf, cuts, done):
+        batches.append(cuts)
+        return encode_lanes(self, buf, cuts, done)
+
+    monkeypatch.setattr(fleet._Dispatcher, "encode_lanes", counted)
+    got = fleet.fleet_verify_ec_files(bases, backend="numpy", chunk=chunk)
+    per_volume = -(-size // span)
+    assert all(r.clean and r.spans == per_volume for r in got.values())
+    assert [len(cuts) for cuts in batches] == \
+        [per_batch] * (n * per_volume // per_batch)
+    assert {w for cuts in batches for _, w in cuts} == {span}
+    assert all([off for off, _ in cuts] ==
+               [i * span for i in range(per_batch)] for cuts in batches)
+
+
+def test_verify_paces_the_throttler_per_span(tmp_path, monkeypatch):
+    """The read side is paced once a planned span, by the ten data
+    reads and the parity reads its compare will make — three for a
+    volume that lost a parity shard."""
+    class Counting:
+        def __init__(self):
+            self.seen = []
+
+        def maybe_slowdown(self, n):
+            self.seen.append(n)
+
+    monkeypatch.setattr(fleet, "SMALL_BLOCK_SIZE", REBUILD_FLOOR)
+    bases = _encoded(tmp_path, [40 * ROW, 40 * ROW], 63)
+    os.remove(shard_file_name(bases[1], 13))
+    throttler = Counting()
+    got = fleet.fleet_verify_ec_files(bases, backend="numpy",
+                                      chunk=REBUILD_CHUNK,
+                                      throttler=throttler)
+    span = 683
+    assert throttler.seen == [14 * span, 13 * span] * 15
+    assert got[bases[0]].clean and got[bases[0]].spans == 15
+    assert got[bases[1]].missing == [13] and got[bases[1]].verified
+    assert got[bases[1]].parity_checked == [10, 11, 12]
+    assert not got[bases[1]].parity_mismatch and got[bases[1]].spans == 15
+
+
+def test_verify_failed_pass_leaks_nothing(tmp_path, monkeypatch):
+    """A dispatch that fails fails the call, and the call leaves nothing
+    behind: the parity files are closed, the buffers it filled are on
+    the idle list, and the next pass runs in them."""
+    from seaweedfs_tpu.resilience import failpoint
+
+    monkeypatch.setattr(fleet, "SMALL_BLOCK_SIZE", REBUILD_FLOOR)
+    bases = _encoded(tmp_path, [40 * ROW, 40 * ROW], 64)
+    open_fds = len(os.listdir("/proc/self/fd"))
+    fresh = _handed("fresh")
+    failpoint.arm("fleet.dispatch", "error", match={"op": "encode"})
+    try:
+        with pytest.raises(failpoint.FailpointError):
+            fleet.fleet_verify_ec_files(bases, backend="numpy",
+                                        chunk=REBUILD_CHUNK)
+    finally:
+        failpoint.disarm("fleet.dispatch")
+    assert len(os.listdir("/proc/self/fd")) == open_fds
+    made = int(_handed("fresh") - fresh)
+    assert made >= 1 and len(fleet._IDLE_STAGING._bufs) == made
+    idle = [id(b) for b in fleet._IDLE_STAGING._bufs]
+    got = fleet.fleet_verify_ec_files(bases, backend="numpy",
+                                      chunk=REBUILD_CHUNK)
+    assert all(r.clean and r.spans == 15 for r in got.values())
+    assert [id(b) for b in fleet._IDLE_STAGING._bufs][:made] == idle
+    assert len(os.listdir("/proc/self/fd")) == open_fds

@@ -72,82 +72,6 @@ def test_pipeline_step_at_64mb_per_device(mesh):
     np.testing.assert_array_equal(np.asarray(rebuilt)[:, 1, :], want[:, 3, :])
 
 
-def test_sharded_write_ec_files_over_volumes(mesh, tmp_path):
-    """Many volumes encoded in ONE mesh dispatch (BASELINE config-4
-    shape) must produce byte-identical .ecNN files to the per-volume
-    host write_ec_files path — including odd sizes that exercise row
-    padding and the batch/lane mesh padding."""
-    from seaweedfs_tpu.ec.encoder import shard_file_name, write_ec_files
-    from seaweedfs_tpu.parallel import sharded_write_ec_files
-
-    small = 64 << 10  # 64KB rows keep the fixture fast but multi-row
-    rng = np.random.default_rng(11)
-    sizes = [3 * 640 * 1024 + 13, 640 * 1024, 2 * 640 * 1024 + 1,
-             640 * 1024 - 7, 5 * 640 * 1024, 640 * 1024 + small,
-             7 * 640 * 1024 + small // 2,
-             0]  # 8 volumes incl. an EMPTY one (must match host: 0-byte shards)
-    bases = []
-    for v, size in enumerate(sizes):
-        base = str(tmp_path / f"{v + 1}")
-        with open(base + ".dat", "wb") as f:
-            f.write(rng.integers(0, 256, size, dtype=np.uint8).tobytes())
-        bases.append(base)
-
-    sharded_write_ec_files(mesh, bases, small_block=small)
-    for v, base in enumerate(bases):
-        ref_base = str(tmp_path / f"ref{v + 1}")
-        os.link(base + ".dat", ref_base + ".dat")
-        write_ec_files(ref_base, backend="auto", small_block=small)
-        for i in range(14):
-            with open(shard_file_name(base, i), "rb") as f:
-                got = f.read()
-            with open(shard_file_name(ref_base, i), "rb") as f:
-                want = f.read()
-            assert got == want, f"volume {v + 1} shard {i} diverged"
-
-
-def test_sharded_write_ec_files_windowed(mesh, tmp_path, monkeypatch):
-    """Size-skewed batch with a tiny lane window: grouping by size and
-    multi-window streaming must still be byte-identical to the host."""
-    from seaweedfs_tpu.ec.encoder import shard_file_name, write_ec_files
-    from seaweedfs_tpu.parallel import mesh as mesh_mod
-
-    small = 16 << 10
-    monkeypatch.setattr(mesh_mod, "_WINDOW_LANES", 2 * small)  # 2-row windows
-    rng = np.random.default_rng(3)
-    # one big volume among small ones: the skew case from the review
-    sizes = [9 * 160 * 1024 + 5, 160 * 1024, 17, 2 * 160 * 1024]
-    bases = []
-    for v, size in enumerate(sizes):
-        base = str(tmp_path / f"{v + 1}")
-        with open(base + ".dat", "wb") as f:
-            f.write(rng.integers(0, 256, size, dtype=np.uint8).tobytes())
-        bases.append(base)
-    mesh_mod.sharded_write_ec_files(mesh, bases, small_block=small)
-    for v, base in enumerate(bases):
-        ref_base = str(tmp_path / f"ref{v + 1}")
-        os.link(base + ".dat", ref_base + ".dat")
-        write_ec_files(ref_base, backend="auto", small_block=small)
-        for i in range(14):
-            with open(shard_file_name(base, i), "rb") as f:
-                got = f.read()
-            with open(shard_file_name(ref_base, i), "rb") as f:
-                want = f.read()
-            assert got == want, f"volume {v + 1} shard {i} diverged"
-
-
-def test_sharded_write_ec_files_edge_cases(mesh, tmp_path):
-    from seaweedfs_tpu.ec.encoder import LARGE_BLOCK_SIZE
-    from seaweedfs_tpu.parallel import sharded_write_ec_files
-
-    sharded_write_ec_files(mesh, [])  # no volumes: no-op
-    big = str(tmp_path / "big")
-    with open(big + ".dat", "wb") as f:  # sparse: size without bytes
-        f.truncate(10 * LARGE_BLOCK_SIZE + 1)
-    with pytest.raises(ValueError, match="large-row"):
-        sharded_write_ec_files(mesh, [big])
-
-
 def test_make_mesh_factoring_pinned(mesh):
     """The sp loop's factoring, pinned per device count (ISSUE 11
     satellite): sp is the largest power of two with sp^2*4 <= n that
@@ -206,36 +130,6 @@ def test_round_robin_by_size(tmp_path):
     assert sorted(len(b) for b in spread) == [2, 2]
     # more buckets than volumes: the extras stay empty
     assert [len(b) for b in round_robin_by_size(empties, 8)].count(1) == 4
-
-
-def test_sharded_write_ec_files_boundary_sizes(mesh, tmp_path):
-    """ISSUE 11 satellite: the small-block boundary sizes — 0, 1 byte,
-    exactly row_bytes, row_bytes+1 — byte-identical to the host path
-    (padding edges are where layout bugs live)."""
-    from seaweedfs_tpu.ec.encoder import shard_file_name, write_ec_files
-    from seaweedfs_tpu.parallel import sharded_write_ec_files
-
-    small = 16 << 10
-    row_bytes = DATA_SHARDS * small
-    rng = np.random.default_rng(23)
-    sizes = [0, 1, row_bytes, row_bytes + 1]
-    bases = []
-    for v, size in enumerate(sizes):
-        base = str(tmp_path / f"{v + 1}")
-        with open(base + ".dat", "wb") as f:
-            f.write(rng.integers(0, 256, size, dtype=np.uint8).tobytes())
-        bases.append(base)
-    sharded_write_ec_files(mesh, bases, small_block=small)
-    for v, base in enumerate(bases):
-        ref_base = str(tmp_path / f"ref{v + 1}")
-        os.link(base + ".dat", ref_base + ".dat")
-        write_ec_files(ref_base, backend="auto", small_block=small)
-        for i in range(14):
-            with open(shard_file_name(base, i), "rb") as f:
-                got = f.read()
-            with open(shard_file_name(ref_base, i), "rb") as f:
-                want = f.read()
-            assert got == want, f"size {sizes[v]} shard {i} diverged"
 
 
 def test_rotate_shards_permutes_batch(mesh):

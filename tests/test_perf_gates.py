@@ -18,27 +18,49 @@ import pytest
 from tests.cluster_util import Cluster
 
 
-def test_data_plane_floor(tmp_path):
-    """In-process config-7 shape, small n: write/read req/s floors.
+def test_data_plane_floor(tmp_path, capsys):
+    """In-process config-7 shape, small n: every request of the write
+    and the read plane completes, over sockets with Nagle off.
 
-    Measured (BASELINE.md round 5): ~3,600 write / ~12,000 read at
-    n=30k c=16. Floors of 500/1,200 sit 4-8x under that but well above
-    the Nagle-stalled plane (~360 req/s both ways), which is the
-    regression class this exists to catch.
+    The regression class this exists to catch is a dropped TCP_NODELAY
+    (the Nagle-stalled plane: ~360 req/s both ways, against ~3,600
+    write / ~12,000 read, BASELINE.md round 5). That is asserted as
+    what repeats exactly on any machine: the option read back from the
+    run's own client sockets and from the sockets the volume server
+    accepted for them. Connection reuse, the other half of the class,
+    is test_pooled_client_reuses_connections's. The two rates are
+    printed, not asserted: a rate belongs to a benchmark cell
+    (`weed-bench.small-io`, PERF.md section 7), on a machine that runs
+    nothing else.
     """
+    import socket
+
     from seaweedfs_tpu.command.benchmark import run_benchmark_programmatic
+    from seaweedfs_tpu.util import http_client
+    n = 2500
     c = Cluster(tmp_path, n_volume_servers=1)
     try:
-        r = run_benchmark_programmatic(c.master.url, n=2500,
+        r = run_benchmark_programmatic(c.master.url, n=n,
                                        concurrency=8, size=1024,
                                        do_read=True, out=io.StringIO())
+        vs = c.volume_servers[0]
+        with http_client._pool_lock:
+            dialed = [conn.sock for conn in http_client._pool[vs.url]]
+        with vs._http_server._conns_lock:
+            accepted = list(vs._http_server._conns)
+        nodelay = [s.getsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY)
+                   for s in dialed + accepted]
     finally:
         c.stop()
-    write_rps = r["write"].completed / r["write_seconds"]
-    read_rps = r["read"].completed / r["read_seconds"]
     assert r["write"].failed == 0 and r["read"].failed == 0
-    assert write_rps >= 500, f"write plane regressed: {write_rps:.0f} req/s"
-    assert read_rps >= 1200, f"read plane regressed: {read_rps:.0f} req/s"
+    assert r["write"].completed == n and r["read"].completed == n
+    # the load threads' keep-alive connections are still up, both ends
+    assert dialed and len(accepted) >= len(dialed)
+    assert all(nodelay), f"Nagle is on: {nodelay}"
+    with capsys.disabled():
+        print(f"\ndata plane, not asserted: "
+              f"{n / r['write_seconds']:.0f} write req/s, "
+              f"{n / r['read_seconds']:.0f} read req/s")
 
 
 def test_ec_kernel_floor(monkeypatch):

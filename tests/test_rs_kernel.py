@@ -113,58 +113,20 @@ def test_jax_vs_numpy_large_random_matrices():
         assert np.array_equal(out, gf256.gf_linear_numpy(m, data))
 
 
-def test_pallas_backend_byte_equality(monkeypatch):
-    """The opt-in Pallas codec matches numpy byte-for-byte on encode
-    and reconstruct, including odd lane counts that exercise the
-    128-lane padding. Off the chip the program itself never interprets
-    (test_pallas_backend_raises_off_chip), so THIS test asks for the
-    Pallas interpreter: a test-side wrapper around gf_linear_pallas."""
-    import functools
+def test_pallas_is_not_a_backend(capsys):
+    """The fused Pallas codec went in PR 29 (never run in a cell): its
+    name is an unknown backend to the codec and to the volume server's
+    `-ec.encoder`."""
+    from seaweedfs_tpu.command import servers
 
-    import numpy as np
-
-    from seaweedfs_tpu.ops import rs_pallas
-    from seaweedfs_tpu.ops.rs_code import ReedSolomon
-
-    monkeypatch.setattr(
-        rs_pallas, "gf_linear_pallas",
-        functools.partial(rs_pallas.gf_linear_pallas, interpret=True))
-    rng = np.random.default_rng(5)
-    ref = ReedSolomon(backend="numpy")
-    pal = ReedSolomon(backend="pallas")
-    lane_cases = (128, 1000, 4096 + 17,
-                  rs_pallas.TILE + 257)   # crosses a tile boundary
-    for lanes in lane_cases:
-        data = rng.integers(0, 256, size=(10, lanes), dtype=np.uint8)
-        np.testing.assert_array_equal(pal.encode(data), ref.encode(data))
-    # empty batch round-trips without dispatch
-    empty = np.zeros((0, 10, 256), dtype=np.uint8)
-    assert pal.encode(empty).shape == (0, 4, 256)
-    data = rng.integers(0, 256, size=(10, 777), dtype=np.uint8)
-    full = ref.encode_all(data)
-    present = [0, 2, 3, 4, 6, 7, 8, 9, 10, 12]
-    src = full[present, :]
-    np.testing.assert_array_equal(
-        pal.reconstruct_some(present, [1, 5, 11, 13], src),
-        ref.reconstruct_some(present, [1, 5, 11, 13], src))
-
-
-def test_pallas_backend_raises_off_chip():
-    """No quiet interpret mode: off the TPU the Pallas codec's entry
-    compiles for the chip or raises — it never falls back to the
-    interpreter (or to another backend) by itself."""
-    import jax
-
-    assert jax.default_backend() != "tpu"
-    data = np.zeros((10, 256), dtype=np.uint8)
-    with pytest.raises(Exception) as ei:
-        ReedSolomon(backend="pallas").encode(data)
-    assert "interpret" in str(ei.value).lower()
-    # asked for by the caller, the interpreter still works
-    from seaweedfs_tpu.ops import rs_pallas
-    out = rs_pallas.gf_linear_pallas(
-        ReedSolomon().matrix[10:], data, interpret=True)
-    assert np.asarray(out).shape == (4, 256)
+    with pytest.raises(ValueError, match="unknown RS backend"):
+        ReedSolomon(backend="pallas")
+    parser = servers._volume_parser()
+    with pytest.raises(SystemExit):
+        parser.parse_args(["-ec.encoder", "pallas"])
+    assert "invalid choice: 'pallas'" in capsys.readouterr().err
+    for name in ("auto", "jax", "native", "numpy"):
+        assert parser.parse_args(["-ec.encoder", name]).ec_encoder == name
 
 
 def test_named_backend_is_never_substituted(monkeypatch):
