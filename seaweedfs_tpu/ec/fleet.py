@@ -8,12 +8,13 @@ This module batches chunks ACROSS volumes. Its three passes — encode
 (`fleet_verify_ec_files`, the scrub) — are each a plan of spans and a
 flush for ONE loop, `_staged_pass`:
 
-  pack      spans from many volumes share one [10, lanes] staging
-            buffer — the layout the device wants — so 64 small volumes
-            cost a handful of dispatches, and nothing is copied between
-            the read and the placement: the buffer IS the dispatch's
-            input, reused from dispatch to dispatch and from pass to
-            pass (`_Staging`).
+  pack      spans from many volumes share the ten input rows of one
+            [14, lanes] staging buffer — the layout the device wants —
+            so 64 small volumes cost a handful of dispatches, and
+            nothing is copied between the read and the placement: the
+            buffer IS the dispatch's input and, in its last rows, the
+            place its result lands, reused from dispatch to dispatch
+            and from pass to pass (`_Staging`).
   feed      a bounded reader pool prefetches spans ahead of the device,
             each reader filling its span's lanes straight from the .dat
             (encode), the ten surviving shard files (rebuild) or the
@@ -337,33 +338,40 @@ class _Dispatcher:
                 max_workers=max(1, encoders),
                 thread_name_prefix="fleet-encode")
 
-    def _lanes(self, op: str, apply_async, apply, buf: np.ndarray,
-               cuts: List[Tuple[int, int]], done: Callable[[], None]):
-        """One GF map over a staging buffer [10, lanes] whose spans lie
-        at `cuts` = [(lane offset, lanes)], back to back from lane 0:
-        .result() yields one [rows out, lanes] array a span. The jax
-        branch hands the filled lanes over as they are, a 2-D view: no
-        copy before the dispatch layer's slab slices, none after its
-        fetch. Host backends get one pool task a span, each a view.
-        `done` runs when every read of `buf` on behalf of this dispatch
-        is over (retire thread)."""
+    def _lanes(self, op: str, rows: int, apply_async, apply,
+               buf: np.ndarray, cuts: List[Tuple[int, int]],
+               done: Callable[[], None]):
+        """One GF map of `rows` output rows over a staging buffer
+        [14, lanes] whose spans lie at `cuts` = [(lane offset, lanes)],
+        back to back from lane 0: .result() yields one [rows, lanes]
+        array a span. The jax branch hands the filled lanes of the ten
+        input rows over as they are, a 2-D view, and lends the same
+        lanes of the rows after them for the result: no copy before the
+        dispatch layer's slab slices, and after its fetch one copy into
+        memory that was touched before. Host backends get one pool task
+        a span, each a view; their codecs allocate their own results.
+        `done` runs when every read of the input rows on behalf of this
+        dispatch is over (retire thread)."""
         if _failpoint._armed:
             _failpoint.hit("fleet.dispatch", op=op)
         if self._pool is None:
             with _StageTimer("pack", spans=len(cuts)):
-                data = buf[:, :cuts[-1][0] + cuts[-1][1]]
-            handle = apply_async(data, device=self._device)
+                used = cuts[-1][0] + cuts[-1][1]
+                data = buf[:DATA_SHARDS, :used]
+                out = buf[DATA_SHARDS:DATA_SHARDS + rows, :used]
+            handle = apply_async(data, device=self._device, out=out)
             return _SplitHandle(handle, [n for _, n in cuts], done)
         token = trace.handoff()
-        return _Gathered([self._pool.submit(_rs_staged, apply,
-                                            buf[:, off:off + n], token)
-                          for off, n in cuts], done)
+        return _Gathered([self._pool.submit(
+            _rs_staged, apply, buf[:DATA_SHARDS, off:off + n], token)
+            for off, n in cuts], done)
 
     def encode_lanes(self, buf: np.ndarray, cuts: List[Tuple[int, int]],
                      done: Callable[[], None]):
         """Parity [4, lanes] of every span of a staging buffer."""
-        return self._lanes("encode", self._rs.encode_async,
-                           self._rs.encode, buf, cuts, done)
+        return self._lanes("encode", TOTAL_SHARDS - DATA_SHARDS,
+                           self._rs.encode_async, self._rs.encode, buf,
+                           cuts, done)
 
     def reconstruct_lanes(self, present, missing, buf: np.ndarray,
                           cuts: List[Tuple[int, int]],
@@ -371,7 +379,7 @@ class _Dispatcher:
         """Shards `missing` [len(missing), lanes] of every span of a
         staging buffer whose rows are the first ten of `present`."""
         return self._lanes(
-            "reconstruct",
+            "reconstruct", len(missing),
             functools.partial(self._rs.reconstruct_some_async, present,
                               missing),
             functools.partial(self._rs.reconstruct_some, present, missing),
@@ -384,8 +392,9 @@ class _Dispatcher:
 
 class _SplitHandle:
     """Adapt one fused async handle over a staging buffer's lanes back
-    to per-span outputs: views of `sizes` lanes each. `done` runs once
-    the fused result is on the host."""
+    to per-span outputs: views of `sizes` lanes each of the result rows
+    the dispatcher lent. `done` runs once the fused result is on the
+    host."""
 
     def __init__(self, handle, sizes: List[int], done: Callable[[], None]):
         self._handle = handle
@@ -438,7 +447,7 @@ class _IdleStaging:
     """The process's staging buffers between passes, so that the next
     pass (the next shell command) fills memory that is already mapped
     instead of faulting in fresh pages. Buffers are kept by CAPACITY,
-    [10, capacity] with ONE capacity at a time, and a pass borrows
+    [14, capacity] with ONE capacity at a time, and a pass borrows
     `[:, :lanes]` views of them: an encode pass's width is set by its
     chunk, a rebuild pass's by the largest shard it was given, and a
     server that alternates them (or rebuilds volumes of another size
@@ -479,7 +488,8 @@ _IDLE_STAGING = _IdleStaging()
 
 class _StagedBatch:
     """One staging buffer on its way through a pass: the spans planned
-    into it, and how many closures still have to read it."""
+    into it, and how many closures still have to read it (its input
+    rows or its result rows)."""
 
     __slots__ = ("buf", "used", "spans", "refs")
 
@@ -494,12 +504,18 @@ class _StagedBatch:
 class _Staging:
     """One pass's share of staging buffers.
 
-    A staging buffer is [DATA_SHARDS, lanes] uint8 — a fused dispatch's
-    input in the layout the device wants. The readers fill it straight
-    from the .dat files (encode), the surviving shard files (rebuild)
-    or the data shard files (verify), the dispatch layer places slices
-    of it, an encode pass's writer lanes write the data shards out of
-    it; then it comes round again. The share is what the pipeline has
+    A staging buffer is [TOTAL_SHARDS, lanes] uint8 — the whole stripe
+    of a fused dispatch in the layout the device wants. Rows 0-9 are
+    its input: the readers fill them straight from the .dat files
+    (encode), the surviving shard files (rebuild) or the data shard
+    files (verify), the dispatch layer places slices of them, an encode
+    pass's writer lanes write the data shards out of them. Rows 10..
+    are where the retire thread puts its result — the first four of
+    them the parity (encode, verify), the first len(missing) the
+    rebuilt shards — and where the writer lanes read it: the parity and
+    rebuilt-shard writes, a verify's compares. Then the buffer comes
+    round again. A rebuild of two shards never touches rows 12-13: they
+    stay address space. The share is what the pipeline has
     in flight anyway (see _staged_pass), so the one place a pass can
     block here — `acquire`, timed as fleet.wait.staging — blocks only
     while buffers are downstream of the packing thread, where they come
@@ -528,7 +544,7 @@ class _Staging:
                 # first round: what an earlier pass left, then new ones
                 if self._handed == len(self._held):
                     self._held.append(np.empty(
-                        (DATA_SHARDS, self._capacity), dtype=np.uint8))
+                        (TOTAL_SHARDS, self._capacity), dtype=np.uint8))
                     state = "fresh"
                 buf = self._held[self._handed][:, :self._lanes]
             else:
@@ -626,6 +642,19 @@ def _write_data_shards(base: str, arr: np.ndarray,
     done()
 
 
+def _then_release(fn: Callable[[np.ndarray], None],
+                  release: Callable[[], None]) -> Callable:
+    """`fn(out)` for a writer lane, `out` one span's lanes of a staging
+    buffer's result rows: the buffer is told that this reader of it is
+    through once fn has run, also when it raised."""
+    def run(out: np.ndarray) -> None:
+        try:
+            fn(out)
+        finally:
+            release()
+    return run
+
+
 def _write_parity_span(base: str, seg: np.ndarray) -> None:
     """One span's parity [4, n] -> append to .ec10-.ec13."""
     for p in range(seg.shape[0]):
@@ -697,21 +726,22 @@ def fleet_write_ec_files(base_names: Sequence[str], backend: str = "auto",
         # (enqueued here, in pack order, so per-volume FIFO holds)
         for v, off, n in batch.spans:
             pipe.write(v.tag, functools.partial(
-                _write_data_shards, v.base, batch.buf[:, off:off + n],
-                release))
+                _write_data_shards, v.base,
+                batch.buf[:DATA_SHARDS, off:off + n], release))
         pipe.submit(handle, [
-            (v.tag, functools.partial(_write_parity_span, v.base))
+            (v.tag, _then_release(
+                functools.partial(_write_parity_span, v.base), release))
             for v, _, _ in batch.spans])
 
     # a buffer is free again when the retire thread has the dispatch's
     # result (every transfer out of it is over) AND each span's
-    # data-shard write has run on its lane
+    # data-shard write and parity write have run on its lane
     _staged_pass(trace.span("fleet.encode", volumes=len(alive),
                             backend=backend),
                  backend, device, encoders, readers, depth,
                  lanes=batch_rows * small_block,
                  per_buffer=batch_rows // span_rows, plan=plan(),
-                 flush=flush, refs=lambda batch: 1 + len(batch.spans))
+                 flush=flush, refs=lambda batch: 1 + 2 * len(batch.spans))
 
 
 def _staged_pass(root, backend: str, device, encoders: int, readers: int,
@@ -740,11 +770,15 @@ def _staged_pass(root, backend: str, device, encoders: int, readers: int,
     prefetch = max(readers, 2 * per_buffer)
     # The pass's share of staging buffers is what the pipeline holds at
     # once: upstream of the dispatch the prefetched spans' buffers (one
-    # more when they straddle), downstream `depth` queued dispatches
-    # and the one in the retire thread's hand, whose data-shard writes
-    # may still be queued on the lanes. Upstream never needs them all,
-    # so a pass out of buffers always has some coming back.
-    staging = _Staging(lanes, -(-prefetch // per_buffer) + 1 + depth + 1,
+    # more when they straddle), downstream `depth` queued dispatches,
+    # the one in the retire thread's hand, whose result is being copied
+    # into its last rows, and the one before it, whose result the
+    # writer lanes are reading (lanes that fall further behind than one
+    # dispatch hold the pass up here, as fleet.wait.staging, where it
+    # would next wait for a lane). Upstream never needs them all, so a
+    # pass out of buffers always has some coming back.
+    staging = _Staging(lanes,
+                       -(-prefetch // per_buffer) + 1 + depth + 1 + 1,
                        pipe._raise_pending)
     inflight: deque = deque()
     filling: Optional[_StagedBatch] = None
@@ -949,18 +983,19 @@ def _fleet_rebuild_group(present: List[int], missing: List[int],
                 present, missing, batch.buf,
                 [(off, span) for _, off, _ in batch.spans], release)
         pipe.submit(handle, [
-            (v.tag, functools.partial(_write_rebuilt_span, v.base, missing,
-                                      valid))
+            (v.tag, _then_release(
+                functools.partial(_write_rebuilt_span, v.base, missing,
+                                  valid), release))
             for v, _, valid in batch.spans])
 
-    # a rebuild writes nothing out of a buffer: it is free again when
-    # the retire thread has the dispatch's result
+    # a buffer is free again when the retire thread has the dispatch's
+    # result AND each span's rebuilt shards are written out of it
     _staged_pass(trace.span("fleet.rebuild", volumes=len(members),
                             backend=backend, groups=groups, present=present,
                             missing=missing),
                  backend, device, encoders, readers, depth,
                  lanes=per_batch * span, per_buffer=per_batch, plan=plan(),
-                 flush=flush, refs=lambda batch: 1)
+                 flush=flush, refs=lambda batch: 1 + len(batch.spans))
 
 
 # --- fleet verify ------------------------------------------------------------
@@ -1083,7 +1118,9 @@ def fleet_verify_ec_files(base_names: Sequence[str], backend: str = "auto",
                 batch.buf, [(off, span) for _, off, _ in batch.spans],
                 release)
         pipe.submit(handle, [
-            (v.tag, functools.partial(compare, v, next(offsets[v.tag])))
+            (v.tag, _then_release(
+                functools.partial(compare, v, next(offsets[v.tag])),
+                release))
             for v, _, _ in batch.spans])
 
     with contextlib.ExitStack() as opened:
@@ -1091,10 +1128,11 @@ def fleet_verify_ec_files(base_names: Sequence[str], backend: str = "auto",
             for sid in results[v.base].parity_checked:
                 parity_files[v.base, sid] = opened.enter_context(
                     open(shard_file_name(v.base, sid), "rb"))
-        # like a rebuild, a verify reads nothing out of a dispatched buffer
+        # like a rebuild's: the result AND each span's compare
         _staged_pass(trace.span("fleet.verify", volumes=len(vols),
                                 backend=backend),
                      backend, device, encoders, readers, depth,
                      lanes=per_batch * span, per_buffer=per_batch,
-                     plan=plan(), flush=flush, refs=lambda batch: 1)
+                     plan=plan(), flush=flush,
+                     refs=lambda batch: 1 + len(batch.spans))
     return results

@@ -118,7 +118,7 @@ class ReedSolomon:
             raise ValueError(f"expected {self.data_shards} data shards")
         return self._apply(self.matrix[self.data_shards:], data)
 
-    def encode_async(self, data: np.ndarray, device=None):
+    def encode_async(self, data: np.ndarray, device=None, out=None):
         """Pipelined encode: returns a handle with .result() -> parity.
 
         On the jax backend the dispatch is issued immediately and the
@@ -126,7 +126,9 @@ class ReedSolomon:
         compute synchronously and return a pre-resolved handle, so
         pipeline-structured callers work uniformly. `device` pins the
         dispatch to one jax device (the fleet scheduler runs one
-        scheduler per device); ignored by host backends.
+        scheduler per device); `out` lends the result its memory
+        (rs_kernel.apply_matrix_async). Host backends ignore both: the
+        codec allocates its own result.
         """
         data = np.asarray(data, dtype=np.uint8)
         if data.shape[-2] != self.data_shards:
@@ -134,7 +136,8 @@ class ReedSolomon:
         if self.backend == "jax":
             from seaweedfs_tpu.ops import rs_kernel
             return rs_kernel.apply_matrix_async(
-                self.matrix[self.data_shards:], data, device=device)
+                self.matrix[self.data_shards:], data, device=device,
+                out=out)
         return _Resolved(self._apply(self.matrix[self.data_shards:], data))
 
     def encode_all(self, data: np.ndarray) -> np.ndarray:
@@ -171,10 +174,11 @@ class ReedSolomon:
 
     def reconstruct_some_async(self, present: Sequence[int],
                                wanted: Sequence[int],
-                               shard_data: np.ndarray, device=None):
+                               shard_data: np.ndarray, device=None,
+                               out=None):
         """Pipelined reconstruct_some: returns a handle with .result().
 
-        Same contract as encode_async (including `device` pinning) — on
+        Same contract as encode_async (`device` pinning and `out`) — on
         the jax backend the dispatch is in flight while the caller
         overlaps host IO (the rebuild pipelines in ec/encoder.py and
         ec/fleet.py ride this)."""
@@ -184,7 +188,8 @@ class ReedSolomon:
         if self.backend == "jax":
             from seaweedfs_tpu.ops import rs_kernel
             return rs_kernel.apply_matrix_async(
-                m, shard_data[..., : self.data_shards, :], device=device)
+                m, shard_data[..., : self.data_shards, :], device=device,
+                out=out)
         return _Resolved(self._apply(m, shard_data[..., : self.data_shards, :]))
 
     def reconstruct(self, shards: list[Optional[np.ndarray]],

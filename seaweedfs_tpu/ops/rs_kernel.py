@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import functools
 import threading
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -33,7 +34,8 @@ import numpy as np
 
 from seaweedfs_tpu.ops import gf256
 from seaweedfs_tpu.stats import trace
-from seaweedfs_tpu.stats.metrics import RsDispatchSecondsHistogram
+from seaweedfs_tpu.stats.metrics import (
+    RsDispatchSecondsHistogram, RsResultBuffersCounter)
 
 _BIT_SHIFTS = tuple(range(8))
 
@@ -42,6 +44,10 @@ _BIT_SHIFTS = tuple(range(8))
 _PHASE_HIST = {p: RsDispatchSecondsHistogram.labels(p)
                for p in ("stage", "place", "enqueue", "wait", "fetch",
                          "unstage")}
+# Where a dispatch's result landed: memory the caller lent (touched
+# before, so the copy pays no page faults) or a fresh array.
+_RESULT_INTO = {state: RsResultBuffersCounter.labels(state)
+                for state in ("lent", "fresh")}
 
 
 def _phase(phase: str, **tags) -> trace.PhaseTimer:
@@ -180,18 +186,26 @@ class PendingApply:
     ec_encoder.go:120-136).
     """
 
-    def __init__(self, parts, o: int, n: int, batch_shape, lanes: int):
+    def __init__(self, parts, o: int, n: int, batch_shape, lanes: int,
+                 out: Optional[np.ndarray] = None):
         self._parts = parts          # [(device_array, want, pos)]
         self._o = o
         self._n = n
         self._batch_shape = batch_shape
         self._lanes = lanes
+        self._out = out              # the caller's [o, n], or None
 
     def result(self) -> np.ndarray:
         o, n = self._o, self._n
+        out = self._out
         if n == 0:
-            return np.zeros(self._batch_shape + (o, 0), dtype=np.uint8)
-        out = np.empty((o, n), dtype=np.uint8)
+            return out if out is not None else \
+                np.zeros(self._batch_shape + (o, 0), dtype=np.uint8)
+        if out is None:
+            out = np.empty((o, n), dtype=np.uint8)
+            _RESULT_INTO["fresh"].inc()
+        else:
+            _RESULT_INTO["lent"].inc()
         for res, want, pos in self._parts:
             # waiting apart from fetching: the device (a transfer's
             # tail and the kernel) against the device->host copy
@@ -209,8 +223,8 @@ class PendingApply:
         return out
 
 
-def apply_matrix_async(matrix: np.ndarray, shards,
-                       device=None) -> PendingApply:
+def apply_matrix_async(matrix: np.ndarray, shards, device=None,
+                       out: Optional[np.ndarray] = None) -> PendingApply:
     """Dispatch apply_matrix without waiting for the device.
 
     Returns a PendingApply whose .result() blocks. Between submit and
@@ -221,6 +235,14 @@ def apply_matrix_async(matrix: np.ndarray, shards,
     default placement / lane sharding: the fleet scheduler
     (ec/fleet.py) runs one scheduler per device, so each scheduler's
     slabs must land on its own chip.
+
+    `out` lends the result its memory, for a 2-D input [S, n] only: a
+    [O, n] uint8 array whose rows are contiguous. .result() copies each
+    fetched slab into it and returns it, where it would copy into a
+    fresh np.empty((O, n)) — whose pages every dispatch faults in anew.
+    A caller that keeps `out` between dispatches (ec/fleet._Staging)
+    pays those faults once. What cannot take the result raises here,
+    not in the thread that fetches it.
     """
     matrix = np.ascontiguousarray(matrix, dtype=np.uint8)
     m2 = _m2_device(matrix.tobytes(), matrix.shape[0], matrix.shape[1])
@@ -230,8 +252,10 @@ def apply_matrix_async(matrix: np.ndarray, shards,
     batch_shape = shards.shape[:-2]
     s, n = shards.shape[-2:]
     o = matrix.shape[0]
+    if out is not None:
+        _check_lent(out, o, n, batch_shape)
     if n == 0:
-        return PendingApply([], o, 0, batch_shape, n)
+        return PendingApply([], o, 0, batch_shape, n, out)
     if batch_shape:
         with _phase("stage"):
             flat = np.ascontiguousarray(
@@ -239,7 +263,22 @@ def apply_matrix_async(matrix: np.ndarray, shards,
     else:
         flat = shards
     parts = _submit_slabs(m2, flat, device=device)
-    return PendingApply(parts, o, flat.shape[1], batch_shape, n)
+    return PendingApply(parts, o, flat.shape[1], batch_shape, n, out)
+
+
+def _check_lent(out, o: int, n: int, batch_shape) -> None:
+    """Can `out` take the [o, n] result of a 2-D dispatch?"""
+    if batch_shape:
+        raise ValueError(
+            "out= takes the result of a 2-D [S, n] input; this one is "
+            f"stacked {batch_shape + ('S', n)}")
+    if not isinstance(out, np.ndarray) or out.dtype != np.uint8:
+        raise ValueError("out= must be a uint8 numpy array, not "
+                         f"{getattr(out, 'dtype', type(out).__name__)}")
+    if out.shape != (o, n):
+        raise ValueError(f"out= has shape {out.shape}, the result {(o, n)}")
+    if not out.flags.writeable or (n > 1 and out.strides[1] != 1):
+        raise ValueError("out= must be writable, its rows contiguous")
 
 
 # Dispatch in fixed, power-of-two lane widths. Every distinct shape costs

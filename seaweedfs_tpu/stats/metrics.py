@@ -319,8 +319,9 @@ FleetWaitSecondsHistogram = REGISTRY.histogram(
     "SeaweedFS_fleet_wait_seconds",
     "fleet scheduler: time one thread blocked on another", ("on",))
 # Staging buffers handed to the encode scheduler's readers: `state` is
-# fresh (never written before: its first fill pays the page faults) or
-# reused (touched by an earlier dispatch of this pass or an earlier pass).
+# fresh (never written before: its first fill, and the first result copied
+# into its last rows, pay the page faults) or reused (touched by an earlier
+# dispatch of this pass or an earlier pass).
 FleetStagingBuffersCounter = REGISTRY.counter(
     "SeaweedFS_fleet_staging_buffers_total",
     "staging buffers handed out by the fleet encode scheduler",
@@ -356,6 +357,13 @@ FleetWriterBacklogGauge = REGISTRY.gauge(
 RsDispatchSecondsHistogram = REGISTRY.histogram(
     "SeaweedFS_rs_dispatch_seconds",
     "RS device dispatch: host-side time by phase", ("phase",))
+# Where PendingApply.result() put a dispatch's result: `lent` (memory
+# the caller handed over with out=, touched before) or `fresh` (an
+# np.empty of its own: the copy out pays the page faults).
+RsResultBuffersCounter = REGISTRY.counter(
+    "SeaweedFS_rs_result_buffers_total",
+    "results of RS device dispatches by the memory they landed in",
+    ("state",))
 
 # Unified mesh scheduler families (parallel/mesh_fleet.py): the
 # pod-scale data plane's bucket stream. `op` is the dispatch kind

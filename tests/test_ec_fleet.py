@@ -386,12 +386,14 @@ def test_write_dat_file_backend_chunk_default(tmp_path):
         assert f.read() == original
 
 
-# --- the staging buffers (ISSUE 26: encode; ISSUE 28: rebuild) ---------------
+# --- the staging buffers (ISSUE 26: encode; ISSUE 28: rebuild; ISSUE 30: the
+# result rows) ----------------------------------------------------------------
 #
-# Spans are read straight into reused [10, lanes] buffers. What that can
-# break: a reused buffer holds an earlier dispatch's bytes (padding past
-# EOF, or past a shard's end), and a buffer handed out again while
-# something still reads it.
+# Spans are read straight into the ten input rows of reused [14, lanes]
+# buffers, and a jax dispatch's result lands in the rows after them. What
+# that can break: a reused buffer holds an earlier dispatch's bytes (padding
+# past EOF, or past a shard's end; an earlier, wider result), and a buffer
+# handed out again while something still reads it.
 
 @pytest.fixture(autouse=True)
 def no_idle_staging(monkeypatch):
@@ -407,6 +409,13 @@ ROOMY = 16 * LARGE
 def _handed(state):
     from seaweedfs_tpu.stats.metrics import FleetStagingBuffersCounter
     return FleetStagingBuffersCounter.labels(state).value
+
+
+def _landed(state):
+    """Results of device dispatches by where they landed: `lent` (the
+    result rows of a staging buffer) or `fresh` (an array a dispatch)."""
+    from seaweedfs_tpu.stats.metrics import RsResultBuffersCounter
+    return RsResultBuffersCounter.labels(state).value
 
 
 def _two_passes(tmp_path, backend, sizes_by_pass, **kw):
@@ -529,23 +538,55 @@ def test_stale_staging_bytes_never_reach_a_shard(tmp_path, monkeypatch,
 
 
 @pytest.mark.parametrize("backend", ["numpy", "jax"])
+@pytest.mark.parametrize("kind", sorted(STALE))
+def test_stale_result_bytes_never_reach_a_shard(tmp_path, monkeypatch, kind,
+                                                backend):
+    """A dispatch's result lands in the last rows of its staging buffer,
+    where the result of an earlier, wider dispatch still lies. The first
+    pass fills every buffer with full spans; then every byte of every
+    idle buffer — all 14 rows, the lanes no later dispatch uses too — is
+    overwritten with a pattern, and the ragged pass, whose last
+    dispatches are narrower, runs in them: what reaches the shard files
+    is the serial encoder's bytes, none of the pattern's."""
+    wide, ragged = STALE[kind]
+    for n, sizes in enumerate((wide, ragged)):
+        root = tmp_path / f"run{n}"
+        root.mkdir()
+        counts, = _passes(kind, root, monkeypatch, backend, [sizes])
+        if n == 0:
+            assert counts[0] > 0 and fleet._IDLE_STAGING._bufs
+            for buf in fleet._IDLE_STAGING._bufs:
+                assert buf.shape[0] == TOTAL_SHARDS
+                buf[:] = 0xA5
+    assert counts == (0, counts[1]) and counts[1] > 0, \
+        "the second pass did not run in the first pass's buffers"
+
+
+@pytest.mark.parametrize("backend", ["numpy", "jax"])
 @pytest.mark.parametrize("kind, sizes, first_counts, second_counts", [
     # 9 dispatches of 2 rows; share: 2 prefetched spans a buffer -> 2
-    # buffers + 1, depth 2 + 1
-    ("encode", [9 * ROW, 9 * ROW], (6, 3), (0, 9)),
+    # buffers + 1, depth 2 + 1, one whose result is on the lanes
+    ("encode", [9 * ROW, 9 * ROW], (7, 2), (0, 9)),
     # 15 spans of 683 a volume, two a buffer: 15 dispatches, same share
-    ("rebuild", [40 * ROW, 40 * ROW], (6, 9), (0, 15)),
+    ("rebuild", [40 * ROW, 40 * ROW], (7, 8), (0, 15)),
 ])
 def test_second_pass_of_one_geometry_hands_out_no_fresh_buffer(
         tmp_path, monkeypatch, kind, sizes, first_counts, second_counts,
         backend):
     """The counter the benchmark's fleet_staging_reuse_share reads: a
     pass touches min(share, dispatches) buffers whatever the timing,
-    and the next pass of the same geometry is handed only those."""
+    and the next pass of the same geometry is handed only those. And
+    the one rs_result_lent_share reads: every device dispatch of a pass
+    puts its result into the buffer it was lent, none into an array of
+    its own (a host codec's results are its own, and not counted)."""
+    lent, fresh = _landed("lent"), _landed("fresh")
     first, second = _passes(kind, tmp_path, monkeypatch, backend,
                             [sizes, sizes], readers=2)
     assert first == first_counts
     assert second == second_counts
+    assert _landed("fresh") == fresh
+    assert _landed("lent") - lent == \
+        (sum(first) + sum(second) if backend == "jax" else 0)
 
 
 def test_short_pass_touches_only_the_buffers_it_fills(tmp_path):
@@ -564,7 +605,7 @@ def test_a_narrower_pass_borrows_the_idle_buffers(tmp_path, monkeypatch):
     runs in the wider pass's buffers and leaves them as they were."""
     monkeypatch.setattr(fleet, "SMALL_BLOCK_SIZE", 3 * SMALL)
     _two_passes(tmp_path, "numpy", [[4 * ROW]])          # 2 * SMALL lanes
-    assert _idle_shapes() == [(DATA_SHARDS, 3 * SMALL)] * 2
+    assert _idle_shapes() == [(TOTAL_SHARDS, 3 * SMALL)] * 2
     kept = [id(b) for b in fleet._IDLE_STAGING._bufs]
     bases = _make_volumes(str(tmp_path), [4 * ROW], seed=3)
     twins = _serial_twin(bases)
@@ -577,7 +618,7 @@ def test_a_narrower_pass_borrows_the_idle_buffers(tmp_path, monkeypatch):
     # four dispatches of one row in the two buffers kept, two new ones
     # of the same capacity beside them
     assert _handed("fresh") - fresh == 2
-    assert _idle_shapes() == [(DATA_SHARDS, 3 * SMALL)] * 4
+    assert _idle_shapes() == [(TOTAL_SHARDS, 3 * SMALL)] * 4
     assert [id(b) for b in fleet._IDLE_STAGING._bufs][:2] == kept
 
 
@@ -588,10 +629,10 @@ def test_a_wider_pass_replaces_the_idle_buffers(tmp_path, monkeypatch):
     bases = _make_volumes(str(tmp_path), [4 * ROW], seed=3)
     fleet.fleet_write_ec_files(bases, backend="numpy", large_block=ROOMY,
                                small_block=SMALL, chunk=ROW)
-    assert _idle_shapes() == [(DATA_SHARDS, SMALL)] * 4
+    assert _idle_shapes() == [(TOTAL_SHARDS, SMALL)] * 4
     first, = _two_passes(tmp_path, "numpy", [[4 * ROW]])  # 2 * SMALL lanes
     assert first == (2, 0)
-    assert _idle_shapes() == [(DATA_SHARDS, 2 * SMALL)] * 2
+    assert _idle_shapes() == [(TOTAL_SHARDS, 2 * SMALL)] * 2
 
 
 @pytest.mark.parametrize("backend", ["numpy", "jax"])
@@ -608,9 +649,9 @@ def test_alternating_encode_and_rebuild_passes_keep_their_buffers(
         root = tmp_path / f"round{n}"
         root.mkdir()
         counts += _passes(kind, root, monkeypatch, backend, [sizes])
-    share = 6
+    share = 7
     assert [fresh for fresh, _ in counts] == [share, share, 0, 0]
-    assert _idle_shapes() == [(DATA_SHARDS, 11 * REBUILD_FLOOR)] * share
+    assert _idle_shapes() == [(TOTAL_SHARDS, 11 * REBUILD_FLOOR)] * share
 
 
 def test_read_span_into_zeroes_past_eof_on_every_use(tmp_path):
@@ -735,19 +776,20 @@ def test_staging_buffer_is_free_only_after_result_and_every_write():
 
 @pytest.mark.parametrize("backend", ["numpy", "jax"])
 @pytest.mark.parametrize("kind, sizes, dispatches, writes", [
-    # every span's data-shard write reads the buffer too
-    ("encode", [5 * ROW + 1, 3 * ROW, 700], 5, 6 + 3 + 1),
-    # a rebuild writes nothing out of the buffer
-    ("rebuild", [40 * ROW, 13 * ROW + 77], 11, 0),
+    # every span's data-shard write reads the buffer, and its parity
+    # write too
+    ("encode", [5 * ROW + 1, 3 * ROW, 700], 5, 2 * (6 + 3 + 1)),
+    # every span's rebuilt shards are written out of the buffer
+    ("rebuild", [40 * ROW, 13 * ROW + 77], 11, 15 + 6),
 ])
 def test_buffer_readers_are_the_retire_thread_and_the_writer_lanes(
         tmp_path, monkeypatch, kind, sizes, dispatches, writes, backend):
     """Who lets go of a buffer, and where: the retire thread once a
     dispatch, when it has the result (every transfer out of the buffer
-    is over), and, in an encode pass, a writer lane once a span, when
-    the data-shard write has run. Nothing is released from the packing
-    thread, so a buffer is never free before its result is on the
-    host."""
+    is over), and a writer lane once for every write of a span: its
+    parity or its rebuilt shards, and, in an encode pass, its data
+    shards. Nothing is released from the packing thread, so a buffer is
+    never free before its result is on the host."""
     import threading
 
     by_thread = []
@@ -824,7 +866,7 @@ def test_buffer_is_not_handed_out_before_its_data_shard_writes(tmp_path,
 
     t = threading.Thread(target=run, daemon=True)
     t.start()
-    share = 2 + 1 + 1 + 1            # 2 prefetched spans, one a buffer
+    share = 2 + 1 + 1 + 1 + 1        # 2 prefetched spans, one a buffer
     deadline = time.monotonic() + 10
     while len(handed) < share and time.monotonic() < deadline:
         time.sleep(0.01)
@@ -842,10 +884,11 @@ def test_buffer_is_not_handed_out_before_its_data_shard_writes(tmp_path,
 
 def test_rebuild_buffer_is_not_handed_out_before_its_result(tmp_path,
                                                             monkeypatch):
-    """With the first dispatch's compute held back, the pass runs out of
-    buffers and WAITS: the buffer under that dispatch goes to no reader
-    until the retire thread has its result — and the rebuilt shards
-    come out byte-identical once it has."""
+    """With the first dispatch's compute held back, the pass fills what
+    the pipeline holds up to the retire thread and WAITS: the buffer
+    under that dispatch goes to no reader until the retire thread has
+    its result — and the rebuilt shards come out byte-identical once it
+    has."""
     import threading
     import time
 
@@ -881,13 +924,15 @@ def test_rebuild_buffer_is_not_handed_out_before_its_result(tmp_path,
 
     t = threading.Thread(target=run, daemon=True)
     t.start()
-    share = 2 + 1 + 1 + 1            # 4 prefetched spans, two a buffer
+    # 4 prefetched spans, two a buffer; with no result yet none is on
+    # the lanes, so the share's last buffer stays where it is
+    busy = 2 + 1 + 1 + 1
     deadline = time.monotonic() + 10
-    while len(handed) < share and time.monotonic() < deadline:
+    while len(handed) < busy and time.monotonic() < deadline:
         time.sleep(0.01)
     time.sleep(0.3)                  # room for a wrong hand-out
     try:
-        assert len(handed) == share and len(set(handed)) == share
+        assert len(handed) == busy and len(set(handed)) == busy
         assert handed.count(held[0]) == 1
     finally:
         gate.set()
@@ -895,6 +940,101 @@ def test_rebuild_buffer_is_not_handed_out_before_its_result(tmp_path,
     assert not t.is_alive() and not errors
     assert len(handed) == 15 and handed.count(held[0]) >= 2
     _assert_shards_equal(bases, twins)
+
+
+@pytest.mark.parametrize("kind, dispatches", [
+    ("encode", 13), ("rebuild", 15), ("verify", 15)])
+def test_buffer_is_not_handed_out_while_a_lane_reads_its_result(
+        tmp_path, monkeypatch, kind, dispatches):
+    """The result rows have a reader of their own: the parity write
+    (encode), the rebuilt-shard write (rebuild), the compare (verify),
+    each on a writer lane. With the first of them held back the pass
+    runs out of buffers and WAITS: the buffer whose result that closure
+    reads goes to no reader, and no other buffer of the share comes
+    round before it (their closures queue behind the held one) — and
+    the output is right once it has run."""
+    import threading
+    import time
+
+    gate = threading.Event()
+    handed, held = [], []
+    real_acquire = fleet._Staging.acquire
+    real_then = fleet._then_release
+
+    def acquire(self):
+        buf = real_acquire(self)
+        handed.append(id(buf.base))      # lent as a view of the kept one
+        return buf
+
+    def then_release(fn, release):
+        run = real_then(fn, release)
+
+        def held_back(out):
+            if not held:
+                # jax: one span's lanes of the buffer's result rows
+                held.append(id(out.base))
+                gate.wait(30)
+            run(out)
+        return held_back
+
+    monkeypatch.setattr(fleet._Staging, "acquire", acquire)
+    monkeypatch.setattr(fleet, "_then_release", then_release)
+    monkeypatch.setattr(fleet, "SMALL_BLOCK_SIZE", REBUILD_FLOOR)
+    verified = {}
+    if kind == "encode":
+        bases = _make_volumes(str(tmp_path), [24 * ROW + 9], seed=37)
+        twins = _serial_twin(bases)
+        ec.write_ec_files(twins[0], backend="numpy", large_block=ROOMY,
+                          small_block=SMALL)
+        run = functools.partial(
+            fleet.fleet_write_ec_files, bases, backend="jax",
+            large_block=ROOMY, small_block=SMALL, chunk=2 * ROW, readers=1,
+            depth=1)
+    elif kind == "rebuild":
+        bases, twins = _lose_and_twin(tmp_path, [40 * ROW, 40 * ROW], 38)
+        run = functools.partial(
+            fleet.fleet_rebuild_ec_files, bases, backend="jax",
+            chunk=REBUILD_CHUNK, readers=1, depth=1)
+    else:
+        bases = twins = _encoded(tmp_path, [40 * ROW, 40 * ROW], 39)
+        _flip(bases[1], 11, 2000)
+
+        def run():
+            verified.update(fleet.fleet_verify_ec_files(
+                bases, backend="jax", chunk=REBUILD_CHUNK, readers=1,
+                depth=1))
+    errors = []
+
+    def guarded():
+        try:
+            run()
+        except BaseException as e:  # surfaced below
+            errors.append(e)
+
+    t = threading.Thread(target=guarded, daemon=True)
+    t.start()
+    share = 2 + 1 + 1 + 1 + 1
+    # until the pass stands still: nothing handed out for a while
+    deadline = time.monotonic() + 30
+    seen, quiet = -1, 0
+    while quiet < 10 and time.monotonic() < deadline:
+        time.sleep(0.05)
+        quiet = quiet + 1 if held and len(handed) == seen else 0
+        seen = len(handed)
+    try:
+        assert held and t.is_alive()
+        assert len(handed) <= share and len(set(handed)) == len(handed)
+        assert handed.count(held[0]) == 1
+    finally:
+        gate.set()
+        t.join(30)
+    assert not t.is_alive() and not errors
+    assert len(handed) == dispatches and handed.count(held[0]) >= 2
+    _assert_shards_equal(bases, twins)
+    if kind == "verify":
+        assert verified[bases[0]].clean and verified[bases[0]].spans == 15
+        assert verified[bases[1]].parity_mismatch == {11: 1}
+        assert verified[bases[1]].first_mismatch == {11: 2000}
 
 
 @pytest.mark.parametrize("kind", ["encode", "rebuild"])
@@ -950,7 +1090,7 @@ def test_failed_pass_returns_every_staging_buffer(tmp_path, monkeypatch,
     fresh1, reused1 = _handed("fresh"), _handed("reused")
     run(bases)
     _assert_shards_equal(bases, twins)
-    share = 2 + 1 + 2 + 1            # 4 prefetched spans, two a buffer
+    share = 2 + 1 + 2 + 1 + 1        # 4 prefetched spans, two a buffer
     assert _handed("fresh") - fresh1 == share - made
     assert _handed("reused") - reused1 == 20 - (share - made)
 
@@ -1118,13 +1258,13 @@ def test_verify_dispatches_views_of_reused_staging(tmp_path, monkeypatch):
     fleet.fleet_write_ec_files(bases, backend="jax", large_block=ROOMY,
                                small_block=SMALL, chunk=2 * ROW)
     idle = list(fleet._IDLE_STAGING._bufs)
-    assert len(idle) == 6 and _idle_shapes() == [(DATA_SHARDS, 512)] * 6
+    assert len(idle) == 7 and _idle_shapes() == [(TOTAL_SHARDS, 512)] * 7
     seen = []
     real = rs_kernel.apply_matrix_async
 
-    def recording(matrix, shards, device=None):
-        seen.append(shards)
-        return real(matrix, shards, device=device)
+    def recording(matrix, shards, device=None, out=None):
+        seen.append((shards, out))
+        return real(matrix, shards, device=device, out=out)
 
     monkeypatch.setattr(rs_kernel, "apply_matrix_async", recording)
     fresh, reused = _handed("fresh"), _handed("reused")
@@ -1138,9 +1278,14 @@ def test_verify_dispatches_views_of_reused_staging(tmp_path, monkeypatch):
     assert [id(b) for b in fleet._IDLE_STAGING._bufs] == \
         [id(b) for b in idle]
     assert len(seen) == 10
-    for arr in seen:
+    for arr, out in seen:
         assert arr.shape == (DATA_SHARDS, per_batch * span)
         assert sum(np.shares_memory(arr, b) for b in idle) == 1
+        # the parity lands in the same buffer, in the rows after the input
+        owner, = [b for b in idle if np.shares_memory(arr, b)]
+        assert out.shape == (4, per_batch * span)
+        assert np.shares_memory(out, owner[DATA_SHARDS:])
+        assert not np.shares_memory(out, arr)
 
 
 @pytest.mark.parametrize("n", [1, 2, 128])

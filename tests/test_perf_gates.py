@@ -288,14 +288,16 @@ def test_phase_timers_allocate_no_span_when_tracing_is_off(path, tmp_path):
     scheduler's pack and wait timers (ec/fleet.py) on the jax backend:
     with the tracer off they observe their histograms — and allocate no
     Span: the process's span-id counter does not advance, the ring
-    stays empty. A count, not a time (ROADMAP C8)."""
+    stays empty. So does the counter of where results landed: the
+    scheduler lends every dispatch its buffer's last rows, a bare
+    apply_matrix makes an array. A count, not a time (ROADMAP C8)."""
     from seaweedfs_tpu.ec import fleet
     from seaweedfs_tpu.ops import rs_kernel
     from seaweedfs_tpu.ops.rs_code import DATA_SHARDS, coding_matrix
     from seaweedfs_tpu.stats import trace
     from seaweedfs_tpu.stats.metrics import (
         FleetStageSecondsHistogram, FleetWaitSecondsHistogram,
-        RsDispatchSecondsHistogram)
+        RsDispatchSecondsHistogram, RsResultBuffersCounter)
 
     assert not trace.is_enabled()
     trace.clear()
@@ -305,12 +307,15 @@ def test_phase_timers_allocate_no_span_when_tracing_is_off(path, tmp_path):
     reader = FleetWaitSecondsHistogram.labels("reader")
     staging = FleetWaitSecondsHistogram.labels("staging")
     counted = (place.count, pack.count, reader.count, staging.count)
+    lent, fresh = (RsResultBuffersCounter.labels(s) for s in ("lent", "fresh"))
+    landed = (lent.value, fresh.value)
     first_id = trace.next_span_id()
     if path == "apply_matrix":
         data = rng.integers(0, 256, (2, DATA_SHARDS, 4096), dtype=np.uint8)
         rs_kernel.apply_matrix_async(
             np.asarray(coding_matrix())[DATA_SHARDS:], data).result()
         assert place.count == counted[0] + 1
+        assert (lent.value, fresh.value) == (landed[0], landed[1] + 1)
     else:
         bases = []
         for v in range(2):
@@ -325,6 +330,7 @@ def test_phase_timers_allocate_no_span_when_tracing_is_off(path, tmp_path):
         assert pack.count > counted[1]
         assert reader.count == counted[2] + 8      # 4 one-row spans each
         assert staging.count == counted[3] + 4     # 2 spans a buffer
+        assert (lent.value, fresh.value) == (landed[0] + 4, landed[1])
     assert trace.next_span_id() == first_id + 1, \
         "a disabled tracer still allocated spans"
     assert trace.spans() == []

@@ -144,3 +144,122 @@ def test_named_backend_is_never_substituted(monkeypatch):
     assert not rs_native.available()
     want = gf256.gf_linear_numpy(ReedSolomon().matrix[10:], data)
     assert np.array_equal(ReedSolomon(backend="auto").encode(data), want)
+
+
+# --- a lent result buffer (ISSUE 30) -------------------------------------------
+# apply_matrix_async(..., out=) copies each fetched slab into memory the
+# caller keeps, where it would fill a fresh array a dispatch.
+
+def _result_counts():
+    from seaweedfs_tpu.stats.metrics import RsResultBuffersCounter
+    return {s: RsResultBuffersCounter.labels(s).value
+            for s in ("lent", "fresh")}
+
+
+@pytest.mark.parametrize("rows", [4, 2])
+@pytest.mark.parametrize("n", [
+    1,                  # one slab, nearly all padding
+    70_000,             # one slab with a padded tail
+    2 * 65_536 + 777,   # several slabs, the last one padded
+    3 * 65_536,         # several slabs, none padded
+])
+def test_result_lands_in_the_lent_buffer(monkeypatch, rows, n):
+    """result() with out= returns `out` itself, byte-equal to the call
+    without it, and touches nothing of the lender's memory beside it."""
+    from seaweedfs_tpu.ops import rs_kernel
+
+    monkeypatch.setattr(rs_kernel, "_MAX_SLAB", rs_kernel._MIN_SLAB)
+    rng = np.random.default_rng(70 + rows)
+    matrix = rand_shards(rng, (rows, 10))
+    # as ec/fleet.py lends it: the last rows and first lanes of a stripe
+    stripe = np.full((14, n + 5), 0xA5, dtype=np.uint8)
+    stripe[:10, :n] = rand_shards(rng, (10, n))
+    data, out = stripe[:10, :n], stripe[10:10 + rows, :n]
+    want = rs_kernel.apply_matrix_async(matrix, data.copy()).result()
+    assert np.array_equal(want, gf256.gf_linear_numpy(matrix, data))
+    before = _result_counts()
+    got = rs_kernel.apply_matrix_async(matrix, data, out=out).result()
+    assert got is out
+    assert np.array_equal(got, want)
+    assert (stripe[10 + rows:] == 0xA5).all() and (stripe[:, n:] == 0xA5).all()
+    after = _result_counts()
+    assert (after["lent"] - before["lent"], after["fresh"] - before["fresh"]) \
+        == (1, 0)
+
+
+def test_result_without_out_counts_fresh():
+    from seaweedfs_tpu.ops import rs_kernel
+
+    rng = np.random.default_rng(72)
+    matrix, data = rand_shards(rng, (4, 10)), rand_shards(rng, (3, 10, 500))
+    before = _result_counts()
+    got = rs_kernel.apply_matrix_async(matrix, data).result()
+    assert np.array_equal(got, gf256.gf_linear_numpy(matrix, data))
+    # nothing to fetch, nothing counted; and an empty `out` is handed back
+    empty = np.empty((4, 0), dtype=np.uint8)
+    assert rs_kernel.apply_matrix_async(
+        matrix, data[0][:, :0], out=empty).result() is empty
+    after = _result_counts()
+    assert (after["lent"] - before["lent"], after["fresh"] - before["fresh"]) \
+        == (0, 1)
+
+
+@pytest.mark.parametrize("case, shape, out, message", [
+    ("rows", (10, 300), np.empty((3, 300), np.uint8), "has shape"),
+    ("lanes", (10, 300), np.empty((4, 301), np.uint8), "has shape"),
+    ("flat", (10, 300), np.empty(1200, np.uint8), "has shape"),
+    ("dtype", (10, 300), np.empty((4, 300), np.int8), "uint8"),
+    ("not_an_array", (10, 300), bytearray(1200), "uint8"),
+    ("strided_rows", (10, 300), np.empty((4, 600), np.uint8)[:, ::2],
+     "contiguous"),
+    ("stacked_input", (2, 10, 150), np.empty((4, 300), np.uint8), "2-D"),
+    ("stacked_both", (2, 10, 150), np.empty((2, 4, 150), np.uint8), "2-D"),
+])
+def test_out_that_cannot_take_the_result_raises_at_the_call(monkeypatch, case,
+                                                            shape, out,
+                                                            message):
+    """At the call, on the caller's thread and before any placement —
+    not from result(), which a scheduler runs on its retire thread."""
+    from seaweedfs_tpu.ops import rs_kernel
+
+    def no_dispatch(*a, **kw):
+        raise AssertionError("dispatched before the check")
+
+    monkeypatch.setattr(rs_kernel, "_submit_slabs", no_dispatch)
+    rng = np.random.default_rng(73)
+    matrix, data = rand_shards(rng, (4, 10)), rand_shards(rng, shape)
+    with pytest.raises(ValueError, match=message):
+        rs_kernel.apply_matrix_async(matrix, data, out=out)
+
+
+def test_read_only_out_raises_at_the_call():
+    from seaweedfs_tpu.ops import rs_kernel
+
+    rng = np.random.default_rng(74)
+    out = np.empty((4, 300), np.uint8)
+    out.flags.writeable = False
+    with pytest.raises(ValueError, match="writable"):
+        rs_kernel.apply_matrix_async(rand_shards(rng, (4, 10)),
+                                     rand_shards(rng, (10, 300)), out=out)
+
+
+@pytest.mark.parametrize("op", ["encode", "reconstruct"])
+def test_codec_passes_out_through_on_jax_and_host_backends_keep_their_own(
+        rs, op):
+    """encode_async / reconstruct_some_async: the jax backend's result IS
+    the lent array; a host codec allocates inside and ignores it."""
+    rng = np.random.default_rng(75)
+    data = rand_shards(rng, (10, 1000))
+    full = np.concatenate([data, ReedSolomon(backend="numpy").encode(data)])
+    if op == "encode":
+        out = np.zeros((4, 1000), np.uint8)
+        got = rs.encode_async(data, out=out).result()
+        want = full[10:]
+    else:
+        present = [1, 2, 4, 5, 6, 7, 8, 9, 10, 11, 12, 13]
+        out = np.zeros((2, 1000), np.uint8)
+        got = rs.reconstruct_some_async(present, [0, 3], full[present],
+                                        out=out).result()
+        want = full[[0, 3]]
+    assert np.array_equal(got, want)
+    assert (got is out) == (rs.backend == "jax")

@@ -375,14 +375,17 @@ def _moved(family, values, before):
 
 
 @pytest.mark.parametrize("placement", ["mesh", "device", "default"])
-@pytest.mark.parametrize("batched", [True, False])
-def test_rs_dispatch_phases_count_and_nest(placement, batched, monkeypatch):
+@pytest.mark.parametrize("batched, lent", [
+    (True, False), (False, False), (False, True)])
+def test_rs_dispatch_phases_count_and_nest(placement, batched, lent,
+                                           monkeypatch):
     """One apply_matrix_async(...).result() observes each phase of
     SeaweedFS_rs_dispatch_seconds the expected number of times — place,
     enqueue, wait and fetch once a slab; stage and unstage once a slab
-    (slice/pad, copy-out) plus once for the flatten / the moveaxis back
-    of a batched input — and its rs.* spans nest under the span the
-    caller has open. Counts only: no wall-clock assertion."""
+    (slice/pad, copy-out: into a fresh array or the one the caller
+    lent) plus once for the flatten / the moveaxis back of a batched
+    input — and its rs.* spans nest under the span the caller has open.
+    Counts only: no wall-clock assertion."""
     import jax
     import numpy as np
 
@@ -404,11 +407,14 @@ def test_rs_dispatch_phases_count_and_nest(placement, batched, monkeypatch):
     n_slabs = -(-210_000 // slab)
     matrix = np.asarray(coding_matrix())[DATA_SHARDS:]
 
+    out = np.empty((PARITY_SHARDS, 210_000), dtype=np.uint8) if lent \
+        else None
     before = _hist_counts(RsDispatchSecondsHistogram, _RS_PHASES)
     trace.enable()
     with trace.span("caller") as caller:
-        got = rs_kernel.apply_matrix_async(matrix, data,
-                                           device=device).result()
+        got = rs_kernel.apply_matrix_async(matrix, data, device=device,
+                                           out=out).result()
+    assert (got is out) == lent
     moved = _moved(RsDispatchSecondsHistogram, _RS_PHASES, before)
     extra = 1 if batched else 0
     assert moved == {"stage": n_slabs + extra, "place": n_slabs,
