@@ -648,31 +648,28 @@ class Smoke:
     def scrub(self, name: str = "scrub") -> None:
         with self.phase(name) as p:
             m0 = self.metrics()
-            out = self.shell.run_command("volume.scrub -full")
+            # returns when the pass has ended; a pass that failed fails
+            # the command (the server's log says why)
+            out = self.shell.run_command("volume.scrub -full -wait")
             check("scrub started" in out, f"volume.scrub: {out!r}")
-            while True:
-                st = self.shell.run_command("volume.scrub -status")
-                m = re.search(
-                    r": (\w+) passes:(\d+) scanned:(\d+)B needles:(\d+) "
-                    r"stripes:(\d+) found:(\d+) repaired:(\d+) "
-                    r"unrecoverable:(\d+)", st)
-                check(m is not None, f"volume.scrub -status: {st!r}")
-                if m.group(1) == "idle":
-                    # a pass that raises is logged by the daemon, which
-                    # then goes idle with no pass counted
-                    check(int(m.group(2)) >= 1,
-                          f"the scrub pass failed (server log above): {st}")
-                    break
-                time.sleep(0.2)
+            m = re.search(
+                r": (\w+) passes:(\d+) scanned:(\d+)B needles:(\d+) "
+                r"stripes:(\d+) found:(\d+) repaired:(\d+) "
+                r"unrecoverable:(\d+)", out)
+            check(m is not None and m.group(1) == "idle"
+                  and int(m.group(2)) == 1, f"volume.scrub -wait: {out!r}")
+            for vid in self.vids:
+                check(f": volume {vid}: clean" in out,
+                      f"volume {vid} is not clean: {out!r}")
             m1 = self.metrics()
             _, _, scanned, needles, stripes, found, repaired, lost = \
                 (int(x) if x.isdigit() else x for x in m.groups())
             p.update(bytes=scanned, needles=needles, stripes=stripes,
                      found=found, repaired=repaired, unrecoverable=lost)
             check(found == 0 and repaired == 0 and lost == 0,
-                  f"scrub found damage: {st}")
+                  f"scrub found damage: {out}")
             check(stripes > 0 and needles > 0,
-                  f"scrub verified nothing: {st}")
+                  f"scrub verified nothing: {out}")
             self.device_moved(every_device=self.args.chips > 1)
             if self.args.chips > 1:
                 key = 'SeaweedFS_fleet_mesh_buckets_total{op="verify"}'

@@ -18,7 +18,9 @@ flush for ONE loop, `_staged_pass`:
   feed      a bounded reader pool prefetches spans ahead of the device,
             each reader filling its span's lanes straight from the .dat
             (encode), the ten surviving shard files (rebuild) or the
-            ten data shard files (verify). Spans are consumed in
+            ten data shard files (verify; on the jax backend the
+            stored parity files too, into rows 10-13: the device
+            compares). Spans are consumed in
             submission order (round-robin rounds over the volumes), so
             per-volume order holds while reads overlap compute.
   dispatch  the jax backend is async already; sync host backends
@@ -29,8 +31,9 @@ flush for ONE loop, `_staged_pass`:
             dispatches strictly in submission order and hands every
             volume's output to that volume's writer LANE (per-volume
             FIFO, parallel across volumes): encoded parity and rebuilt
-            shards are appended to the .ecNN files, a verify's parity
-            is compared with the stored .ec10-13.
+            shards are appended to the .ecNN files; a host codec's
+            verify parity is compared with the stored .ec10-13, a jax
+            verify's counts (all that came back) are added up.
 
 Volumes that need large-row striping (> 10 * large_block bytes) fall
 back to the per-volume `write_ec_files` path; everything else is
@@ -66,8 +69,8 @@ from seaweedfs_tpu.stats.metrics import (
     FleetMeshFallbacksCounter, FleetReaderQueueGauge,
     FleetRebuildGroupsCounter, FleetRebuildVolumesCounter,
     FleetRebuiltBytesCounter, FleetStageSecondsHistogram,
-    FleetStagingBuffersCounter, FleetWaitSecondsHistogram,
-    FleetWriterBacklogGauge)
+    FleetStagingBuffersCounter, FleetVerifyBytesCounter,
+    FleetWaitSecondsHistogram, FleetWriterBacklogGauge)
 
 
 def mesh_fleet_or_none():
@@ -119,6 +122,8 @@ _WAIT_HIST = {on: FleetWaitSecondsHistogram.labels(on)
                          "lane_from_retire", "staging")}
 _STAGING_HANDED = {state: FleetStagingBuffersCounter.labels(state)
                    for state in ("fresh", "reused")}
+_VERIFIED_BYTES = {where: FleetVerifyBytesCounter.labels(where)
+                   for where in ("device", "host")}
 
 
 class _StageTimer(trace.PhaseTimer):
@@ -385,9 +390,44 @@ class _Dispatcher:
             functools.partial(self._rs.reconstruct_some, present, missing),
             buf, cuts, done)
 
+    def verify_lanes(self, buf: np.ndarray, cuts: List[Tuple[int, int]],
+                     done: Callable[[], None]):
+        """The jax backend's verify: the filled lanes of ALL 14 rows go
+        to the device as they lie — the data shards and the stored
+        parity — and .result() yields a span's (counts, firsts), each
+        [4, blocks of the span]: nothing is lent, what comes back is
+        KB. Every cut starts and ends on a block boundary."""
+        from seaweedfs_tpu.ops import rs_kernel
+        if _failpoint._armed:
+            _failpoint.hit("fleet.dispatch", op="verify")
+        with _StageTimer("pack", spans=len(cuts)):
+            stripe = buf[:, :cuts[-1][0] + cuts[-1][1]]
+        handle = rs_kernel.verify_stripe_async(
+            self._rs.matrix[DATA_SHARDS:], stripe, device=self._device)
+        return _CountsHandle(
+            handle, [n // rs_kernel.VERIFY_BLOCK for _, n in cuts], done)
+
     def close(self) -> None:
         if self._pool is not None:
             self._pool.shutdown(wait=True)
+
+
+class _CountsHandle:
+    """Adapt one fused compare-and-count over a staging buffer's lanes
+    back to per-span outputs: (counts, firsts), each the span's `sizes`
+    blocks. `done` runs once the counts are on the host: the device has
+    read the buffer."""
+
+    def __init__(self, handle, sizes: List[int], done: Callable[[], None]):
+        self._handle = handle
+        self._cuts = np.cumsum(sizes)[:-1]
+        self._done = done
+
+    def result(self) -> List[Tuple[np.ndarray, np.ndarray]]:
+        counts, firsts = self._handle.result()
+        self._done()
+        return list(zip(np.split(counts, self._cuts, axis=1),
+                        np.split(firsts, self._cuts, axis=1)))
 
 
 class _SplitHandle:
@@ -510,12 +550,14 @@ class _Staging:
     (encode), the surviving shard files (rebuild) or the data shard
     files (verify), the dispatch layer places slices of them, an encode
     pass's writer lanes write the data shards out of them. Rows 10..
-    are where the retire thread puts its result — the first four of
-    them the parity (encode, verify), the first len(missing) the
-    rebuilt shards — and where the writer lanes read it: the parity and
-    rebuilt-shard writes, a verify's compares. Then the buffer comes
-    round again. A rebuild of two shards never touches rows 12-13: they
-    stay address space. The share is what the pipeline has
+    are where an encode's or a rebuild's retire thread puts the result
+    — the four parity rows, or the first len(missing) rows the rebuilt
+    shards — and where the writer lanes read it: the parity and
+    rebuilt-shard writes. A jax verify has its readers fill rows 10-13
+    with the STORED parity and places all 14 rows; nothing comes back
+    into the buffer. Then the buffer comes round again. A rebuild of
+    two shards never touches rows 12-13: they stay address space. The
+    share is what the pipeline has
     in flight anyway (see _staged_pass), so the one place a pass can
     block here — `acquire`, timed as fleet.wait.staging — blocks only
     while buffers are downstream of the packing thread, where they come
@@ -933,16 +975,28 @@ def _read_present_span_into(base: str, present: List[int], shard_size: int,
                             off: int) -> None:
     """Bytes [offset, offset + span) of the first 10 present shards
     straight into lanes [off, off + span) of a staging buffer, row r
-    from shard present[r]: one read a shard file, nothing in between.
-    What lies past the shard's end, or past a survivor that is shorter
-    than it should be, is zeroed on EVERY use: the buffer still holds an
-    earlier dispatch's bytes, possibly another volume's. (The map works
-    column by column and the columns past the end are trimmed on the
-    way to the file, so this keeps a dispatch's input a function of the
-    files alone, and a short survivor reading as zeros.)"""
+    from shard present[r] (see _read_rows_into)."""
+    _read_rows_into(base, list(enumerate(present[:DATA_SHARDS])), shard_size,
+                    offset, span, span, buf, off)
+
+
+def _read_rows_into(base: str, rows: Sequence[Tuple[int, int]],
+                    shard_size: int, offset: int, span: int, width: int,
+                    buf: np.ndarray, off: int) -> None:
+    """Bytes [offset, offset + span) of shard `sid` straight into lanes
+    [off, off + span) of row `row` of a staging buffer, for each (row,
+    sid) of `rows`: one read a shard file, nothing in between. The span
+    owns `width` >= span lanes. What lies past the shard's end, past a
+    file that is shorter than it should be, or past the span, is zeroed
+    on EVERY use: the buffer still holds an earlier dispatch's bytes,
+    possibly another volume's. (The map works column by column and the
+    columns past the end are trimmed on the way out, or are zeros in
+    every row and verify as such, so this keeps a dispatch's input a
+    function of the files alone, and a short survivor reading as
+    zeros.)"""
     want = min(span, max(shard_size - offset, 0))
-    for row, sid in enumerate(present[:DATA_SHARDS]):
-        lanes = buf[row, off:off + span]
+    for row, sid in rows:
+        lanes = buf[row, off:off + width]
         got = 0
         if want > 0:
             fd = os.open(shard_file_name(base, sid), os.O_RDONLY)
@@ -1035,19 +1089,26 @@ def fleet_verify_ec_files(base_names: Sequence[str], backend: str = "auto",
     """Verify EC stripe consistency for MANY volumes in one fused pass.
 
     The scrub scanner's compute path, on the encode and rebuild passes'
-    loop: the data shards are re-encoded in shared [10, B * span]
-    dispatches over the reused staging buffers, and the parity is
-    compared byte-for-byte against the stored .ec10-13. Nothing on disk
-    is touched; mismatches are reported per parity shard for the repair
-    planner to classify (a corrupt DATA shard surfaces as all four
-    parity shards disagreeing at the same offsets — scrub/planner.py).
-    `throttler` (util.throttler.Throttler) paces the read side so a
-    background scrub stays inside its IO budget.
+    loop. The backend says where the re-encode-and-compare runs. jax:
+    the readers fill ALL 14 rows of the reused staging buffers — the
+    data shards and, beside them, the stored .ec10-13 — a dispatch
+    places the stripe as it lies and the device sends back counts
+    (rs_kernel.verify_stripe_async): no parity crosses to the host.
+    Host codecs: the data rows are re-encoded in shared [10, B * span]
+    dispatches and each span's parity is compared with the parity files
+    on its volume's writer lane. Nothing on disk is touched; mismatches
+    are reported per parity shard for the repair planner to classify (a
+    corrupt DATA shard surfaces as all four parity shards disagreeing at
+    the same offsets — scrub/planner.py). `throttler`
+    (util.throttler.Throttler) paces the read side so a background
+    scrub stays inside its IO budget.
     """
     if chunk is None:
         chunk = default_chunk_for(backend)
+    on_device = backend == "jax"
     results: Dict[str, VerifyResult] = {}
     sized: List[Tuple[str, int]] = []  # (base, shard size) to verify
+    short: List[str] = []
     for base in base_names:
         r = results[base] = VerifyResult()
         present = [i for i in range(TOTAL_SHARDS)
@@ -1060,24 +1121,79 @@ def fleet_verify_ec_files(base_names: Sequence[str], backend: str = "auto",
             r.verified = False
             continue
         r.parity_checked = parity
-        sized.append((base, os.path.getsize(shard_file_name(base, 0))))
+        size = os.path.getsize(shard_file_name(base, 0))
+        if on_device and any(
+                os.path.getsize(shard_file_name(base, sid)) < size
+                for sid in parity):
+            short.append(base)
+        else:
+            sized.append((base, size))
+    if short:
+        # A parity file that ends early lacks bytes the data shards say
+        # should exist, and each of them is a mismatch whatever the
+        # re-encode gives there. The device sees zeros where the file
+        # has nothing and would count only those that re-encode to
+        # non-zero: such a volume (damaged already, the rebuild's next)
+        # is held to its files by a host codec, which is exact.
+        results.update(fleet_verify_ec_files(
+            short, readers=readers, depth=depth, encoders=encoders,
+            throttler=throttler))
     if not any(size for _, size in sized):
         return results  # nothing to read: no pool, no buffer
     span, per_batch = _stacked_spans(chunk, [size for _, size in sized])
+    # What a span takes of a buffer: from the device a count comes back
+    # a block of lanes, so every span starts on a block boundary there
+    # (the reader zeroes the lanes past the span: zeros verify).
+    block = 1
+    if on_device:
+        from seaweedfs_tpu.ops.rs_kernel import VERIFY_BLOCK as block
+    width = -(-span // block) * block
     vols = [_VolState(base, size, -(-size // span), tag)
             for tag, (base, size) in enumerate(sized)]
+    where = _VERIFIED_BYTES["device" if on_device else "host"]
 
     def plan():
         for v, row0, _rows in _round_robin_spans(vols, 1):
             offset = row0 * span
+            parity = results[v.base].parity_checked
             if throttler is not None:
                 # paced on the packing thread, which pulls the plan: a
-                # span costs 10 data reads plus the compare's parity reads
-                throttler.maybe_slowdown(span * (
-                    DATA_SHARDS + len(results[v.base].parity_checked)))
-            yield v, span, min(span, v.dat_size - offset), functools.partial(
-                _read_present_span_into, v.base, range(DATA_SHARDS),
-                v.dat_size, offset, span)
+                # span costs 10 data reads plus its parity reads
+                throttler.maybe_slowdown(span * (DATA_SHARDS + len(parity)))
+            # the device is handed the stored parity beside the data
+            shards = list(range(DATA_SHARDS)) + (parity if on_device else [])
+            yield v, width, min(span, v.dat_size - offset), functools.partial(
+                _read_rows_into, v.base, [(sid, sid) for sid in shards],
+                v.dat_size, offset, span, width)
+
+    def tally(v: _VolState, sid: int, offset: int, bad: int,
+              first: int) -> None:
+        if bad:
+            r = results[v.base]
+            r.parity_mismatch[sid] = r.parity_mismatch.get(sid, 0) + bad
+            # spans retire in offset order on this volume's lane: the
+            # first recorded hit is the lowest
+            r.first_mismatch.setdefault(sid, offset + first)
+
+    def verified(v: _VolState, valid: int) -> None:
+        r = results[v.base]
+        r.bytes_verified += DATA_SHARDS * valid
+        r.spans += 1
+        where.inc(float(DATA_SHARDS * valid))
+
+    def counted(v: _VolState, offset: int, out) -> None:
+        """On v's writer lane: the device's counts of one span, each
+        [4, blocks]."""
+        with _StageTimer("verify", vol=os.path.basename(v.base)):
+            counts, firsts = out
+            for sid in results[v.base].parity_checked:
+                row = counts[sid - DATA_SHARDS]
+                hit = np.flatnonzero(row)
+                if len(hit):
+                    tally(v, sid, offset, int(row.sum()),
+                          int(hit[0]) * block
+                          + int(firsts[sid - DATA_SHARDS, hit[0]]))
+            verified(v, min(span, v.dat_size - offset))
 
     # open for the whole pass: a volume's compares run FIFO on ITS
     # writer lane (one reader a file), and per-span open/close would cost
@@ -1085,11 +1201,11 @@ def fleet_verify_ec_files(base_names: Sequence[str], backend: str = "auto",
     parity_files: Dict[Tuple[str, int], object] = {}
 
     def compare(v: _VolState, offset: int, out: np.ndarray) -> None:
-        """On v's writer lane: parity [4, span] against the stored one."""
+        """On v's writer lane: a host codec's parity [4, span] against
+        the stored one."""
         with _StageTimer("verify", vol=os.path.basename(v.base)):
             valid = min(span, v.dat_size - offset)
-            r = results[v.base]
-            for sid in r.parity_checked:
+            for sid in results[v.base].parity_checked:
                 f = parity_files[v.base, sid]
                 f.seek(offset)
                 stored = np.frombuffer(f.read(valid), dtype=np.uint8)
@@ -1097,42 +1213,37 @@ def fleet_verify_ec_files(base_names: Sequence[str], backend: str = "auto",
                     out[sid - DATA_SHARDS, :len(stored)] != stored)[0]
                 # a truncated parity shard lacks bytes the data shards say
                 # should exist: each is a mismatch, not a free pass
-                bad = len(diff) + valid - len(stored)
-                if bad:
-                    r.parity_mismatch[sid] = \
-                        r.parity_mismatch.get(sid, 0) + bad
-                    # spans retire in offset order on this volume's lane:
-                    # the first recorded hit is the lowest
-                    r.first_mismatch.setdefault(sid, offset + (
-                        int(diff[0]) if len(diff) else len(stored)))
-            r.bytes_verified += DATA_SHARDS * valid
-            r.spans += 1
+                tally(v, sid, offset, len(diff) + valid - len(stored),
+                      int(diff[0]) if len(diff) else len(stored))
+            verified(v, valid)
 
     # batches are flushed in the plan's order: a volume's spans in turn
     offsets = [itertools.count(0, span) for _ in vols]
 
     def flush(batch: _StagedBatch, dispatcher: _Dispatcher,
               pipe: TaggedPipeline, release: Callable[[], None]) -> None:
+        cuts = [(off, width) for _, off, _ in batch.spans]
         with _StageTimer("dispatch", batch=len(batch.spans)):
-            handle = dispatcher.encode_lanes(
-                batch.buf, [(off, span) for _, off, _ in batch.spans],
-                release)
+            handle = dispatcher.verify_lanes(batch.buf, cuts, release) \
+                if on_device else \
+                dispatcher.encode_lanes(batch.buf, cuts, release)
         pipe.submit(handle, [
-            (v.tag, _then_release(
-                functools.partial(compare, v, next(offsets[v.tag])),
-                release))
+            (v.tag, functools.partial(counted if on_device else compare,
+                                      v, next(offsets[v.tag])))
             for v, _, _ in batch.spans])
 
     with contextlib.ExitStack() as opened:
-        for v in vols:  # one by one: a failed open() closes the earlier
-            for sid in results[v.base].parity_checked:
-                parity_files[v.base, sid] = opened.enter_context(
-                    open(shard_file_name(v.base, sid), "rb"))
-        # like a rebuild's: the result AND each span's compare
+        if not on_device:
+            for v in vols:  # one by one: a failed open() closes the earlier
+                for sid in results[v.base].parity_checked:
+                    parity_files[v.base, sid] = opened.enter_context(
+                        open(shard_file_name(v.base, sid), "rb"))
+        # a verify's buffer is free once its dispatch's input has been
+        # read (the retire thread has the counts, or every host codec's
+        # parity): what the lanes then read is not in it
         _staged_pass(trace.span("fleet.verify", volumes=len(vols),
                                 backend=backend),
                      backend, device, encoders, readers, depth,
-                     lanes=per_batch * span, per_buffer=per_batch,
-                     plan=plan(), flush=flush,
-                     refs=lambda batch: 1 + len(batch.spans))
+                     lanes=per_batch * width, per_buffer=per_batch,
+                     plan=plan(), flush=flush, refs=lambda batch: 1)
     return results

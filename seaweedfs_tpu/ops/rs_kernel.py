@@ -141,6 +141,30 @@ def _gf_linear_jit(m2, shards):
     return gf_linear(m2, shards)
 
 
+# Lanes a block of the compare-and-count program covers: what it hands
+# back is one count and one first lane a parity row and block, so a
+# caller that lays its spans out on block boundaries can tell them apart.
+# A power of two under the narrowest slab (_MIN_SLAB), so slabs hold
+# whole blocks: 4 Mi lanes come back as 26 KB.
+VERIFY_BLOCK = 1 << 12
+
+
+@jax.jit
+def _verify_jit(m2, stripe):
+    """stripe [D + P, slab] uint8, m2 the parity rows' bit-matrix:
+    re-encode rows 0..D-1, hold the result against rows D.. and return
+    int32 [2, P, slab / VERIFY_BLOCK]: per parity row and block the
+    count of differing bytes, and the first differing lane of the block
+    (VERIFY_BLOCK where none differs)."""
+    p = m2.shape[0] // 8
+    differ = (gf_linear(m2, stripe[:-p]) != stripe[-p:]).reshape(
+        p, -1, VERIFY_BLOCK)
+    lane = jax.lax.broadcasted_iota(jnp.int32, differ.shape, 2)
+    return jnp.stack([
+        jnp.sum(differ, axis=-1, dtype=jnp.int32),
+        jnp.min(jnp.where(differ, lane, VERIFY_BLOCK), axis=-1)])
+
+
 @functools.lru_cache(maxsize=64)
 def _m2_device(matrix_bytes: bytes, rows: int, cols: int) -> jnp.ndarray:
     m = np.frombuffer(matrix_bytes, dtype=np.uint8).reshape(rows, cols)
@@ -266,6 +290,59 @@ def apply_matrix_async(matrix: np.ndarray, shards, device=None,
     return PendingApply(parts, o, flat.shape[1], batch_shape, n, out)
 
 
+class PendingVerify:
+    """An in-flight compare-and-count over a stripe: .result() is
+    (counts, firsts), each int32 [P, n / VERIFY_BLOCK] — per parity row
+    and block of lanes the differing bytes and the first differing lane
+    of the block. All that crosses back to the host."""
+
+    def __init__(self, parts, p: int):
+        self._parts = parts          # [(device_array, want, pos)]
+        self._p = p
+
+    def result(self):
+        got = []
+        for res, want, _pos in self._parts:
+            with _phase("wait"):
+                res.block_until_ready()
+            with _phase("fetch", bytes=res.nbytes):
+                host = np.asarray(res)
+            got.append(host[:, :, :want // VERIFY_BLOCK])
+        with _phase("unstage"):
+            both = np.concatenate(got, axis=2) if got else \
+                np.zeros((2, self._p, 0), dtype=np.int32)
+        return both[0], both[1]
+
+
+def verify_stripe_async(matrix: np.ndarray, stripe: np.ndarray,
+                        device=None) -> PendingVerify:
+    """Re-encode and compare on the device, without waiting for it.
+
+    `matrix` [P, D] are the code's parity rows, `stripe` [D + P, n] a
+    2-D uint8 array (a view will do; nothing is copied before the slab
+    slices): rows 0..D-1 the data shards, rows D.. the STORED parity.
+    The slab loop, the widths, a tail slab's zero pad (zero data
+    re-encodes to zero parity: padding never differs) and the placement
+    are `apply_matrix_async`'s; what comes back a slab is its counts,
+    KB where a map's result is rows. `n` is a multiple of VERIFY_BLOCK:
+    the caller lays out its spans on block boundaries."""
+    matrix = np.ascontiguousarray(matrix, dtype=np.uint8)
+    p, d = matrix.shape
+    if not isinstance(stripe, np.ndarray) or stripe.dtype != np.uint8 \
+            or stripe.ndim != 2 or stripe.shape[0] != d + p:
+        raise ValueError(f"a stripe is a uint8 array [{d + p}, n], not "
+                         f"{getattr(stripe, 'dtype', type(stripe).__name__)}"
+                         f" {getattr(stripe, 'shape', '')}")
+    if stripe.shape[1] % VERIFY_BLOCK:
+        raise ValueError(f"{stripe.shape[1]} lanes are no whole number of "
+                         f"blocks of {VERIFY_BLOCK}")
+    m2 = _m2_device(matrix.tobytes(), p, d)
+    if device is not None:
+        m2 = jax.device_put(m2, device)
+    return PendingVerify(
+        _submit_slabs(m2, stripe, device=device, program=_verify_jit), p)
+
+
 def _check_lent(out, o: int, n: int, batch_shape) -> None:
     """Can `out` take the [o, n] result of a 2-D dispatch?"""
     if batch_shape:
@@ -315,8 +392,10 @@ def _lane_sharding():
     return NamedSharding(mesh, PartitionSpec(None, "lanes"))
 
 
-def _submit_slabs(m2: jnp.ndarray, flat: np.ndarray, device=None):
-    """Issue one async dispatch per power-of-two slab; no fetches."""
+def _submit_slabs(m2: jnp.ndarray, flat: np.ndarray, device=None,
+                  program=_gf_linear_jit):
+    """Issue one async dispatch of `program(m2, slab)` per power-of-two
+    slab; no fetches."""
     s, n = flat.shape
     sharding = None if device is not None else _lane_sharding()
     parts = []
@@ -351,7 +430,7 @@ def _submit_slabs(m2: jnp.ndarray, flat: np.ndarray, device=None):
                 x = jnp.asarray(chunk)
         note_placement(x)
         with _phase("enqueue"):
-            res = _gf_linear_jit(m2, x)
+            res = program(m2, x)
         parts.append((res, want, pos))
         pos += want
     return parts

@@ -6,9 +6,12 @@ mode warm stores guard against with continuous scrubbing (f4-style).
 This package closes that gap with three parts:
 
   scanner   walks mounted volumes and EC volumes at a throttled pace,
-            recomputing needle CRCs and re-encoding EC data shards
-            through the fleet dispatcher (ec/fleet.py) in fused
-            [B, 10, chunk] batches, comparing against stored parity.
+            recomputing needle CRCs and re-encoding EC data shards on
+            the fleet scheduler's staged loop (ec/fleet.py): the
+            readers fill reused [14, lanes] staging buffers — the data
+            shards and the stored parity — and the jax backend compares
+            on the device (counts come back, not parity); host codecs
+            compare on the writer lanes.
   planner   classifies damage (bad parity shard vs bad data shard vs
             unrecoverable), quarantines corrupt files with a .corrupt
             rename, and reconstructs shards via the fleet rebuild path
@@ -16,10 +19,14 @@ This package closes that gap with three parts:
   daemon    the control plane: a background thread per volume server
             with start/pause/status, wired to VolumeScrubStart/Pause/
             Status RPCs, the HTTP /status page, the master's staggered
-            scheduler, and the `volume.scrub` shell command.
+            scheduler, and the `volume.scrub` shell command, whose
+            `-wait` returns when the pass has ended, with the pass's
+            verdict on every volume it covered.
 
-Everything is instrumented with the PR 2 primitives: scrub.pass/scan/
-verify/repair spans and the SeaweedFS_scrub_* metric families.
+Everything is instrumented with the PR 2 primitives: the span
+scrub.pass, the phases scan / scan_ec / verify / repair / reverify
+(SeaweedFS_scrub_phase_seconds always, spans scrub.<phase> while the
+ring is on) and the SeaweedFS_scrub_* metric families.
 """
 
 from seaweedfs_tpu.scrub.daemon import ScrubDaemon, PassResult

@@ -10,11 +10,20 @@ mounted volume and EC volume:
   2. needle sweep per EC volume over local shards, localizing bad
      data shards by exclusion;
   3. ONE fused stripe verify across ALL the server's EC volumes
-     (fleet_verify_ec_files) — verification rides the same batched
-     TPU/mesh dispatch path as encode;
+     (fleet_verify_ec_files), on the encode and rebuild passes' loop
+     and staging buffers. On the jax backend the stored parity goes to
+     the device beside the data shards, [14, lanes] a dispatch, and
+     counts come back, not parity; host codecs compare on the writer
+     lanes;
   4. per damaged EC volume: classify -> quarantine .corrupt ->
      fleet rebuild -> re-verify (a data repair un-contaminates the
      parity evidence; round two condemns genuinely bad parity).
+
+Each step is a phase of SeaweedFS_scrub_phase_seconds (scan, scan_ec,
+verify, repair, reverify) and, while the span ring is on, a span
+scrub.<phase>. A pass leaves a report: what it concluded volume by
+volume (PassResult.verdicts), or that it failed and why. wait_pass()
+blocks until a pass has ended; `volume.scrub -wait` prints the report.
 
 Pacing rides util.throttler.Throttler (burst-capped), so an idle-hour
 backlog can't turn into a full-rate IO storm. pause() takes effect at
@@ -33,6 +42,7 @@ from typing import Callable, Dict, List, Optional, Sequence
 
 from seaweedfs_tpu.ec import fleet
 from seaweedfs_tpu.scrub import planner, scanner
+from seaweedfs_tpu.scrub.phases import phase
 from seaweedfs_tpu.stats import trace
 from seaweedfs_tpu.stats.metrics import (
     ScrubCorruptionsFoundCounter, ScrubCorruptionsRepairedCounter,
@@ -44,6 +54,17 @@ from seaweedfs_tpu.util import wlog
 from seaweedfs_tpu.util.throttler import Throttler
 
 log = wlog.logger("scrub")
+
+
+@dataclass
+class VolumeVerdict:
+    """What one pass concluded about one volume it covered: nothing set
+    is `clean`."""
+
+    rebuilt_shards: List[int] = field(default_factory=list)  # condemned,
+    #                              quarantined and rebuilt (EC volumes)
+    needles_repaired: int = 0    # rewritten from a replica
+    unrecoverable: int = 0       # shards / needles left damaged
 
 
 @dataclass
@@ -59,6 +80,12 @@ class PassResult:
     volumes: int = 0
     ec_volumes: int = 0
     details: List[str] = field(default_factory=list)
+    # the pass's report (not summed into the ledger): every volume it
+    # covered, and whether it ran to its end
+    verdicts: Dict[int, VolumeVerdict] = field(default_factory=dict)
+    failed: bool = False
+    error: str = ""
+    seconds: float = 0.0
 
 
 class ScrubPaused(Exception):
@@ -108,6 +135,11 @@ class ScrubDaemon:
         self.passes_completed = 0
         self.last_pass_unix = 0.0
         self.totals = PassResult()
+        # passes that ran to an end, completed or failed, and the last
+        # one's report: what wait_pass() waits on
+        self._ended = threading.Condition()
+        self.passes_ended = 0  # guarded_by(self._ended, writes)
+        self.last_pass: Optional[PassResult] = None  # guarded_by(self._ended, writes)
         if export_lag:
             # weakref: the gauge is process-global and must neither pin
             # a dead daemon's Store in memory nor keep reporting it
@@ -184,6 +216,24 @@ class ScrubDaemon:
             t.join(timeout=10)
         with self._lock:
             self._state = "idle"
+        with self._ended:
+            self._ended.notify_all()
+
+    def wait_pass(self, after: int, timeout: Optional[float] = None) -> bool:
+        """Block until more than `after` passes have ended (completed
+        or failed; start() and status() give the count), the daemon is
+        stopping, or `timeout` seconds are over. True unless it timed
+        out."""
+        with self._ended:
+            return self._ended.wait_for(
+                lambda: self.passes_ended > after or self._stopping,
+                timeout)
+
+    def _pass_ended(self, res: PassResult) -> None:
+        with self._ended:
+            self.passes_ended += 1
+            self.last_pass = res
+            self._ended.notify_all()
 
     def status(self) -> Dict:
         lag = self._scan_lag()
@@ -200,6 +250,7 @@ class ScrubDaemon:
             "passes_completed": self.passes_completed,
             "last_pass_unix": self.last_pass_unix,
             "scan_lag_seconds": lag,
+            "passes_ended": self.passes_ended,
         }
 
     # -- the pass ------------------------------------------------------------
@@ -220,40 +271,70 @@ class ScrubDaemon:
         # durability, never the other way). No-op context when QoS off.
         from seaweedfs_tpu import qos
         vids, mbps = self._pass_volume_ids, self._pass_mbps
+        res = None                   # a pass that ended, not yet said so
         while not self._stopping:
             try:
                 with qos.internal_context():
-                    self.run_pass(vids, mbps=mbps)
+                    res, exc = self._sweep(vids, mbps)
             except ScrubPaused:
                 return
-            except Exception:
-                log.exception("scrub pass failed")
+            if exc is not None:
+                log.error("scrub pass failed", exc_info=exc)
             vids, mbps = None, None  # later passes: whole store, server budget
             if self.interval_s <= 0:
                 break
+            self._pass_ended(res)
+            res = None
             self._wake.wait(timeout=self.interval_s)
             self._wake.clear()
+        # The last thing this thread does is say that its pass has
+        # ended, with the daemon already in its final state and free to
+        # be started: whoever waited may start the next pass at once.
         with self._lock:
             if not self._stopping:   # stop() owns the final state
-                self._state = "idle"
+                self._state = "failed" if res is not None and res.failed \
+                    else "idle"
+                self._thread = None
+        if res is not None:
+            self._pass_ended(res)
 
     def run_pass(self, volume_ids: Optional[Sequence[int]] = None,
                  mbps: Optional[float] = None) -> PassResult:
         """One synchronous sweep over everything mounted locally."""
+        res, exc = self._sweep(volume_ids, mbps)
+        self._pass_ended(res)
+        if exc is not None:
+            raise exc
+        return res
+
+    def _sweep(self, volume_ids: Optional[Sequence[int]],
+               mbps: Optional[float]):
+        """-> (the pass's report, what it raised or None). A pass that
+        raised ended too, as failed: whoever waits for it learns so
+        instead of reading an idle daemon. A stop (ScrubPaused) is no
+        end and leaves no report."""
         res = PassResult()
         mbps = self.mbps if mbps is None else mbps
         throttler = Throttler(mbps) if mbps > 0 else None
         t0 = time.perf_counter()
         only = set(volume_ids) if volume_ids else None
-        with trace.span("scrub.pass"):
-            self._scan_volumes(res, throttler, only)
-            self._scan_ec_volumes(res, throttler, only)
-        ScrubPassSecondsHistogram.observe(time.perf_counter() - t0)
+        try:
+            with trace.span("scrub.pass"):
+                self._scan_volumes(res, throttler, only)
+                self._scan_ec_volumes(res, throttler, only)
+        except ScrubPaused:
+            raise
+        except Exception as e:
+            res.failed, res.error = True, f"{type(e).__name__}: {e}"
+            res.seconds = time.perf_counter() - t0
+            return res, e
+        res.seconds = time.perf_counter() - t0
+        ScrubPassSecondsHistogram.observe(res.seconds)
         self.last_pass_unix = time.time()
         self.passes_completed += 1
         self.current_volume_id = 0
         self._accumulate(res)
-        return res
+        return res, None
 
     def _accumulate(self, res: PassResult) -> None:
         t = self.totals
@@ -277,6 +358,7 @@ class ScrubDaemon:
                     continue  # cloud-tiered bytes are the backend's
                 self._checkpoint(vid)
                 scan = scanner.scan_volume(v, throttler)
+                verdict = res.verdicts.setdefault(vid, VolumeVerdict())
                 res.volumes += 1
                 res.bytes_scanned += scan.bytes_scanned
                 res.needles_verified += scan.needles_verified
@@ -290,6 +372,7 @@ class ScrubDaemon:
                     if self.replica_fetch is not None and \
                             planner.repair_needle(v, n, self.replica_fetch):
                         res.corruptions_repaired += 1
+                        verdict.needles_repaired += 1
                         ScrubCorruptionsRepairedCounter.labels(
                             "needle").inc()
                         if self.on_repair is not None:
@@ -299,6 +382,7 @@ class ScrubDaemon:
                             f"from replica")
                     else:
                         res.unrecoverable += 1
+                        verdict.unrecoverable += 1
                         ScrubUnrecoverableCounter.inc()
                         res.details.append(
                             f"volume {vid}: needle {n.id:x} corrupt, "
@@ -315,6 +399,7 @@ class ScrubDaemon:
         for vid, ecv in ecvs:
             self._checkpoint(vid)
             scan = scanner.scan_ec_volume_needles(ecv, throttler=throttler)
+            res.verdicts.setdefault(vid, VolumeVerdict())
             res.ec_volumes += 1
             res.bytes_scanned += scan.bytes_scanned
             res.needles_verified += scan.needles_verified
@@ -331,7 +416,7 @@ class ScrubDaemon:
         # spans from every volume share RS dispatches (the tentpole)
         self._checkpoint(0)
         by_base = {ecv.base_name: (vid, ecv) for vid, ecv in ecvs}
-        with trace.span("scrub.verify", volumes=len(by_base)):
+        with phase("verify", volumes=len(by_base)):
             mesh_fleet = fleet.mesh_fleet_or_none() \
                 if self.mesh_cfg is not None else None
             if mesh_fleet is not None:
@@ -391,6 +476,7 @@ class ScrubDaemon:
                 ScrubCorruptionsFoundCounter.labels(k).inc()
             if verdict == "unrecoverable":
                 res.unrecoverable += len(bad)
+                res.verdicts[vid].unrecoverable += len(bad)
                 ScrubUnrecoverableCounter.inc(len(bad))
                 res.details.append(
                     f"ec volume {vid}: shards {bad} unrecoverable "
@@ -407,6 +493,7 @@ class ScrubDaemon:
                     unmount=ecv.unmount_shard, remount=ecv.mount_shard)
             except (ValueError, OSError) as e:
                 res.unrecoverable += len(bad)
+                res.verdicts[vid].unrecoverable += len(bad)
                 ScrubUnrecoverableCounter.inc(len(bad))
                 res.details.append(
                     f"ec volume {vid}: rebuild of {bad} failed: {e}")
@@ -421,6 +508,8 @@ class ScrubDaemon:
             for k in kinds:
                 res.corruptions_repaired += 1
                 ScrubCorruptionsRepairedCounter.labels(k).inc()
+            rebuilt = res.verdicts[vid].rebuilt_shards
+            rebuilt[:] = sorted(set(rebuilt) | set(bad))
             res.details.append(
                 f"ec volume {vid}: shards {bad} reconstructed")
             # evidence for the next round: repaired shards are clean

@@ -41,6 +41,7 @@ from seaweedfs_tpu.ec import fleet
 from seaweedfs_tpu.ec.encoder import shard_file_name
 from seaweedfs_tpu.ec.shard_bits import TOTAL_SHARDS
 from seaweedfs_tpu.ops.rs_code import DATA_SHARDS, PARITY_SHARDS
+from seaweedfs_tpu.scrub.phases import phase
 from seaweedfs_tpu.stats import trace
 from seaweedfs_tpu.storage.needle import Needle, NeedleError, masked_crc
 from seaweedfs_tpu.storage.volume import Volume, VolumeError
@@ -150,8 +151,8 @@ def repair_ec_volume(base: str, bad_shards: List[int],
     EcVolumeShard holds the old inode otherwise). Returns the rebuilt
     shard ids; raises if fewer than DATA_SHARDS survivors remain.
     """
-    with trace.span("scrub.repair", base=os.path.basename(base),
-                    shards=len(bad_shards)):
+    with phase("repair", base=os.path.basename(base),
+               shards=len(bad_shards)):
         for sid in bad_shards:
             if unmount is not None:
                 unmount(sid)
@@ -168,7 +169,8 @@ def verify_ec_repair(base: str, backend: str = "auto") -> "fleet.VerifyResult":
     """Post-repair stripe verify of ONE volume (the daemon's second
     evidence round: after a data-shard rebuild, any parity mismatch
     that remains is genuine parity damage)."""
-    return fleet.fleet_verify_ec_files([base], backend=backend)[base]
+    with phase("reverify", base=os.path.basename(base)):
+        return fleet.fleet_verify_ec_files([base], backend=backend)[base]
 
 
 def repair_needle(v: Volume, corrupt: Needle,

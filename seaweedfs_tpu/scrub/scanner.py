@@ -27,7 +27,7 @@ from typing import List, Optional, Set, Tuple
 from seaweedfs_tpu.ec.ec_volume import EcVolume
 from seaweedfs_tpu.ec.shard_bits import DATA_SHARDS
 from seaweedfs_tpu.ops.rs_code import ReedSolomon
-from seaweedfs_tpu.stats import trace
+from seaweedfs_tpu.scrub.phases import phase
 from seaweedfs_tpu.storage import types as t
 from seaweedfs_tpu.storage.needle import (DataCorruptionError, Needle,
                                           NeedleError, actual_size,
@@ -60,7 +60,7 @@ def scan_volume(v: Volume, throttler=None) -> NeedleScan:
     consulted per record to skip dead copies.
     """
     res = NeedleScan()
-    with trace.span("scrub.scan", vid=v.id):
+    with phase("scan", vid=v.id):
         for offset, n in v.scan_needles():
             nv = v.nm.get(n.id)
             if nv is None or nv.offset != offset or \
@@ -101,7 +101,7 @@ def scan_ec_volume_needles(ecv: EcVolume, version: int = 3,
     server doesn't hold are skipped (their holder scrubs them).
     """
     res = EcNeedleScan()
-    with trace.span("scrub.scan_ec", vid=ecv.volume_id):
+    with phase("scan_ec", vid=ecv.volume_id):
         for i in range(len(ecv._keys)):
             size = int(ecv._sizes[i])
             if t.size_is_deleted(size) or size < 0:

@@ -911,19 +911,38 @@ class VolumeServer:
     # -- gRPC: scrub control plane ---------------------------------------------
 
     def VolumeScrubStart(self, request, context):
+        # read before the start: the pass it begins ends after this count
+        ended = self.scrub.passes_ended
         started = self.scrub.start(
             volume_ids=list(request.volume_ids) or None,
             throttle_mbps=request.throttle_mbps or None,
             full=request.full)
-        return volume_server_pb2.VolumeScrubStartResponse(started=started)
+        return volume_server_pb2.VolumeScrubStartResponse(
+            started=started, passes_ended=ended)
 
     def VolumeScrubPause(self, request, context):
         return volume_server_pb2.VolumeScrubPauseResponse(
             paused=self.scrub.pause())
 
     def VolumeScrubStatus(self, request, context):
-        return volume_server_pb2.VolumeScrubStatusResponse(
+        if request.wait:
+            # in slices: a caller that went away frees this thread
+            while context.is_active() and not self.scrub.wait_pass(
+                    request.after_passes_ended, timeout=1.0):
+                pass
+        resp = volume_server_pb2.VolumeScrubStatusResponse(
             **self.scrub.status())
+        last = self.scrub.last_pass
+        if last is not None:
+            resp.last_pass.failed = last.failed
+            resp.last_pass.error = last.error
+            resp.last_pass.seconds = last.seconds
+            for vid, v in sorted(last.verdicts.items()):
+                resp.last_pass.volumes.add(
+                    volume_id=vid, rebuilt_shard_ids=v.rebuilt_shards,
+                    needles_repaired=v.needles_repaired,
+                    unrecoverable=v.unrecoverable)
+        return resp
 
     def _fetch_needle_from_replica(self, vid: int, corrupt: Needle):
         """Scrub repair source: the raw stored payload of one needle

@@ -471,7 +471,11 @@ def volume_scrub(env: CommandEnv, argv: List[str], out) -> None:
     """Control the per-server scrub daemon (seaweedfs_tpu/scrub/):
     start a verification pass (the default), pause a running one, or
     print each server's ledger. Without -node the action fans out to
-    every volume server in the topology."""
+    every volume server in the topology. With -wait a start returns
+    when the pass has ended on every server, and prints each server's
+    ledger and one line a volume the pass covered: `clean`, what it
+    rebuilt or rewrote, or `unrecoverable`; a pass that failed fails
+    the command."""
     p = argparse.ArgumentParser(prog="volume.scrub")
     p.add_argument("-node", default="",
                    help="<host:port>; all volume servers when empty")
@@ -481,6 +485,9 @@ def volume_scrub(env: CommandEnv, argv: List[str], out) -> None:
                    help="IO budget for the pass (0 = server default)")
     p.add_argument("-full", action="store_true",
                    help="reset the ledger and rescan from scratch")
+    p.add_argument("-wait", action="store_true",
+                   help="return when the started pass has ended, with "
+                        "its verdict on every volume it covered")
     g = p.add_mutually_exclusive_group()
     g.add_argument("-pause", action="store_true",
                    help="hold the running pass at the next volume")
@@ -492,20 +499,12 @@ def volume_scrub(env: CommandEnv, argv: List[str], out) -> None:
     else:
         urls = sorted(dn.id for _, _, dn
                       in env.data_nodes(env.topology()))
+    started = []   # (url, passes ended when its start came)
     for url in urls:
         stub = env.volume_server(url)
         if args.status:
-            st = stub.VolumeScrubStatus(
-                volume_server_pb2.VolumeScrubStatusRequest())
-            out.write(
-                f"{url}: {st.state} passes:{st.passes_completed} "
-                f"scanned:{st.bytes_scanned}B "
-                f"needles:{st.needles_verified} "
-                f"stripes:{st.stripes_verified} "
-                f"found:{st.corruptions_found} "
-                f"repaired:{st.corruptions_repaired} "
-                f"unrecoverable:{st.unrecoverable} "
-                f"lag:{st.scan_lag_seconds:.0f}s\n")
+            _write_scrub_ledger(out, url, stub.VolumeScrubStatus(
+                volume_server_pb2.VolumeScrubStatusRequest()))
         elif args.pause:
             r = stub.VolumeScrubPause(
                 volume_server_pb2.VolumeScrubPauseRequest())
@@ -519,6 +518,44 @@ def volume_scrub(env: CommandEnv, argv: List[str], out) -> None:
                     full=args.full))
             out.write(f"{url}: "
                       f"{'scrub started' if r.started else 'scrub already running'}\n")
+            started.append((url, r.passes_ended))
+    if not args.wait:
+        return
+    failed = []
+    for url, ended in started:   # every server's pass is under way
+        st = env.volume_server(url).VolumeScrubStatus(
+            volume_server_pb2.VolumeScrubStatusRequest(
+                wait=True, after_passes_ended=ended))
+        _write_scrub_ledger(out, url, st)
+        if st.passes_ended <= ended:
+            failed.append(f"{url}: the daemon stopped before the pass ended")
+            continue
+        for v in st.last_pass.volumes:
+            did = []
+            if v.rebuilt_shard_ids:
+                did.append(f"rebuilt shards {list(v.rebuilt_shard_ids)}")
+            if v.needles_repaired:
+                did.append(f"rewrote {v.needles_repaired} needle(s)")
+            if v.unrecoverable:
+                did.append("unrecoverable")
+            out.write(f"{url}: volume {v.volume_id}: "
+                      f"{', '.join(did) or 'clean'}\n")
+        if st.last_pass.failed:
+            failed.append(f"{url}: scrub pass failed: {st.last_pass.error}")
+    if failed:
+        raise RuntimeError("volume.scrub failed: " + "; ".join(failed))
+
+
+def _write_scrub_ledger(out, url: str, st) -> None:
+    out.write(
+        f"{url}: {st.state} passes:{st.passes_completed} "
+        f"scanned:{st.bytes_scanned}B "
+        f"needles:{st.needles_verified} "
+        f"stripes:{st.stripes_verified} "
+        f"found:{st.corruptions_found} "
+        f"repaired:{st.corruptions_repaired} "
+        f"unrecoverable:{st.unrecoverable} "
+        f"lag:{st.scan_lag_seconds:.0f}s\n")
 
 
 @command("volume.vacuum", "compact volumes above the garbage threshold")

@@ -346,6 +346,15 @@ FleetRebuildGroupsCounter = REGISTRY.counter(
 FleetRebuiltBytesCounter = REGISTRY.counter(
     "SeaweedFS_fleet_rebuilt_bytes_total",
     "bytes appended to rebuilt shard files by fleet rebuild passes")
+# Where a verify pass's re-encode-and-compare ran: `device` (the jax
+# backend: stored parity placed beside the data shards, counts come
+# back) or `host` (a host codec's parity against the parity files, on
+# the writer lanes). Bytes of the ten data rows, as VerifyResult counts
+# them.
+FleetVerifyBytesCounter = REGISTRY.counter(
+    "SeaweedFS_fleet_verify_bytes_total",
+    "data-shard bytes whose stripes a fleet verify pass held against "
+    "the stored parity", ("where",))
 FleetWriterBacklogGauge = REGISTRY.gauge(
     "SeaweedFS_fleet_writer_lane_backlog",
     "writes queued on one writer lane", ("lane",))
@@ -407,6 +416,13 @@ ScrubPassSecondsHistogram = REGISTRY.histogram(
     "SeaweedFS_scrub_pass_seconds",
     "wall time of one full scrub pass",
     buckets=(0.01, 0.1, 1, 10, 60, 600, 3600, 6 * 3600, 24 * 3600))
+# One pass by phase: scan (needle sweep of normal volumes), scan_ec
+# (needle sweep of EC volumes over local shards), verify (the one fused
+# stripe verify), repair (quarantine + rebuild of one volume's condemned
+# shards), reverify (the stripe verify that follows a repair).
+ScrubPhaseSecondsHistogram = REGISTRY.histogram(
+    "SeaweedFS_scrub_phase_seconds",
+    "scrub pass: wall time by phase", ("phase",))
 ScrubScanLagGauge = REGISTRY.gauge(
     "SeaweedFS_scrub_scan_lag_seconds",
     "seconds since the last completed scrub pass")

@@ -71,8 +71,9 @@ def test_described_device_is_the_kind_the_peaks_table_knows(topo):
 
 # matrix rows: 4 = the parity matrix of ec.encode, 2 = the decode matrix
 # of ec.rebuild after a loss of two shards (benchmark cell
-# node-repair.rebuild)
-@pytest.mark.parametrize("rows", [4, 2])
+# node-repair.rebuild), 1 = a scrub's repair of one condemned shard
+# (warm-scrub.scrub)
+@pytest.mark.parametrize("rows", [4, 2, 1])
 @pytest.mark.parametrize("lanes", [LANES_MIN, LANES_MAX])
 def test_gf_linear_compiles_for_the_chip(one_chip, lanes, rows):
     import jax
@@ -81,6 +82,20 @@ def test_gf_linear_compiles_for_the_chip(one_chip, lanes, rows):
         _m2(one_chip, rows), _data(one_chip, lanes)).compile()
     assert compiled is not None
     assert compiled.memory_analysis() is not None
+
+
+@pytest.mark.parametrize("lanes", [LANES_MIN, LANES_MAX])
+def test_verify_program_compiles_for_the_chip(one_chip, lanes):
+    """A scrub's compare-and-count over a placed [14, lanes] stripe:
+    what leaves the device is int32 [2, 4, blocks], KB."""
+    import jax
+    import jax.numpy as jnp
+    from seaweedfs_tpu.ops import rs_kernel
+    stripe = jax.ShapeDtypeStruct((14, lanes), jnp.uint8, sharding=one_chip)
+    compiled = rs_kernel._verify_jit.lower(_m2(one_chip), stripe).compile()
+    counts = 2 * 4 * (lanes // rs_kernel.VERIFY_BLOCK) * 4
+    assert counts <= compiled.memory_analysis().output_size_in_bytes \
+        <= max(counts, 4096)                 # a tile at the least
 
 
 def test_gf_linear_gemm_compiles_for_the_chip(one_chip):

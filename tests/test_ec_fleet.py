@@ -943,12 +943,13 @@ def test_rebuild_buffer_is_not_handed_out_before_its_result(tmp_path,
 
 
 @pytest.mark.parametrize("kind, dispatches", [
-    ("encode", 13), ("rebuild", 15), ("verify", 15)])
+    ("encode", 13), ("rebuild", 15)])
 def test_buffer_is_not_handed_out_while_a_lane_reads_its_result(
         tmp_path, monkeypatch, kind, dispatches):
     """The result rows have a reader of their own: the parity write
-    (encode), the rebuilt-shard write (rebuild), the compare (verify),
-    each on a writer lane. With the first of them held back the pass
+    (encode), the rebuilt-shard write (rebuild), each on a writer lane
+    (a verify's lanes read nothing of the buffer: the test after this
+    one). With the first of them held back the pass
     runs out of buffers and WAITS: the buffer whose result that closure
     reads goes to no reader, and no other buffer of the share comes
     round before it (their closures queue behind the held one) — and
@@ -980,7 +981,6 @@ def test_buffer_is_not_handed_out_while_a_lane_reads_its_result(
     monkeypatch.setattr(fleet._Staging, "acquire", acquire)
     monkeypatch.setattr(fleet, "_then_release", then_release)
     monkeypatch.setattr(fleet, "SMALL_BLOCK_SIZE", REBUILD_FLOOR)
-    verified = {}
     if kind == "encode":
         bases = _make_volumes(str(tmp_path), [24 * ROW + 9], seed=37)
         twins = _serial_twin(bases)
@@ -990,19 +990,11 @@ def test_buffer_is_not_handed_out_while_a_lane_reads_its_result(
             fleet.fleet_write_ec_files, bases, backend="jax",
             large_block=ROOMY, small_block=SMALL, chunk=2 * ROW, readers=1,
             depth=1)
-    elif kind == "rebuild":
+    else:
         bases, twins = _lose_and_twin(tmp_path, [40 * ROW, 40 * ROW], 38)
         run = functools.partial(
             fleet.fleet_rebuild_ec_files, bases, backend="jax",
             chunk=REBUILD_CHUNK, readers=1, depth=1)
-    else:
-        bases = twins = _encoded(tmp_path, [40 * ROW, 40 * ROW], 39)
-        _flip(bases[1], 11, 2000)
-
-        def run():
-            verified.update(fleet.fleet_verify_ec_files(
-                bases, backend="jax", chunk=REBUILD_CHUNK, readers=1,
-                depth=1))
     errors = []
 
     def guarded():
@@ -1031,10 +1023,80 @@ def test_buffer_is_not_handed_out_while_a_lane_reads_its_result(
     assert not t.is_alive() and not errors
     assert len(handed) == dispatches and handed.count(held[0]) >= 2
     _assert_shards_equal(bases, twins)
-    if kind == "verify":
-        assert verified[bases[0]].clean and verified[bases[0]].spans == 15
-        assert verified[bases[1]].parity_mismatch == {11: 1}
-        assert verified[bases[1]].first_mismatch == {11: 2000}
+
+
+@pytest.mark.parametrize("backend", ["numpy", "jax"])
+def test_verify_buffer_is_free_once_its_input_is_read(tmp_path, monkeypatch,
+                                                      backend):
+    """What a verify's writer lanes read is not in the staging buffer:
+    a host codec's own parity against the parity files, or the device's
+    counts. So with the first span's closure held back on its lane the
+    pass never waits for a BUFFER: it goes on handing out the share's
+    buffers, the held span's own among them, until the full lane stops
+    the retire thread — and the result is right once the lane runs."""
+    import threading
+    import time
+
+    gate = threading.Event()
+    handed, held = [], []
+    real_acquire = fleet._Staging.acquire
+    real_submit = fleet.TaggedPipeline.submit
+
+    def acquire(self):
+        buf = real_acquire(self)
+        handed.append(id(buf.base))
+        return buf
+
+    def held_back(fn):
+        def run(out):
+            if not held:
+                held.append(len(handed))
+                gate.wait(30)
+            fn(out)
+        return run
+
+    def submit(self, handle, tagged, timeout_s=None):
+        real_submit(self, handle,
+                    [(tag, held_back(fn)) for tag, fn in tagged], timeout_s)
+
+    monkeypatch.setattr(fleet._Staging, "acquire", acquire)
+    monkeypatch.setattr(fleet.TaggedPipeline, "submit", submit)
+    monkeypatch.setattr(fleet, "SMALL_BLOCK_SIZE", REBUILD_FLOOR)
+    bases = _encoded(tmp_path, [40 * ROW, 40 * ROW], 39)
+    _flip(bases[1], 11, 2000)
+    verified, errors = {}, []
+
+    def guarded():
+        try:
+            verified.update(fleet.fleet_verify_ec_files(
+                bases, backend=backend, chunk=REBUILD_CHUNK, readers=1,
+                depth=1))
+        except BaseException as e:  # surfaced below
+            errors.append(e)
+
+    t = threading.Thread(target=guarded, daemon=True)
+    t.start()
+    share = 2 + 1 + 1 + 1 + 1
+    deadline = time.monotonic() + 30
+    seen, quiet = -1, 0
+    while quiet < 10 and time.monotonic() < deadline:
+        time.sleep(0.05)
+        quiet = quiet + 1 if held and len(handed) == seen else 0
+        seen = len(handed)
+    try:
+        assert held and t.is_alive()
+        # more hand-outs than the share has buffers: they came round
+        # while the closure of the first dispatch stood on its lane
+        assert len(handed) > share and len(set(handed)) <= share
+        assert handed.count(handed[0]) >= 2
+    finally:
+        gate.set()
+        t.join(30)
+    assert not t.is_alive() and not errors
+    assert len(handed) == 15
+    assert verified[bases[0]].clean and verified[bases[0]].spans == 15
+    assert verified[bases[1]].parity_mismatch == {11: 1}
+    assert verified[bases[1]].first_mismatch == {11: 2000}
 
 
 @pytest.mark.parametrize("kind", ["encode", "rebuild"])
@@ -1165,23 +1227,29 @@ def _flip(base, sid, offset):
 
 
 def _plain_verify(base):
-    """The reference: whole shards, one numpy encode, one compare."""
-    shards = {}
-    for sid in range(TOTAL_SHARDS):
-        path = shard_file_name(base, sid)
-        if os.path.exists(path):
-            shards[sid] = np.fromfile(path, dtype=np.uint8)
+    """The reference: whole shards, one numpy encode, one compare. A
+    parity file that ends early differs in every byte it lacks; a volume
+    without all ten data shards is not verified."""
+    shards = {sid: np.fromfile(shard_file_name(base, sid), dtype=np.uint8)
+              for sid in range(TOTAL_SHARDS)
+              if os.path.exists(shard_file_name(base, sid))}
     want = {"missing": [s for s in range(TOTAL_SHARDS) if s not in shards],
-            "parity_checked": [s for s in shards if s >= DATA_SHARDS],
-            "parity_mismatch": {}, "first_mismatch": {},
-            "bytes_verified": DATA_SHARDS * len(shards[0]), "verified": True}
+            "parity_checked": [], "parity_mismatch": {}, "first_mismatch": {},
+            "bytes_verified": 0, "verified": False}
+    if any(s < DATA_SHARDS for s in want["missing"]):
+        return want
+    want.update(verified=True,
+                parity_checked=[s for s in shards if s >= DATA_SHARDS],
+                bytes_verified=DATA_SHARDS * len(shards[0]))
     parity = ReedSolomon(backend="numpy").encode(
         np.stack([shards[i] for i in range(DATA_SHARDS)]))
     for sid in want["parity_checked"]:
-        diff = np.nonzero(parity[sid - DATA_SHARDS] != shards[sid])[0]
-        if len(diff):
-            want["parity_mismatch"][sid] = len(diff)
-            want["first_mismatch"][sid] = int(diff[0])
+        have = len(shards[sid])
+        diff = np.nonzero(parity[sid - DATA_SHARDS, :have] != shards[sid])[0]
+        lacking = parity.shape[1] - have
+        if len(diff) or lacking:
+            want["parity_mismatch"][sid] = len(diff) + lacking
+            want["first_mismatch"][sid] = int(diff[0]) if len(diff) else have
     return want
 
 
@@ -1248,44 +1316,49 @@ def test_verify_finds_damage_on_span_and_buffer_edges(tmp_path, monkeypatch,
 
 
 def test_verify_dispatches_views_of_reused_staging(tmp_path, monkeypatch):
-    """A scrub after an encode makes no staging buffer: every buffer it
-    is handed is one the encode pass filled, and what reaches the
-    dispatch layer is a 2-D view of one of them — no stacked copy."""
+    """A scrub after an encode of the same width makes no staging
+    buffer: every buffer it is handed is one the encode pass filled, and
+    what reaches the dispatch layer is a 2-D view of ALL 14 rows of one
+    of them — the stored parity beside the data, no stacked copy, no
+    memory lent for a result."""
     from seaweedfs_tpu.ops import rs_kernel
 
+    block = rs_kernel.VERIFY_BLOCK
     monkeypatch.setattr(fleet, "SMALL_BLOCK_SIZE", REBUILD_FLOOR)
-    bases = _make_volumes(str(tmp_path), [6 * ROW] * 3, seed=62)
+    rows = 7 * 3 * block // (3 * SMALL)        # 7 dispatches of 3 spans
+    bases = _make_volumes(str(tmp_path), [rows * ROW] * 3, seed=62)
     fleet.fleet_write_ec_files(bases, backend="jax", large_block=ROOMY,
-                               small_block=SMALL, chunk=2 * ROW)
+                               small_block=SMALL,
+                               chunk=3 * block // SMALL * ROW)
     idle = list(fleet._IDLE_STAGING._bufs)
-    assert len(idle) == 7 and _idle_shapes() == [(TOTAL_SHARDS, 512)] * 7
+    assert len(idle) == 7 and \
+        _idle_shapes() == [(TOTAL_SHARDS, 3 * block)] * 7
     seen = []
-    real = rs_kernel.apply_matrix_async
+    real = rs_kernel.verify_stripe_async
 
-    def recording(matrix, shards, device=None, out=None):
-        seen.append((shards, out))
-        return real(matrix, shards, device=device, out=out)
+    def recording(matrix, stripe, device=None):
+        seen.append(stripe)
+        return real(matrix, stripe, device=device)
 
-    monkeypatch.setattr(rs_kernel, "apply_matrix_async", recording)
-    fresh, reused = _handed("fresh"), _handed("reused")
-    chunk = DATA_SHARDS * 512
-    span, per_batch = fleet._stacked_spans(chunk, [6 * SMALL] * 3)
-    assert (span, per_batch) == (154, 3)       # 30 spans, 10 dispatches
+    monkeypatch.setattr(rs_kernel, "verify_stripe_async", recording)
+    monkeypatch.setattr(
+        rs_kernel, "apply_matrix_async",
+        lambda *a, **kw: pytest.fail("a verify fetched parity"))
+    fresh, reused, lent = _handed("fresh"), _handed("reused"), _landed("lent")
+    chunk = DATA_SHARDS * 3 * block
+    span, per_batch = fleet._stacked_spans(chunk, [rows * SMALL] * 3)
+    assert (span, per_batch) == (block, 3)     # 21 spans, 7 dispatches
     got = fleet.fleet_verify_ec_files(bases, backend="jax", chunk=chunk)
-    assert all(r.clean and r.spans == 10 for r in got.values())
+    assert all(r.clean and r.spans == 7 for r in got.values())
     assert _handed("fresh") == fresh
-    assert _handed("reused") - reused == 10
+    assert _handed("reused") - reused == 7
+    assert _landed("lent") == lent
     assert [id(b) for b in fleet._IDLE_STAGING._bufs] == \
         [id(b) for b in idle]
-    assert len(seen) == 10
-    for arr, out in seen:
-        assert arr.shape == (DATA_SHARDS, per_batch * span)
-        assert sum(np.shares_memory(arr, b) for b in idle) == 1
-        # the parity lands in the same buffer, in the rows after the input
-        owner, = [b for b in idle if np.shares_memory(arr, b)]
-        assert out.shape == (4, per_batch * span)
-        assert np.shares_memory(out, owner[DATA_SHARDS:])
-        assert not np.shares_memory(out, arr)
+    assert len(seen) == 7
+    for stripe in seen:
+        assert stripe.shape == (TOTAL_SHARDS, per_batch * span)
+        assert sum(np.shares_memory(stripe, b) for b in idle) == 1
 
 
 @pytest.mark.parametrize("n", [1, 2, 128])
@@ -1374,3 +1447,125 @@ def test_verify_failed_pass_leaks_nothing(tmp_path, monkeypatch):
     assert all(r.clean and r.spans == 15 for r in got.values())
     assert [id(b) for b in fleet._IDLE_STAGING._bufs][:made] == idle
     assert len(os.listdir("/proc/self/fd")) == open_fds
+
+
+# --- verify on the device: counts come back, not parity -----------------------
+
+def _truncate(base, sid, size):
+    with open(shard_file_name(base, sid), "r+b") as f:
+        f.truncate(size)
+
+
+DAMAGE = {
+    # case -> (volume sizes, damage(bases, shard sizes, span))
+    "clean": ([40 * ROW, 40 * ROW], lambda b, z, span: None),
+    "parity-flip": ([40 * ROW, 40 * ROW], lambda b, z, span: (
+        _flip(b[0], 12, 5 * span + 17), _flip(b[0], 12, 5 * span + 18),
+        _flip(b[1], 10, 0))),
+    # all four parity rows disagree, at the same offsets
+    "data-flip": ([40 * ROW, 40 * ROW], lambda b, z, span: (
+        _flip(b[1], 3, 2 * span - 1), _flip(b[1], 3, 2 * span))),
+    "truncated-parity": ([40 * ROW, 40 * ROW], lambda b, z, span: (
+        _truncate(b[0], 11, 3 * span + 9), _flip(b[0], 11, 77),
+        _flip(b[1], 13, 5))),
+    "missing-parity-shard": ([40 * ROW, 40 * ROW], lambda b, z, span: (
+        os.remove(shard_file_name(b[0], 13)), _flip(b[0], 10, 4 * span))),
+    "missing-data-shard": ([40 * ROW, 40 * ROW], lambda b, z, span: (
+        os.remove(shard_file_name(b[1], 6)), _flip(b[0], 11, 1))),
+    # three volumes share every buffer; one is shorter than a span, one
+    # not a multiple of it
+    "unequal-volumes": ([40 * ROW, 13 * ROW + 77, 200], lambda b, z, span: (
+        _flip(b[0], 12, z[0] - 1), _flip(b[1], 3, z[1] - 1),
+        _flip(b[2], 10, 0))),
+    # the last span of a volume is short: damage in its last lanes
+    "last-short-span": ([33 * ROW + 100, 40 * ROW], lambda b, z, span: (
+        _flip(b[0], 13, z[0] - 1), _flip(b[0], 13, z[0] - z[0] % span))),
+}
+
+
+@pytest.mark.parametrize("case", sorted(DAMAGE))
+def test_device_verify_equals_host_verify_and_a_plain_compare(
+        tmp_path, monkeypatch, case):
+    """`fleet_verify_ec_files(backend="jax")`, which places the stored
+    parity beside the data and fetches counts, against the numpy backend
+    (the host compare on the writer lanes) and against a plain numpy
+    re-encode of whole shards: byte count for byte count, offset for
+    offset, in every field of the result."""
+    monkeypatch.setattr(fleet, "SMALL_BLOCK_SIZE", REBUILD_FLOOR)
+    sizes, damage = DAMAGE[case]
+    bases = _encoded(tmp_path, sizes, 80 + sorted(DAMAGE).index(case))
+    shard_sizes = [os.path.getsize(shard_file_name(b, 0)) for b in bases]
+    span, _ = fleet._stacked_spans(REBUILD_CHUNK, shard_sizes)
+    if case == "last-short-span":
+        assert shard_sizes[0] % span
+    damage(bases, shard_sizes, span)
+    want = {b: _plain_verify(b) for b in bases}
+    assert case == "clean" or any(
+        w["parity_mismatch"] or not w["verified"] for w in want.values())
+    host = fleet.fleet_verify_ec_files(bases, backend="numpy",
+                                       chunk=REBUILD_CHUNK)
+    device = fleet.fleet_verify_ec_files(bases, backend="jax",
+                                         chunk=REBUILD_CHUNK)
+    for base in bases:
+        for got in (host[base], device[base]):
+            assert {f: getattr(got, f) for f in VERIFY_FIELDS} == want[base]
+        if want[base]["verified"]:
+            # a volume with a short parity file is held to its files by
+            # a host codec, in a pass of its own width
+            assert device[base].spans == host[base].spans or \
+                case == "truncated-parity"
+    if case == "data-flip":
+        assert device[bases[1]].parity_mismatch == \
+            {10: 2, 11: 2, 12: 2, 13: 2}
+        assert set(device[bases[1]].first_mismatch.values()) == {2 * span - 1}
+
+
+def _verified_bytes(where):
+    from seaweedfs_tpu.stats.metrics import FleetVerifyBytesCounter
+    return FleetVerifyBytesCounter.labels(where).value
+
+
+@pytest.mark.parametrize("backend, where, other", [
+    ("jax", "device", "host"), ("numpy", "host", "device")])
+def test_verify_counts_its_bytes_where_the_compare_ran(tmp_path, monkeypatch,
+                                                       backend, where, other):
+    monkeypatch.setattr(fleet, "SMALL_BLOCK_SIZE", REBUILD_FLOOR)
+    bases = _encoded(tmp_path, [40 * ROW, 13 * ROW + 77], 95)
+    before = _verified_bytes(where), _verified_bytes(other)
+    got = fleet.fleet_verify_ec_files(bases, backend=backend,
+                                      chunk=REBUILD_CHUNK)
+    assert _verified_bytes(where) - before[0] == \
+        sum(r.bytes_verified for r in got.values()) == \
+        DATA_SHARDS * sum(os.path.getsize(shard_file_name(b, 0))
+                          for b in bases)
+    assert _verified_bytes(other) == before[1]
+
+
+def test_device_verify_reads_fourteen_rows_and_lends_nothing(tmp_path,
+                                                             monkeypatch):
+    """What a jax verify fetches is counts: every dispatch's `rs.fetch`
+    is a few hundred bytes a slab, no result lands anywhere, and its
+    placement carries all 14 rows."""
+    from seaweedfs_tpu.ops import rs_kernel
+
+    monkeypatch.setattr(fleet, "SMALL_BLOCK_SIZE", REBUILD_FLOOR)
+    bases = _encoded(tmp_path, [40 * ROW, 40 * ROW], 96)
+    phases = []
+    real = rs_kernel._phase
+
+    def phase(name, **tags):
+        phases.append((name, tags.get("bytes")))
+        return real(name, **tags)
+
+    monkeypatch.setattr(rs_kernel, "_phase", phase)
+    lent, fresh = _landed("lent"), _landed("fresh")
+    got = fleet.fleet_verify_ec_files(bases, backend="jax",
+                                      chunk=REBUILD_CHUNK)
+    assert all(r.clean and r.spans == 15 for r in got.values())
+    assert (_landed("lent"), _landed("fresh")) == (lent, fresh)
+    placed = [b for name, b in phases if name == "place"]
+    fetched = [b for name, b in phases if name == "fetch"]
+    assert len(placed) == len(fetched) == 15
+    assert set(placed) == {TOTAL_SHARDS * rs_kernel._MIN_SLAB}
+    assert set(fetched) == {
+        2 * 4 * (rs_kernel._MIN_SLAB // rs_kernel.VERIFY_BLOCK) * 4}

@@ -263,3 +263,105 @@ def test_codec_passes_out_through_on_jax_and_host_backends_keep_their_own(
         want = full[[0, 3]]
     assert np.array_equal(got, want)
     assert (got is out) == (rs.backend == "jax")
+
+
+# --- compare-and-count: a verify's counts, not its parity --------------------
+
+def _plain_counts(stripe, block):
+    """The reference: numpy re-encode, numpy compare, per parity row and
+    block of lanes the differing bytes and the first differing lane."""
+    parity = ReedSolomon(backend="numpy").encode(stripe[:10])
+    differ = (parity != stripe[10:]).reshape(4, -1, block)
+    lane = np.arange(block)
+    return differ.sum(axis=2), \
+        np.where(differ, lane, block).min(axis=2)
+
+
+@pytest.mark.parametrize("flips", [
+    "none", "data-row", "one-parity-row", "first-lane", "last-lane",
+    "block-boundary"])
+@pytest.mark.parametrize("blocks", [
+    16,            # one full slab of 2^16 lanes
+    5,             # one slab, a padded tail
+    2 * 16 + 3,    # several slabs, the last one padded
+])
+def test_verify_counts_equal_a_numpy_reencode_and_compare(monkeypatch, blocks,
+                                                          flips):
+    from seaweedfs_tpu.ops import rs_kernel
+
+    block = rs_kernel.VERIFY_BLOCK
+    assert rs_kernel._MIN_SLAB == 16 * block
+    monkeypatch.setattr(rs_kernel, "_MAX_SLAB", rs_kernel._MIN_SLAB)
+    n = blocks * block
+    rng = np.random.default_rng(90 + blocks)
+    rs = ReedSolomon(backend="numpy")
+    stripe = np.empty((14, n), dtype=np.uint8)
+    stripe[:10] = rand_shards(rng, (10, n))
+    stripe[10:] = rs.encode(stripe[:10])
+    for row, lane in {
+            "none": [],
+            "data-row": [(4, 3 * block + 17), (4, 3 * block + 18)],
+            "one-parity-row": [(12, block + 5), (12, n - 7), (12, 9)],
+            "first-lane": [(10, 0), (7, 0)],
+            "last-lane": [(13, n - 1), (0, n - 1)],
+            # the last lane of one block and the first of the next
+            "block-boundary": [(11, 2 * block - 1), (11, 2 * block),
+                               (2, 2 * block - 1)],
+    }[flips]:
+        stripe[row, lane] ^= 0x5A
+    want_counts, want_firsts = _plain_counts(stripe, block)
+    counts, firsts = rs_kernel.verify_stripe_async(
+        rs.matrix[10:], stripe).result()
+    assert counts.shape == firsts.shape == (4, blocks)
+    assert np.array_equal(counts, want_counts)
+    assert np.array_equal(firsts, want_firsts)
+    if flips == "none":
+        assert not counts.any() and (firsts == block).all()
+    if flips == "data-row":            # every parity row, the same lanes
+        assert (counts[:, 3] == 2).all() and (firsts[:, 3] == 17).all()
+        assert counts.sum() == 8
+
+
+def test_verify_stripe_takes_a_view_and_fetches_counts_only(monkeypatch):
+    """As ec/fleet.py hands it over: the first lanes of a wider buffer.
+    What comes back is KB; no result memory is lent or made."""
+    from seaweedfs_tpu.ops import rs_kernel
+
+    block = rs_kernel.VERIFY_BLOCK
+    rng = np.random.default_rng(97)
+    rs = ReedSolomon(backend="numpy")
+    buf = np.full((14, 3 * block + 100), 0xA5, dtype=np.uint8)
+    stripe = buf[:, :3 * block]
+    stripe[:10] = rand_shards(rng, (10, 3 * block))
+    stripe[10:] = rs.encode(stripe[:10])
+    kept = buf.copy()
+    before = _result_counts()
+    fetched = []
+    real = rs_kernel._phase
+
+    def phase(name, **tags):
+        if name == "fetch":
+            fetched.append(tags["bytes"])
+        return real(name, **tags)
+
+    monkeypatch.setattr(rs_kernel, "_phase", phase)
+    counts, firsts = rs_kernel.verify_stripe_async(
+        rs.matrix[10:], stripe).result()
+    assert not counts.any() and counts.shape == (4, 3)
+    assert np.array_equal(buf, kept)
+    assert _result_counts() == before
+    assert fetched == [2 * 4 * 16 * 4]       # one slab: int32 [2, 4, 16]
+
+
+@pytest.mark.parametrize("case, stripe, message", [
+    ("not whole blocks", np.zeros((14, 4097), dtype=np.uint8), "blocks"),
+    ("ten rows", np.zeros((10, 4096), dtype=np.uint8), "stripe"),
+    ("stacked", np.zeros((2, 14, 4096), dtype=np.uint8), "stripe"),
+    ("dtype", np.zeros((14, 4096), dtype=np.int32), "stripe"),
+])
+def test_a_stripe_that_cannot_be_verified_raises_at_the_call(case, stripe,
+                                                             message):
+    from seaweedfs_tpu.ops import rs_kernel
+
+    with pytest.raises(ValueError, match=message):
+        rs_kernel.verify_stripe_async(ReedSolomon().matrix[10:], stripe)
