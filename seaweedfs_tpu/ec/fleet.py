@@ -343,18 +343,32 @@ class _Dispatcher:
                 max_workers=max(1, encoders),
                 thread_name_prefix="fleet-encode")
 
+    def room(self, lanes: int) -> int:
+        """Lanes of a staging buffer that a dispatch of `lanes` takes:
+        the jax dispatch layer places whole slabs, the last one padded
+        up to its power-of-two width, and a buffer that reaches that
+        far is sliced for every slab and never copied. Host codecs take
+        the lanes as they are."""
+        if self._pool is not None:
+            return lanes
+        from seaweedfs_tpu.ops import rs_kernel
+        return rs_kernel.placed_lanes(lanes)
+
     def _lanes(self, op: str, rows: int, apply_async, apply,
                buf: np.ndarray, cuts: List[Tuple[int, int]],
                done: Callable[[], None]):
         """One GF map of `rows` output rows over a staging buffer
         [14, lanes] whose spans lie at `cuts` = [(lane offset, lanes)],
         back to back from lane 0: .result() yields one [rows, lanes]
-        array a span. The jax branch hands the filled lanes of the ten
-        input rows over as they are, a 2-D view, and lends the same
-        lanes of the rows after them for the result: no copy before the
-        dispatch layer's slab slices, and after its fetch one copy into
-        memory that was touched before. Host backends get one pool task
-        a span, each a view; their codecs allocate their own results.
+        array a span. The jax branch hands the ten input rows over as
+        they are, a 2-D view of the filled lanes AND the buffer's slack
+        after them up to the tail slab's end (`room`: whatever an
+        earlier dispatch left there pads the tail, and is trimmed off
+        with it), and lends the filled lanes of the rows after them for
+        the result: no copy before the dispatch layer's slab slices,
+        and after its fetch one copy into memory that was touched
+        before. Host backends get one pool task a span, each a view;
+        their codecs allocate their own results.
         `done` runs when every read of the input rows on behalf of this
         dispatch is over (retire thread)."""
         if _failpoint._armed:
@@ -362,9 +376,10 @@ class _Dispatcher:
         if self._pool is None:
             with _StageTimer("pack", spans=len(cuts)):
                 used = cuts[-1][0] + cuts[-1][1]
-                data = buf[:DATA_SHARDS, :used]
+                data = buf[:DATA_SHARDS, :self.room(used)]
                 out = buf[DATA_SHARDS:DATA_SHARDS + rows, :used]
-            handle = apply_async(data, device=self._device, out=out)
+            handle = apply_async(data, device=self._device, out=out,
+                                 lanes=used)
             return _SplitHandle(handle, [n for _, n in cuts], done)
         token = trace.handoff()
         return _Gathered([self._pool.submit(
@@ -394,16 +409,19 @@ class _Dispatcher:
                      done: Callable[[], None]):
         """The jax backend's verify: the filled lanes of ALL 14 rows go
         to the device as they lie — the data shards and the stored
-        parity — and .result() yields a span's (counts, firsts), each
-        [4, blocks of the span]: nothing is lent, what comes back is
-        KB. Every cut starts and ends on a block boundary."""
+        parity, and the slack after them as in `_lanes` — and .result()
+        yields a span's (counts, firsts), each [4, blocks of the span]:
+        nothing is lent, what comes back is KB. Every cut starts and
+        ends on a block boundary."""
         from seaweedfs_tpu.ops import rs_kernel
         if _failpoint._armed:
             _failpoint.hit("fleet.dispatch", op="verify")
         with _StageTimer("pack", spans=len(cuts)):
-            stripe = buf[:, :cuts[-1][0] + cuts[-1][1]]
+            used = cuts[-1][0] + cuts[-1][1]
+            stripe = buf[:, :self.room(used)]
         handle = rs_kernel.verify_stripe_async(
-            self._rs.matrix[DATA_SHARDS:], stripe, device=self._device)
+            self._rs.matrix[DATA_SHARDS:], stripe, device=self._device,
+            lanes=used)
         return _CountsHandle(
             handle, [n // rs_kernel.VERIFY_BLOCK for _, n in cuts], done)
 
@@ -491,10 +509,14 @@ class _IdleStaging:
     `[:, :lanes]` views of them: an encode pass's width is set by its
     chunk, a rebuild pass's by the largest shard it was given, and a
     server that alternates them (or rebuilds volumes of another size
-    every command) must not throw its pages away each time. The
-    capacity is the widest pass so far, rounded up to a small block —
-    an encode pass's width as it is — and only grows, so odd widths
-    cannot pile up. Only buffers that were filled before come here (an
+    every command) must not throw its pages away each time. A pass's
+    `lanes` here are what its widest dispatch TAKES of a buffer
+    (`_Dispatcher.room`): on the jax backend the planned lanes and the
+    slack up to the tail slab's end, at most 2 Mi lanes a row of
+    address space, touched only where a tail lies. The capacity is the
+    widest pass so far, rounded up to a small block — an encode pass's
+    width as it is — and only grows, so odd widths cannot pile up. Only
+    buffers that were filled before come here (an
     untouched np.empty is address space, not memory), at most one
     pass's share: that is all the memory the scheduler keeps resident
     while idle."""
@@ -819,7 +841,9 @@ def _staged_pass(root, backend: str, device, encoders: int, readers: int,
     # dispatch hold the pass up here, as fleet.wait.staging, where it
     # would next wait for a lane). Upstream never needs them all, so a
     # pass out of buffers always has some coming back.
-    staging = _Staging(lanes,
+    # A buffer is as wide as the dispatch layer takes for a dispatch of
+    # `lanes`; the plan fills `lanes` of it.
+    staging = _Staging(dispatcher.room(lanes),
                        -(-prefetch // per_buffer) + 1 + depth + 1 + 1,
                        pipe._raise_pending)
     inflight: deque = deque()

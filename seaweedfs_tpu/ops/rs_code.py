@@ -118,7 +118,8 @@ class ReedSolomon:
             raise ValueError(f"expected {self.data_shards} data shards")
         return self._apply(self.matrix[self.data_shards:], data)
 
-    def encode_async(self, data: np.ndarray, device=None, out=None):
+    def encode_async(self, data: np.ndarray, device=None, out=None,
+                     lanes=None):
         """Pipelined encode: returns a handle with .result() -> parity.
 
         On the jax backend the dispatch is issued immediately and the
@@ -126,9 +127,11 @@ class ReedSolomon:
         compute synchronously and return a pre-resolved handle, so
         pipeline-structured callers work uniformly. `device` pins the
         dispatch to one jax device (the fleet scheduler runs one
-        scheduler per device); `out` lends the result its memory
-        (rs_kernel.apply_matrix_async). Host backends ignore both: the
-        codec allocates its own result.
+        scheduler per device); `out` lends the result its memory and
+        `lanes` says how many lanes of a wider `data` are the dispatch
+        (rs_kernel.apply_matrix_async). Host backends ignore `device`
+        and `out` (the codec allocates its own result) and map the
+        first `lanes`.
         """
         data = np.asarray(data, dtype=np.uint8)
         if data.shape[-2] != self.data_shards:
@@ -137,8 +140,9 @@ class ReedSolomon:
             from seaweedfs_tpu.ops import rs_kernel
             return rs_kernel.apply_matrix_async(
                 self.matrix[self.data_shards:], data, device=device,
-                out=out)
-        return _Resolved(self._apply(self.matrix[self.data_shards:], data))
+                out=out, lanes=lanes)
+        return _Resolved(self._apply(self.matrix[self.data_shards:],
+                                     data[..., :lanes]))
 
     def encode_all(self, data: np.ndarray) -> np.ndarray:
         """data: [..., D, N] -> all shards [..., D+P, N]."""
@@ -175,10 +179,10 @@ class ReedSolomon:
     def reconstruct_some_async(self, present: Sequence[int],
                                wanted: Sequence[int],
                                shard_data: np.ndarray, device=None,
-                               out=None):
+                               out=None, lanes=None):
         """Pipelined reconstruct_some: returns a handle with .result().
 
-        Same contract as encode_async (`device` pinning and `out`) — on
+        Same contract as encode_async (`device`, `out`, `lanes`) — on
         the jax backend the dispatch is in flight while the caller
         overlaps host IO (the rebuild pipelines in ec/encoder.py and
         ec/fleet.py ride this)."""
@@ -189,8 +193,9 @@ class ReedSolomon:
             from seaweedfs_tpu.ops import rs_kernel
             return rs_kernel.apply_matrix_async(
                 m, shard_data[..., : self.data_shards, :], device=device,
-                out=out)
-        return _Resolved(self._apply(m, shard_data[..., : self.data_shards, :]))
+                out=out, lanes=lanes)
+        return _Resolved(self._apply(
+            m, shard_data[..., : self.data_shards, :lanes]))
 
     def reconstruct(self, shards: list[Optional[np.ndarray]],
                     data_only: bool = False) -> list[np.ndarray]:

@@ -35,7 +35,7 @@ import numpy as np
 from seaweedfs_tpu.ops import gf256
 from seaweedfs_tpu.stats import trace
 from seaweedfs_tpu.stats.metrics import (
-    RsDispatchSecondsHistogram, RsResultBuffersCounter)
+    RsDispatchSecondsHistogram, RsResultBuffersCounter, RsTailSlabsCounter)
 
 _BIT_SHIFTS = tuple(range(8))
 
@@ -48,6 +48,11 @@ _PHASE_HIST = {p: RsDispatchSecondsHistogram.labels(p)
 # before, so the copy pays no page faults) or a fresh array.
 _RESULT_INTO = {state: RsResultBuffersCounter.labels(state)
                 for state in ("lent", "fresh")}
+# How a dispatch's short tail slab got its padding: `in_place` (the
+# caller's array had the room: a slice) or `copied` (into a fresh
+# zeroed array, whose pages every dispatch faults in anew).
+_TAIL_PAD = {pad: RsTailSlabsCounter.labels(pad)
+             for pad in ("in_place", "copied")}
 
 
 def _phase(phase: str, **tags) -> trace.PhaseTimer:
@@ -248,7 +253,8 @@ class PendingApply:
 
 
 def apply_matrix_async(matrix: np.ndarray, shards, device=None,
-                       out: Optional[np.ndarray] = None) -> PendingApply:
+                       out: Optional[np.ndarray] = None,
+                       lanes: Optional[int] = None) -> PendingApply:
     """Dispatch apply_matrix without waiting for the device.
 
     Returns a PendingApply whose .result() blocks. Between submit and
@@ -267,6 +273,13 @@ def apply_matrix_async(matrix: np.ndarray, shards, device=None,
     A caller that keeps `out` between dispatches (ec/fleet._Staging)
     pays those faults once. What cannot take the result raises here,
     not in the thread that fetches it.
+
+    `lanes` says how many lanes of a 2-D input [S, >= lanes] are the
+    dispatch: the map runs over the first `lanes`, the result is
+    [O, lanes], and the lanes after them are room the caller brings
+    for a tail slab's padding (see `_submit_slabs`) — a staging buffer
+    hands itself over up to `placed_lanes(lanes)` and the slab loop
+    copies nothing.
     """
     matrix = np.ascontiguousarray(matrix, dtype=np.uint8)
     m2 = _m2_device(matrix.tobytes(), matrix.shape[0], matrix.shape[1])
@@ -276,6 +289,12 @@ def apply_matrix_async(matrix: np.ndarray, shards, device=None,
     batch_shape = shards.shape[:-2]
     s, n = shards.shape[-2:]
     o = matrix.shape[0]
+    if lanes is not None:
+        if batch_shape or not 0 <= lanes <= n:
+            raise ValueError(
+                f"lanes={lanes} wants a 2-D input [S, >= lanes], not "
+                f"{shards.shape}")
+        n = lanes
     if out is not None:
         _check_lent(out, o, n, batch_shape)
     if n == 0:
@@ -284,10 +303,11 @@ def apply_matrix_async(matrix: np.ndarray, shards, device=None,
         with _phase("stage"):
             flat = np.ascontiguousarray(
                 np.moveaxis(shards.reshape((-1, s, n)), 1, 0)).reshape(s, -1)
+        total = flat.shape[1]
     else:
-        flat = shards
-    parts = _submit_slabs(m2, flat, device=device)
-    return PendingApply(parts, o, flat.shape[1], batch_shape, n, out)
+        flat, total = shards, n
+    parts = _submit_slabs(m2, flat, total, device=device)
+    return PendingApply(parts, o, total, batch_shape, n, out)
 
 
 class PendingVerify:
@@ -315,17 +335,20 @@ class PendingVerify:
 
 
 def verify_stripe_async(matrix: np.ndarray, stripe: np.ndarray,
-                        device=None) -> PendingVerify:
+                        device=None,
+                        lanes: Optional[int] = None) -> PendingVerify:
     """Re-encode and compare on the device, without waiting for it.
 
     `matrix` [P, D] are the code's parity rows, `stripe` [D + P, n] a
     2-D uint8 array (a view will do; nothing is copied before the slab
     slices): rows 0..D-1 the data shards, rows D.. the STORED parity.
-    The slab loop, the widths, a tail slab's zero pad (zero data
-    re-encodes to zero parity: padding never differs) and the placement
-    are `apply_matrix_async`'s; what comes back a slab is its counts,
-    KB where a map's result is rows. `n` is a multiple of VERIFY_BLOCK:
-    the caller lays out its spans on block boundaries."""
+    The slab loop, the widths, a tail slab's padding (its blocks'
+    counts are dropped) and the placement are `apply_matrix_async`'s,
+    and so is `lanes`: the first `lanes` of the stripe are verified,
+    what lies after them is the caller's room for the tail slab. What
+    comes back a slab is its counts, KB where a map's result is rows.
+    `lanes` (`n` without it) is a multiple of VERIFY_BLOCK: the caller
+    lays out its spans on block boundaries."""
     matrix = np.ascontiguousarray(matrix, dtype=np.uint8)
     p, d = matrix.shape
     if not isinstance(stripe, np.ndarray) or stripe.dtype != np.uint8 \
@@ -333,14 +356,19 @@ def verify_stripe_async(matrix: np.ndarray, stripe: np.ndarray,
         raise ValueError(f"a stripe is a uint8 array [{d + p}, n], not "
                          f"{getattr(stripe, 'dtype', type(stripe).__name__)}"
                          f" {getattr(stripe, 'shape', '')}")
-    if stripe.shape[1] % VERIFY_BLOCK:
-        raise ValueError(f"{stripe.shape[1]} lanes are no whole number of "
+    if lanes is None:
+        lanes = stripe.shape[1]
+    if not 0 <= lanes <= stripe.shape[1]:
+        raise ValueError(f"lanes={lanes} of a stripe of {stripe.shape[1]}")
+    if lanes % VERIFY_BLOCK:
+        raise ValueError(f"{lanes} lanes are no whole number of "
                          f"blocks of {VERIFY_BLOCK}")
     m2 = _m2_device(matrix.tobytes(), p, d)
     if device is not None:
         m2 = jax.device_put(m2, device)
     return PendingVerify(
-        _submit_slabs(m2, stripe, device=device, program=_verify_jit), p)
+        _submit_slabs(m2, stripe, lanes, device=device, program=_verify_jit),
+        p)
 
 
 def _check_lent(out, o: int, n: int, batch_shape) -> None:
@@ -360,12 +388,32 @@ def _check_lent(out, o: int, n: int, batch_shape) -> None:
 
 # Dispatch in fixed, power-of-two lane widths. Every distinct shape costs
 # an XLA compile (a second or more each on the chip; util/compile_cache
-# persists them), so we bucket: tails are zero-padded up to the next
-# bucket — harmless, since GF maps send 0 to 0 and the padded columns
-# are simply sliced off.
+# persists them), so we bucket: a tail is padded up to the next bucket —
+# harmless, since a GF map works lane by lane and the padded lanes are
+# simply sliced off.
 _MIN_SLAB = 1 << 16   # 64KB
 _MAX_SLAB = 1 << 22   # 4MB lanes per dispatch (40MB data for S=10);
                       # value not measured on the attached chip
+
+
+def _slabs(lanes: int):
+    """The bucket rule: yield (want, slab) for a dispatch of `lanes` —
+    `want` lanes of it go out as a slab `slab` lanes wide. Whole
+    _MAX_SLABs, then one tail in the narrowest power of two from
+    _MIN_SLAB up that holds it."""
+    while lanes > 0:
+        want = min(lanes, _MAX_SLAB)
+        slab = _MIN_SLAB
+        while slab < want:
+            slab <<= 1
+        yield want, slab
+        lanes -= want
+
+
+def placed_lanes(lanes: int) -> int:
+    """Lanes the slab loop places for a dispatch of `lanes`: a 2-D
+    input at least this wide is sliced and never copied."""
+    return sum(slab for _, slab in _slabs(lanes))
 
 
 @functools.lru_cache(maxsize=1)
@@ -392,26 +440,36 @@ def _lane_sharding():
     return NamedSharding(mesh, PartitionSpec(None, "lanes"))
 
 
-def _submit_slabs(m2: jnp.ndarray, flat: np.ndarray, device=None,
+def _submit_slabs(m2: jnp.ndarray, flat: np.ndarray, lanes: int, device=None,
                   program=_gf_linear_jit):
     """Issue one async dispatch of `program(m2, slab)` per power-of-two
-    slab; no fetches."""
-    s, n = flat.shape
+    slab over the first `lanes` of `flat` [S, >= lanes]; no fetches.
+
+    A tail slab is wider than what it carries. Where `flat` itself
+    reaches the slab's end (a staging buffer's slack, handed over with
+    the dispatch) the slab is a slice like every other, padded by
+    whatever lies there: the map works lane by lane, and every lane
+    past `lanes` is dropped from what comes back — a map's rows are
+    trimmed to `want`, a verify's counts to want's blocks — so stale
+    bytes there reach no result, and nothing is written to zero them.
+    Where it does not (a caller that owns no buffer) the tail is copied
+    into a fresh zeroed array."""
+    s = flat.shape[0]
     sharding = None if device is not None else _lane_sharding()
     parts = []
     pos = 0
-    while pos < n:
-        want = min(n - pos, _MAX_SLAB)
-        slab = _MIN_SLAB
-        while slab < want:
-            slab <<= 1
+    for want, slab in _slabs(lanes):
         on_mesh = sharding is not None and slab % sharding.mesh.size == 0
         with _phase("stage"):
-            chunk = flat[:, pos:pos + want]
+            chunk = flat[:, pos:pos + slab]
             if want < slab:
-                padded = np.zeros((s, slab), dtype=np.uint8)
-                padded[:, :want] = chunk
-                chunk = padded
+                if chunk.shape[1] == slab:
+                    _TAIL_PAD["in_place"].inc()
+                else:
+                    padded = np.zeros((s, slab), dtype=np.uint8)
+                    padded[:, :want] = chunk[:, :want]
+                    chunk = padded
+                    _TAIL_PAD["copied"].inc()
             if device is not None or on_mesh:
                 chunk = np.ascontiguousarray(chunk)
         # `place` is the time the call holds this thread; nothing here

@@ -374,6 +374,16 @@ RsResultBuffersCounter = REGISTRY.counter(
     "results of RS device dispatches by the memory they landed in",
     ("state",))
 
+# A dispatch's short tail slab, padded up to its power-of-two width:
+# `in_place` (the caller's array reached the slab's end: a slice, like
+# every whole slab) or `copied` (rs_kernel._submit_slabs copied the
+# tail into a fresh zeroed array). A dispatch of whole slabs counts
+# nothing.
+RsTailSlabsCounter = REGISTRY.counter(
+    "SeaweedFS_rs_tail_slabs_total",
+    "tail slabs of RS device dispatches by how they were padded",
+    ("pad",))
+
 # Unified mesh scheduler families (parallel/mesh_fleet.py): the
 # pod-scale data plane's bucket stream. `op` is the dispatch kind
 # (encode | verify | rebuild); fallback `reason` is bounded

@@ -641,8 +641,13 @@ def test_alternating_encode_and_rebuild_passes_keep_their_buffers(
     """A server that encodes, rebuilds, encodes, rebuilds: the rebuild's
     width follows its largest shard (1,366 lanes here, an encode's 512),
     so its first pass makes the buffers wider, once. After that neither
-    pass is handed a fresh buffer."""
+    pass is handed a fresh buffer. On the jax backend a buffer reaches
+    to its tail slab's end: 512 lanes are one whole slab of the (here
+    shrunk) narrowest width, 1,366 a tail in a slab of 2,048."""
+    from seaweedfs_tpu.ops import rs_kernel
+
     monkeypatch.setattr(fleet, "SMALL_BLOCK_SIZE", REBUILD_FLOOR)
+    monkeypatch.setattr(rs_kernel, "_MIN_SLAB", 512)
     sizes = [40 * ROW, 40 * ROW]
     counts = []
     for n, kind in enumerate(["encode", "rebuild", "encode", "rebuild"]):
@@ -651,7 +656,9 @@ def test_alternating_encode_and_rebuild_passes_keep_their_buffers(
         counts += _passes(kind, root, monkeypatch, backend, [sizes])
     share = 7
     assert [fresh for fresh, _ in counts] == [share, share, 0, 0]
-    assert _idle_shapes() == [(TOTAL_SHARDS, 11 * REBUILD_FLOOR)] * share
+    assert _idle_shapes() == [
+        (TOTAL_SHARDS, 2048 if backend == "jax" else 11 * REBUILD_FLOOR)
+    ] * share
 
 
 def test_read_span_into_zeroes_past_eof_on_every_use(tmp_path):
@@ -1319,11 +1326,14 @@ def test_verify_dispatches_views_of_reused_staging(tmp_path, monkeypatch):
     """A scrub after an encode of the same width makes no staging
     buffer: every buffer it is handed is one the encode pass filled, and
     what reaches the dispatch layer is a 2-D view of ALL 14 rows of one
-    of them — the stored parity beside the data, no stacked copy, no
-    memory lent for a result."""
+    of them — the stored parity beside the data and the buffer's slack
+    up to the tail slab's end, no stacked copy, no memory lent for a
+    result."""
     from seaweedfs_tpu.ops import rs_kernel
 
     block = rs_kernel.VERIFY_BLOCK
+    room = rs_kernel.placed_lanes(3 * block)
+    assert room == rs_kernel._MIN_SLAB > 3 * block
     monkeypatch.setattr(fleet, "SMALL_BLOCK_SIZE", REBUILD_FLOOR)
     rows = 7 * 3 * block // (3 * SMALL)        # 7 dispatches of 3 spans
     bases = _make_volumes(str(tmp_path), [rows * ROW] * 3, seed=62)
@@ -1332,13 +1342,13 @@ def test_verify_dispatches_views_of_reused_staging(tmp_path, monkeypatch):
                                chunk=3 * block // SMALL * ROW)
     idle = list(fleet._IDLE_STAGING._bufs)
     assert len(idle) == 7 and \
-        _idle_shapes() == [(TOTAL_SHARDS, 3 * block)] * 7
+        _idle_shapes() == [(TOTAL_SHARDS, room)] * 7
     seen = []
     real = rs_kernel.verify_stripe_async
 
-    def recording(matrix, stripe, device=None):
-        seen.append(stripe)
-        return real(matrix, stripe, device=device)
+    def recording(matrix, stripe, device=None, lanes=None):
+        seen.append((stripe, lanes))
+        return real(matrix, stripe, device=device, lanes=lanes)
 
     monkeypatch.setattr(rs_kernel, "verify_stripe_async", recording)
     monkeypatch.setattr(
@@ -1356,8 +1366,9 @@ def test_verify_dispatches_views_of_reused_staging(tmp_path, monkeypatch):
     assert [id(b) for b in fleet._IDLE_STAGING._bufs] == \
         [id(b) for b in idle]
     assert len(seen) == 7
-    for stripe in seen:
-        assert stripe.shape == (TOTAL_SHARDS, per_batch * span)
+    for stripe, lanes in seen:
+        assert lanes == per_batch * span
+        assert stripe.shape == (TOTAL_SHARDS, room)
         assert sum(np.shares_memory(stripe, b) for b in idle) == 1
 
 
@@ -1569,3 +1580,217 @@ def test_device_verify_reads_fourteen_rows_and_lends_nothing(tmp_path,
     assert set(placed) == {TOTAL_SHARDS * rs_kernel._MIN_SLAB}
     assert set(fetched) == {
         2 * 4 * (rs_kernel._MIN_SLAB // rs_kernel.VERIFY_BLOCK) * 4}
+
+
+# --- a dispatch's tail slab is padded where it lies (ISSUE 32) -----------------
+#
+# The jax dispatch layer places whole power-of-two slabs. A pass's buffers
+# reach to the end of the tail slab of its widest dispatch, and a dispatch is
+# handed over with that slack: the slab loop slices, for every slab, and
+# whatever an earlier dispatch left in the slack pads the tail. What that can
+# break: stale bytes there reaching a file or a count; a last piece that
+# takes the slack with it; a buffer too narrow for its tail.
+
+TAIL_SLABS = (4096, 4 * 4096)    # _MIN_SLAB and _MAX_SLAB, shrunk: a slab
+#                                  still holds whole VERIFY_BLOCKs
+TAIL_ROWS = 516                  # a shard of 132,096 B = 3 spans of 44,032:
+TAIL_SPAN = 44_032               # two whole slabs and 11,264 lanes in a
+TAIL_CHUNK = DATA_SHARDS * 45_000   # third of 16,384
+TAIL_ROOM = 3 * TAIL_SLABS[1]
+
+
+@pytest.fixture
+def short_slabs(monkeypatch):
+    from seaweedfs_tpu.ops import rs_kernel
+
+    monkeypatch.setattr(rs_kernel, "_MIN_SLAB", TAIL_SLABS[0])
+    monkeypatch.setattr(rs_kernel, "_MAX_SLAB", TAIL_SLABS[1])
+    monkeypatch.setattr(fleet, "SMALL_BLOCK_SIZE", REBUILD_FLOOR)
+    assert rs_kernel.placed_lanes(TAIL_SPAN) == TAIL_ROOM
+    assert fleet._stacked_spans(TAIL_CHUNK, [TAIL_ROWS * SMALL]) == \
+        (TAIL_SPAN, 1)
+
+
+def _padded():
+    """Tail slabs by how they got their padding: (in_place, copied)."""
+    from seaweedfs_tpu.stats.metrics import RsTailSlabsCounter
+    return np.array([RsTailSlabsCounter.labels(p).value
+                     for p in ("in_place", "copied")])
+
+
+def _make_idle_buffers_stale(room):
+    """Every byte of every idle buffer — the slack too — becomes 0xFF;
+    each reaches as far as a dispatch of the pass before took."""
+    assert fleet._IDLE_STAGING._bufs
+    for buf in fleet._IDLE_STAGING._bufs:
+        assert buf.shape[0] == TOTAL_SHARDS and buf.shape[1] >= room
+        buf[:] = 0xFF
+
+
+@pytest.mark.parametrize("lost", [(3,), (0, 3), (12,), (10, 11, 12, 13)])
+def test_one_volume_rebuild_with_a_tail_slab_copies_nothing(tmp_path,
+                                                            short_slabs, lost):
+    """The scrub's repair: ONE volume, so every dispatch is one span —
+    two whole slabs and a short third. The second rebuild runs in the
+    first one's buffers with 0xFF in every lane: the rebuilt files are
+    the numpy codec's, each tail slab is a slice of its buffer, and no
+    buffer is made."""
+    base, = _encoded(tmp_path, [TAIL_ROWS * ROW - 100], 130)
+    want = {sid: open(shard_file_name(base, sid), "rb").read()
+            for sid in lost}
+    for stale in (False, True):
+        for sid in lost:
+            os.remove(shard_file_name(base, sid))
+        before, fresh = _padded(), _handed("fresh")
+        assert fleet.fleet_rebuild_ec_files(
+            [base], backend="jax", chunk=TAIL_CHUNK) == {base: list(lost)}
+        for sid in lost:
+            with open(shard_file_name(base, sid), "rb") as f:
+                assert f.read() == want[sid], f"shard {sid}, stale={stale}"
+        assert list(_padded() - before) == [3, 0]
+        if stale:
+            assert _handed("fresh") == fresh
+        else:
+            _make_idle_buffers_stale(TAIL_ROOM)
+
+
+VERIFY_TAIL_DAMAGE = {
+    "clean": [],
+    # in the tail slab of the last dispatch, and in its very last lane
+    "parity-in-the-tail": [(12, 3 * TAIL_SPAN - 5000),
+                           (12, TAIL_ROWS * SMALL - 1)],
+    # a data byte: all four parity rows disagree there
+    "data-in-the-tail": [(4, 2 * TAIL_SPAN + 2 * TAIL_SLABS[1] + 1)],
+    "first-whole-slab": [(10, 0), (13, TAIL_SLABS[1] - 1)],
+}
+
+
+@pytest.mark.parametrize("case", sorted(VERIFY_TAIL_DAMAGE))
+def test_one_volume_verify_with_a_tail_slab_copies_nothing(tmp_path,
+                                                           short_slabs, case):
+    """The scrub's re-verify of the repaired volume: every dispatch two
+    whole slabs of all 14 rows and a short third. In buffers full of
+    0xFF the verdict is a plain numpy compare's, byte count for byte
+    count — the slack's blocks reach no count — and nothing is copied."""
+    from seaweedfs_tpu.ops import rs_kernel
+
+    base, = _encoded(tmp_path, [TAIL_ROWS * ROW - 100], 131)
+    width = -(-TAIL_SPAN // rs_kernel.VERIFY_BLOCK) * rs_kernel.VERIFY_BLOCK
+    assert width % TAIL_SLABS[1] and \
+        rs_kernel.placed_lanes(width) == TAIL_ROOM
+    assert fleet.fleet_verify_ec_files(
+        [base], backend="jax", chunk=TAIL_CHUNK)[base].clean
+    _make_idle_buffers_stale(TAIL_ROOM)
+    for sid, offset in VERIFY_TAIL_DAMAGE[case]:
+        _flip(base, sid, offset)
+    want = _plain_verify(base)
+    assert (case == "clean") == (not want["parity_mismatch"])
+    before, fresh = _padded(), _handed("fresh")
+    got = fleet.fleet_verify_ec_files([base], backend="jax",
+                                      chunk=TAIL_CHUNK)[base]
+    assert {f: getattr(got, f) for f in VERIFY_FIELDS} == want
+    assert got.spans == 3 and got.clean == (case == "clean")
+    assert list(_padded() - before) == [3, 0]
+    assert _handed("fresh") == fresh
+
+
+@pytest.mark.parametrize("rows, tails", [
+    # chunk = 172 rows = 44,032 lanes. Two full dispatches, a last one of
+    # 50 rows = 12,800 lanes: one short slab
+    (2 * 172 + 50, 3),
+    # a last dispatch of 64 rows = one whole slab of 16,384: two tails
+    (2 * 172 + 64, 2),
+    # one dispatch of 17 rows = 4,352 lanes in a slab of 8,192
+    (17, 1),
+])
+def test_encode_whose_last_dispatch_has_a_tail_writes_whole_shards(
+        tmp_path, short_slabs, rows, tails):
+    """An encode's parity comes back as the lent rows' filled lanes: the
+    last span's piece ends where the span ends, not where the slab does.
+    Parity files are exactly a shard long and the serial encoder's, also
+    out of buffers that held 0xFF."""
+    chunk = 172 * ROW
+    roomy = 1 << 20                  # small rows only, on the fleet's path
+    for stale in (False, True):
+        root = tmp_path / f"stale{stale}"
+        root.mkdir()
+        bases = _make_volumes(str(root), [rows * ROW - 9], seed=132)
+        twins = _serial_twin(bases)
+        ec.write_ec_files(twins[0], backend="numpy", large_block=roomy,
+                          small_block=SMALL)
+        before, fresh = _padded(), _handed("fresh")
+        fleet.fleet_write_ec_files(bases, backend="jax", large_block=roomy,
+                                   small_block=SMALL, chunk=chunk)
+        _assert_shards_equal(bases, twins)
+        assert {os.path.getsize(shard_file_name(bases[0], sid))
+                for sid in range(TOTAL_SHARDS)} == {rows * SMALL}
+        assert list(_padded() - before) == [tails, 0]
+        if stale:
+            assert _handed("fresh") == fresh
+        else:
+            _make_idle_buffers_stale(min(rows, 172) * SMALL)
+
+
+def test_host_codecs_take_the_lanes_as_they_are(tmp_path, short_slabs):
+    """No slab loop, no slack: a host pass's buffers are as wide as its
+    dispatches (rounded up to a small block) and count no tail slab."""
+    base, = _encoded(tmp_path, [TAIL_ROWS * ROW - 100], 133)
+    os.remove(shard_file_name(base, 3))
+    before = _padded()
+    fleet.fleet_rebuild_ec_files([base], backend="numpy", chunk=TAIL_CHUNK)
+    assert fleet.fleet_verify_ec_files(
+        [base], backend="numpy", chunk=TAIL_CHUNK)[base].clean
+    assert list(_padded() - before) == [0, 0]
+    assert set(_idle_shapes()) == {(TOTAL_SHARDS, TAIL_SPAN)}
+
+
+@pytest.mark.parametrize("lanes", [
+    1, 4096, 4097, 16_384, 16_385, TAIL_SPAN, 3 * 16_384, 3 * 16_384 + 1,
+    5 * 16_384 + 8192, 5 * 16_384 + 8193])
+def test_a_pass_buffers_reach_the_end_of_its_tail_slab(short_slabs, lanes):
+    """The capacity rule: what a jax pass takes of the idle list leaves
+    room for the tail slab of its widest dispatch, whatever its lanes —
+    and for the tail of every narrower dispatch, so a dispatcher never
+    finds its buffer short. A host pass asks for its lanes alone."""
+    from seaweedfs_tpu.ops import rs_kernel
+
+    jax_d = fleet._Dispatcher(ReedSolomon(backend="jax"))
+    host_d = fleet._Dispatcher(ReedSolomon(backend="numpy"))
+    try:
+        room = jax_d.room(lanes)
+        assert room == rs_kernel.placed_lanes(lanes) >= lanes
+        assert room - lanes < max(TAIL_SLABS[0], TAIL_SLABS[1] // 2)
+        assert all(jax_d.room(n) <= room
+                   for n in range(1, lanes, max(1, lanes // 97)))
+        assert host_d.room(lanes) == lanes
+    finally:
+        host_d.close()
+    st = fleet._Staging(room, 2, lambda: None)
+    buf = st.acquire()
+    assert buf.shape == (TOTAL_SHARDS, room)
+    buf[:] = 1                        # filled: it goes to the idle list
+    st.close()
+    (_, capacity), = _idle_shapes()
+    assert room <= capacity < room + REBUILD_FLOOR
+    assert capacity % REBUILD_FLOOR == 0
+
+
+def test_staging_capacity_only_grows(short_slabs):
+    """Passes of any widths in any order: one capacity at a time, the
+    widest room asked for so far rounded up to a small block; a narrower
+    pass borrows what is idle, a wider one replaces it."""
+    rng = np.random.default_rng(134)
+    widest, kept = 0, None
+    for lanes in rng.integers(1, 6 * TAIL_SLABS[1], 40):
+        st = fleet._Staging(int(lanes), 1, lambda: None)
+        buf = st.acquire()
+        assert buf.shape == (TOTAL_SHARDS, lanes)
+        buf[:] = 1
+        st.close()
+        grew = lanes > widest
+        widest = max(widest, -(-int(lanes) // REBUILD_FLOOR) * REBUILD_FLOOR)
+        (_, capacity), = _idle_shapes()
+        assert capacity == widest
+        if not grew:
+            assert fleet._IDLE_STAGING._bufs[0] is kept
+        kept = fleet._IDLE_STAGING._bufs[0]

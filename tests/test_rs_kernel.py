@@ -365,3 +365,190 @@ def test_a_stripe_that_cannot_be_verified_raises_at_the_call(case, stripe,
 
     with pytest.raises(ValueError, match=message):
         rs_kernel.verify_stripe_async(ReedSolomon().matrix[10:], stripe)
+
+
+# --- a tail slab's padding (ISSUE 32) ----------------------------------------
+# A dispatch is placed as whole power-of-two slabs. A caller that brings the
+# room (a staging buffer's slack, `lanes=`) has its tail slab sliced like
+# every other; one that does not has it copied into a fresh zeroed array.
+
+def _tail_counts():
+    from seaweedfs_tpu.stats.metrics import RsTailSlabsCounter
+    return {p: RsTailSlabsCounter.labels(p).value
+            for p in ("in_place", "copied")}
+
+
+@pytest.fixture
+def placed(monkeypatch):
+    """The slab loop as a one-chip host runs it — no lane sharding, so
+    a slab goes to jnp.asarray as the view it is — with every slab it
+    places recorded (uint8; a matrix's bits are int8)."""
+    from seaweedfs_tpu.ops import rs_kernel
+
+    seen = []
+    real = rs_kernel.jnp
+
+    class Recording:
+        def __getattr__(self, name):
+            return getattr(real, name)
+
+        def asarray(self, x):
+            if x.dtype == np.uint8:
+                seen.append(x)
+            return real.asarray(x)
+
+    monkeypatch.setattr(rs_kernel, "_lane_sharding", lambda: None)
+    monkeypatch.setattr(rs_kernel, "jnp", Recording())
+    return seen
+
+
+def test_the_bucket_rule_has_one_statement(monkeypatch):
+    """placed_lanes is the sum of the widths the slab loop places."""
+    from seaweedfs_tpu.ops import rs_kernel
+
+    lo, hi = rs_kernel._MIN_SLAB, rs_kernel._MAX_SLAB
+    assert (lo, hi) == (1 << 16, 1 << 22)
+    for lanes, want in [
+            (0, 0), (1, lo), (lo, lo), (lo + 1, 2 * lo), (hi - 1, hi),
+            (hi, hi), (hi + 1, hi + lo), (3 * hi, 3 * hi),
+            # the cells' dispatches: a one-volume rebuild and re-verify,
+            # the pool's verify, a two-shard rebuild, an encode
+            (12_000_370, 3 * hi), (12_001_280, 3 * hi),
+            (13_107_200, 13_107_200), (12_705_742, 3 * hi + (1 << 17)),
+            (12 << 20, 3 * hi)]:
+        assert rs_kernel.placed_lanes(lanes) == want
+        assert [w for w, _ in rs_kernel._slabs(lanes)] == \
+            [hi] * (lanes // hi) + ([lanes % hi] if lanes % hi else [])
+    monkeypatch.setattr(rs_kernel, "_MAX_SLAB", 4 * lo)
+    assert list(rs_kernel._slabs(9 * lo + 5)) == \
+        [(4 * lo, 4 * lo), (4 * lo, 4 * lo), (lo + 5, 2 * lo)]
+
+
+# (lanes of the dispatch in blocks, lanes the input array has beyond
+# them as a share of the tail slab's slack) -> what is counted
+TAILS = {
+    # 2 slabs of 32 blocks and one of 16: nothing to pad
+    "whole_slabs": (2 * 32 + 16, 0, (0, 0)),
+    # a tail of 17 blocks in a slab of 32, the buffer reaching its end
+    "tail_with_its_slack": (32 + 17, 1.0, (1, 0)),
+    # the same dispatch as a bare [S, n]
+    "bare_tail": (32 + 17, 0, (0, 1)),
+    # room that stops short of the slab's end is no room
+    "slack_too_short": (32 + 17, 0.5, (0, 1)),
+    # one slab, nearly all of it slack
+    "one_block": (1, 1.0, (1, 0)),
+}
+
+
+@pytest.mark.parametrize("program", ["map4", "map1", "verify"])
+@pytest.mark.parametrize("case", sorted(TAILS))
+def test_tail_slab_is_sliced_where_the_input_has_the_room(monkeypatch, placed,
+                                                          case, program):
+    """Whole slabs and a tail that brings its slack are slices of the
+    caller's array, every one; a bare tail is the one copy. The result
+    is the same in all of them, and whatever lies in the slack — here
+    a pattern in every row — reaches none of it."""
+    from seaweedfs_tpu.ops import rs_kernel
+
+    block = rs_kernel.VERIFY_BLOCK
+    monkeypatch.setattr(rs_kernel, "_MAX_SLAB", 2 * rs_kernel._MIN_SLAB)
+    blocks, share, counted = TAILS[case]
+    n = blocks * block
+    slack = rs_kernel.placed_lanes(n) - n
+    rng = np.random.default_rng(110 + blocks)
+    rs = ReedSolomon(backend="numpy")
+    buf = np.full((14, n + int(share * slack)), 0xA5, dtype=np.uint8)
+    buf[:10, :n] = rand_shards(rng, (10, n))
+    buf[10:, :n] = rs.encode(buf[:10, :n])
+    for row, lane in [(3, n - 1), (12, n - block), (12, 5)]:
+        buf[row, lane] ^= 0x5A
+    kept = buf.copy()
+    before = _tail_counts()
+    if program == "verify":
+        got = rs_kernel.verify_stripe_async(rs.matrix[10:], buf,
+                                            lanes=n).result()
+        want = _plain_counts(kept[:, :n], block)
+        assert got[0].shape == got[1].shape == (4, blocks)
+        rows = 14
+    else:
+        matrix = rs.matrix[10:] if program == "map4" else \
+            rs.decode_matrix([1, 2, 3, 4, 5, 6, 7, 8, 9, 10], [0])
+        out = np.full((4, n), 0xEE, dtype=np.uint8)[:len(matrix)]
+        got = rs_kernel.apply_matrix_async(matrix, buf[:10], out=out,
+                                           lanes=n).result()
+        assert got is out
+        got, want = [got], [gf256.gf_linear_numpy(matrix, kept[:10, :n])]
+        rows = 10
+    for g, w in zip(got, want):
+        assert np.array_equal(g, w)
+    assert np.array_equal(buf, kept), "the dispatch layer wrote to its input"
+    after = _tail_counts()
+    assert (after["in_place"] - before["in_place"],
+            after["copied"] - before["copied"]) == counted
+    widths = [slab for _, slab in rs_kernel._slabs(n)]
+    assert [x.shape for x in placed] == [(rows, w) for w in widths]
+    assert [np.shares_memory(x, buf) for x in placed] == \
+        [True] * (len(widths) - counted[1]) + [False] * counted[1]
+
+
+def test_default_lanes_are_the_whole_input_and_a_short_tail_is_copied(placed):
+    """Without `lanes` nothing changes for a caller: the input is the
+    dispatch, stacked inputs too, and a short tail counts `copied`."""
+    from seaweedfs_tpu.ops import rs_kernel
+
+    rng = np.random.default_rng(120)
+    matrix = rand_shards(rng, (4, 10))
+    before = _tail_counts()
+    for data in (rand_shards(rng, (10, 700)), rand_shards(rng, (3, 10, 500))):
+        got = rs_kernel.apply_matrix_async(matrix, data).result()
+        assert np.array_equal(got, gf256.gf_linear_numpy(matrix, data))
+    after = _tail_counts()
+    assert (after["in_place"] - before["in_place"],
+            after["copied"] - before["copied"]) == (0, 2)
+
+
+@pytest.mark.parametrize("case, shape, lanes, message", [
+    ("more_than_there_is", (10, 300), 301, "lanes=301"),
+    ("negative", (10, 300), -1, "lanes=-1"),
+    ("stacked", (2, 10, 150), 100, "2-D"),
+])
+def test_lanes_that_the_input_cannot_have_raise_at_the_call(monkeypatch, case,
+                                                            shape, lanes,
+                                                            message):
+    from seaweedfs_tpu.ops import rs_kernel
+
+    monkeypatch.setattr(
+        rs_kernel, "_submit_slabs",
+        lambda *a, **kw: pytest.fail("dispatched before the check"))
+    rng = np.random.default_rng(121)
+    with pytest.raises(ValueError, match=message):
+        rs_kernel.apply_matrix_async(rand_shards(rng, (4, 10)),
+                                     rand_shards(rng, shape), lanes=lanes)
+    if len(shape) == 2:
+        beyond = 8192 if lanes > 0 else -4096
+        with pytest.raises(ValueError, match=f"lanes={beyond}"):
+            rs_kernel.verify_stripe_async(
+                ReedSolomon().matrix[10:],
+                np.zeros((14, 4096), dtype=np.uint8), lanes=beyond)
+
+
+@pytest.mark.parametrize("op", ["encode", "reconstruct"])
+def test_codec_passes_lanes_through_and_host_backends_map_that_many(rs, op):
+    """encode_async / reconstruct_some_async with lanes=: every backend
+    maps the first `lanes` of a wider input and nothing after them."""
+    rng = np.random.default_rng(122)
+    data = np.full((10, 1300), 0xA5, dtype=np.uint8)
+    data[:, :1000] = rand_shards(rng, (10, 1000))
+    full = np.concatenate(
+        [data, ReedSolomon(backend="numpy").encode(data)])[:, :1000]
+    if op == "encode":
+        got = rs.encode_async(data, lanes=1000).result()
+        want = full[10:]
+    else:
+        present = [1, 2, 4, 5, 6, 7, 8, 9, 10, 11, 12, 13]
+        wide = np.concatenate([data, np.zeros((4, 1300), np.uint8)])
+        wide[:, :1000] = full
+        got = rs.reconstruct_some_async(present, [0, 3], wide[present],
+                                        lanes=1000).result()
+        want = full[[0, 3]]
+    assert got.shape == want.shape and np.array_equal(got, want)

@@ -315,6 +315,55 @@ class TestDaemon:
         assert os.path.exists(shard + ".corrupt")
         assert d.run_pass().corruptions_found == 0
 
+    @pytest.mark.parametrize("sid, back", [
+        (5, 100),         # a data shard, deep in the padding: the probe
+        (0, 1 << 20),     # a data shard's first byte
+        (12, 7777),       # a parity shard
+    ])
+    def test_jax_repair_and_reverify_pad_their_tail_slabs_in_place(
+            self, store, monkeypatch, sid, back):
+        """The repair's two ONE-volume passes, the rebuild of the
+        condemned shard and the re-verify, on the jax backend with
+        dispatches that are two whole slabs and a short third: the tail
+        is a slice of the staging buffer like every other slab. With
+        0xFF in every lane of every idle buffer the shard comes back
+        byte-identical, the verdicts are the files', and the dispatch
+        layer copied no tail."""
+        from seaweedfs_tpu.ops import rs_kernel
+        from seaweedfs_tpu.stats.metrics import RsTailSlabsCounter
+
+        monkeypatch.setattr(rs_kernel, "_MIN_SLAB", 4096)
+        monkeypatch.setattr(rs_kernel, "_MAX_SLAB", 4 * 4096)
+        # 24 spans of 43,691 lanes a 1 MiB shard: 2 * 16,384 + 10,923
+        monkeypatch.setattr(fleet, "default_chunk_for",
+                            lambda backend: 10 * 45_000)
+        monkeypatch.setattr(fleet, "_IDLE_STAGING", fleet._IdleStaging())
+        base = _make_ec(store, 2)
+        shard = encoder.shard_file_name(base, sid)
+        with open(shard, "rb") as f:
+            pristine = f.read()
+        assert len(pristine) == 1 << 20
+        d = ScrubDaemon(store, backend="jax")
+        assert d.run_pass().corruptions_found == 0
+        assert fleet._IDLE_STAGING._bufs
+        for buf in fleet._IDLE_STAGING._bufs:
+            assert buf.shape[1] >= rs_kernel.placed_lanes(43_691) == 49_152
+            buf[:] = 0xFF
+        _flip_byte(shard, len(pristine) - back)
+        pads = {p: RsTailSlabsCounter.labels(p) for p in ("in_place",
+                                                          "copied")}
+        before = {p: c.value for p, c in pads.items()}
+        res = d.run_pass()
+        assert (res.corruptions_found, res.corruptions_repaired,
+                res.unrecoverable) == (1, 1, 0)
+        with open(shard, "rb") as f:
+            assert f.read() == pristine
+        assert os.path.exists(shard + ".corrupt")
+        # the pass's verify, the rebuild and the re-verify: 24 each
+        assert pads["in_place"].value - before["in_place"] == 3 * 24
+        assert pads["copied"].value == before["copied"]
+        assert d.run_pass().corruptions_found == 0
+
     def test_dead_space_probe_with_partial_local_parity(self, store):
         """Only 3 of 4 parity shards local: a dead-space data flip
         mismatches all THREE checked parity streams, and the probe must
