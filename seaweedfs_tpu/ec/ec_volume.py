@@ -116,6 +116,27 @@ class EcVolumeShard:
             self._f.seek(offset)
             return self._f.read(length)
 
+    def read_into(self, offset: int, view: memoryview) -> int:
+        """read_at's bytes placed in `view` (writable, as long as the
+        read) instead of a fresh `bytes`: one positional read into the
+        caller's memory, under read_at's lock, so a close or a swap
+        waits for it. Returns the count, short only at the file's
+        end. A tiered shard, or one swapped or unmounted mid-read,
+        goes through read_at and pays its copy."""
+        if self._remote is None:
+            with self._lock:
+                if self._f is not None:
+                    fd, got = self._f.fileno(), 0
+                    while got < len(view):
+                        n = os.preadv(fd, [view[got:]], offset + got)
+                        if n == 0:
+                            break
+                        got += n
+                    return got
+        data = self.read_at(offset, len(view))
+        view[:len(data)] = data
+        return len(data)
+
     def swap_to_remote(self, storage, key: str, size: int) -> None:
         """Serve from the backend from now on (the tier-upload handle
         swap; the caller deletes the local file afterwards)."""
@@ -267,11 +288,22 @@ class EcVolume:
     def locate_needle(self, needle_id: int, version: int = 3):
         """(offset, size, intervals) for the WHOLE needle record."""
         offset, size = self.find_needle(needle_id)
+        return offset, size, self._record_intervals(offset, size, version)
+
+    def locate_index(self, i: int, version: int = 3):
+        """locate_needle for the i-th .ecx entry: a sweep of the whole
+        index holds the position already and searches for nothing."""
+        size = int(self._sizes[i])
+        if t.size_is_deleted(size):
+            raise NeedleError(f"needle {int(self._keys[i]):x} deleted")
+        offset = int(self._offsets[i])
+        return offset, size, self._record_intervals(offset, size, version)
+
+    def _record_intervals(self, offset: int, size: int, version: int):
         dat_size = DATA_SHARDS * self.shard_size
-        intervals = ec_locate.locate_data(
+        return ec_locate.locate_data(
             self.large_block, self.small_block, dat_size,
             offset, actual_size(size, version))
-        return offset, size, intervals
 
     def read_needle(self, n: Needle, version: int = 3,
                     remote_reader: Optional[Callable] = None,

@@ -165,7 +165,18 @@ def crc32c(data, value: int = 0) -> int:
     # numpy round trip (~2x cheaper per call — it's on the per-needle
     # write path)
     if type(data) is not bytes:
-        data = bytes(memoryview(data))
+        view = memoryview(data)
+        if view.readonly or not view.c_contiguous:
+            data = bytes(view)
+        elif not view.nbytes:
+            return value
+        else:
+            # a writable buffer (bytearray, numpy, a view of either) is
+            # read where it lies: the scrub's sweep checks a 4 MiB
+            # payload inside its read buffer
+            return int(lib.crc32c(
+                value, ctypes.byref(ctypes.c_uint8.from_buffer(view)),
+                view.nbytes))
     if not data:
         return value
     return int(lib.crc32c(

@@ -433,6 +433,21 @@ ScrubPassSecondsHistogram = REGISTRY.histogram(
 ScrubPhaseSecondsHistogram = REGISTRY.histogram(
     "SeaweedFS_scrub_phase_seconds",
     "scrub pass: wall time by phase", ("phase",))
+# The EC needle sweep, needle by needle: `in_place` (every interval
+# read into a worker's reused buffer, the record parsed and CRC'd
+# there, and found clean) or `copied` (everything else — a mismatch,
+# a torn or short record, a tiered shard, a needle over the buffer's
+# cap — goes through read_at + join + Needle.from_bytes, which alone
+# decides corrupt). Steps are observed once a needle on the worker
+# that ran it.
+ScrubNeedlesCounter = REGISTRY.counter(
+    "SeaweedFS_scrub_needles_total",
+    "EC needles swept by how they were checked", ("check",))
+ScrubSweepSecondsHistogram = REGISTRY.histogram(
+    "SeaweedFS_scrub_sweep_seconds",
+    "EC needle sweep: thread-seconds a needle by step (workers run "
+    "side by side, so the sum may exceed the scan_ec phase's wall)",
+    ("step",))
 ScrubScanLagGauge = REGISTRY.gauge(
     "SeaweedFS_scrub_scan_lag_seconds",
     "seconds since the last completed scrub pass")

@@ -98,6 +98,50 @@ def test_masked_crc_known_vector():
     assert masked_crc(b"123456789") == expected
 
 
+@pytest.mark.parametrize("length", [0, 1, 7, 8, 9, (4 << 20) + 3])
+@pytest.mark.parametrize("kind", ["bytearray", "bytearray-odd-start",
+                                  "numpy-slice", "readonly-view"])
+def test_crc32c_over_a_buffer_equals_crc32c_over_its_bytes(length, kind):
+    import numpy as np
+    from seaweedfs_tpu.native import rs_native
+    raw = np.random.default_rng(length).integers(
+        0, 256, length + 16, dtype=np.uint8)
+    view = {"bytearray": lambda: memoryview(bytearray(raw))[:length],
+            "bytearray-odd-start":
+                lambda: memoryview(bytearray(raw))[3:3 + length],
+            "numpy-slice": lambda: raw[5:5 + length],
+            "readonly-view": lambda: memoryview(raw.tobytes())[1:1 + length],
+            }[kind]()
+    want = rs_native.crc32c(bytes(view))
+    assert rs_native.crc32c(view) == want
+    assert rs_native.crc32c(view, 0x1234) == \
+        rs_native.crc32c(bytes(view), 0x1234)
+    assert masked_crc(view) == masked_crc(bytes(view))
+
+
+def test_crc32c_makes_no_copy_of_a_writable_buffer():
+    import tracemalloc
+    from seaweedfs_tpu.native import rs_native
+    buf = bytearray(b"\x07" * ((4 << 20) + 3))
+    view = memoryview(buf)[1:]
+    want = rs_native.crc32c(bytes(view))
+    tracemalloc.start()
+    try:
+        assert rs_native.crc32c(view) == want
+        _, peak = tracemalloc.get_traced_memory()
+        assert peak < 64 << 10, f"{peak} bytes allocated for a view"
+        # a read-only view still goes through bytes(): the copy the
+        # writable branch saves
+        readonly = memoryview(bytes(buf))[1:]
+        tracemalloc.reset_peak()
+        held, _ = tracemalloc.get_traced_memory()
+        assert rs_native.crc32c(readonly) == want
+        _, peak = tracemalloc.get_traced_memory()
+        assert peak - held > 4 << 20
+    finally:
+        tracemalloc.stop()
+
+
 def test_ttl_parse_and_bytes():
     for s, minutes in [("3m", 3), ("4h", 240), ("5d", 7200),
                        ("1w", 10080), ("", 0)]:

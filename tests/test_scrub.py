@@ -12,8 +12,11 @@ from seaweedfs_tpu.ec import encoder, fleet, store_ec
 from seaweedfs_tpu.scrub import (EcDamage, ScrubDaemon, classify_ec_damage,
                                  repair_ec_volume, repair_needle,
                                  scan_ec_volume_needles, scan_volume)
+from seaweedfs_tpu.scrub import scanner
+from seaweedfs_tpu.stats.metrics import ScrubNeedlesCounter
 from seaweedfs_tpu.storage import volume as volume_mod
 from seaweedfs_tpu.storage.needle import (DataCorruptionError, Needle,
+                                          NeedleError, actual_size,
                                           masked_crc)
 from seaweedfs_tpu.storage.store import Store
 
@@ -63,6 +66,145 @@ def _make_ec(store, vid, n=25, size=4096):
     store_ec.mount_ec_shards(store, vid, "", range(14))
     store.delete_volume(vid)
     return base
+
+
+def _make_mixed_ec(store, vid):
+    """An EC volume whose needles span blocks, shards and the row
+    boundary at 10 MiB: needle 1 of one byte, 2-13 of 1 MiB + 1 (every
+    one crosses a 1 MiB block, 10 and 11 lie across the end of the
+    first small-block row), 14-17 of 4 KiB with a name."""
+    store.add_volume(vid)
+    v = store.find_volume(vid)
+    v.write_needle(Needle(id=1, cookie=7, data=b"\x5a"))
+    for i in range(2, 14):
+        v.write_needle(Needle(id=i, cookie=7, data=_blob((1 << 20) + 1)))
+    for i in range(14, 18):
+        v.write_needle(Needle(id=i, cookie=7, data=_blob(4096),
+                              name=b"n%d" % i))
+    base = store_ec.generate_ec_shards(store, vid, backend="native")
+    store_ec.mount_ec_shards(store, vid, "", range(14))
+    store.delete_volume(vid)
+    ecv = store.find_ec_volume(vid)
+    rows = {iv.block_index // 10 for i in (10, 11)
+            for iv in ecv.locate_needle(i)[2]}
+    assert rows == {0, 1}, "no needle spans the row boundary"
+    return base
+
+
+def _placed(ecv, nid):
+    return [iv.to_shard_and_offset(ecv.large_block, ecv.small_block)
+            + (iv.size,) for iv in ecv.locate_needle(nid)[2]]
+
+
+def _flip_in_record(ecv, base, nid, at):
+    """Flip the byte `at` bytes into needle nid's stored record."""
+    for sid, off, ln in _placed(ecv, nid):
+        if at < ln:
+            _flip_byte(encoder.shard_file_name(base, sid), off + at)
+            return sid
+        at -= ln
+    raise AssertionError("offset past the record")
+
+
+def _copied_scan(ecv, version=3):
+    """The sweep as it was before the pool: one needle at a time through
+    scanner.check_copied (read_at + join + Needle.from_bytes)."""
+    res = scanner.EcNeedleScan()
+    for key, size in zip(ecv._keys.tolist(), ecv._sizes.tolist()):
+        if size < 0:
+            continue
+        try:
+            placed = _placed(ecv, key)
+        except NeedleError:
+            continue
+        if any(sid not in ecv.shards for sid, _, _ in placed):
+            res.skipped_remote += 1
+            continue
+        got, bad = scanner.check_copied(ecv, placed, version, None)
+        res.bytes_scanned += got
+        res.needles_verified += 1
+        if bad is not None:
+            res.corrupt.append(key)
+            res.bad_data_shards |= bad
+    return res
+
+
+def _needle_counts():
+    return {c: ScrubNeedlesCounter.labels(c).value
+            for c in ("in_place", "copied")}
+
+
+def _case_flip(nid, at_from_size):
+    """Flip one byte of needle nid, `at_from_size(size)` into its
+    record: one needle goes to the copied path."""
+    def plant(ecv, base, monkeypatch):
+        _flip_in_record(ecv, base, nid,
+                        at_from_size(ecv.find_needle(nid)[1]))
+        return 1
+    return plant
+
+
+def _case_truncated(ecv, base, monkeypatch):
+    with open(encoder.shard_file_name(base, 0), "r+b") as f:
+        f.truncate(64)
+    return sum(1 for nid in ecv._keys.tolist()
+               if any(sid == 0 and off + ln > 64
+                      for sid, off, ln in _placed(ecv, nid)))
+
+
+def _case_tombstoned(ecv, base, monkeypatch):
+    ecv.delete_needle(5)
+    ecv.delete_needle(14)
+    return 0
+
+
+def _case_over_the_cap(ecv, base, monkeypatch):
+    monkeypatch.setattr(scanner, "_BUFFER_CAP", 1 << 20)
+    _flip_in_record(ecv, base, 6, 16 + 4 + 999)
+    return 12   # every needle of 1 MiB + 1, the damaged one among them
+
+
+def _case_shard_missing(ecv, base, monkeypatch):
+    ecv.unmount_shard(3)
+    return 0
+
+
+def _case_remote_shard(ecv, base, monkeypatch):
+    """A tiered shard's intervals come through read_at: every needle
+    with a byte on it is copied, and reads the same."""
+    shard = ecv.shards[2]
+    path = shard.path
+
+    class Backend:
+        def read_range(self, key, offset, length):
+            with open(path, "rb") as f:
+                f.seek(offset)
+                return f.read(length)
+
+    shard.swap_to_remote(Backend(), "k", shard.size)
+    return sum(1 for nid in ecv._keys.tolist()
+               if any(sid == 2 for sid, _, _ in _placed(ecv, nid)))
+
+
+# what is planted -> how many needles must take the copied path
+_SWEEP_CASES = {
+    "clean": lambda ecv, base, monkeypatch: 0,
+    "payload-byte": _case_flip(7, lambda size: 16 + 4 + 70000),
+    "payload-byte-small-needle": _case_flip(15, lambda size: 16 + 4 + 9),
+    "one-byte-needle": _case_flip(1, lambda size: 16 + 4),
+    "header-id": _case_flip(3, lambda size: 4 + 7),
+    "header-size": _case_flip(3, lambda size: 15),
+    "data-size": _case_flip(8, lambda size: 16 + 2),
+    "flags-after-payload": _case_flip(9, lambda size: 16 + 4 + (1 << 20) + 1),
+    "name-size-after-payload": _case_flip(16, lambda size: 16 + 4 + 4096 + 1),
+    "stored-checksum": _case_flip(12, lambda size: 16 + size + 1),
+    "row-boundary-needle": _case_flip(11, lambda size: 16 + size - 50),
+    "truncated-shard": _case_truncated,
+    "tombstoned": _case_tombstoned,
+    "over-the-cap": _case_over_the_cap,
+    "shard-missing": _case_shard_missing,
+    "remote-shard": _case_remote_shard,
+}
 
 
 # -- scanner ------------------------------------------------------------------
@@ -117,6 +259,101 @@ class TestScanner:
         _make_ec(store, 2)
         res = scan_ec_volume_needles(store.find_ec_volume(2))
         assert res.corrupt == [] and res.needles_verified == 25
+
+
+    # -- the EC sweep: in place, several needles in flight ---------------------
+
+    @pytest.mark.parametrize("case", sorted(_SWEEP_CASES))
+    def test_ec_sweep_gives_what_the_copied_path_gives(self, store, case,
+                                                       monkeypatch):
+        """The sweep (in place, workers) and the per-needle path it
+        falls back on (read_at + join + Needle.from_bytes), run over
+        the same damaged volume, give the same EcNeedleScan; `copied`
+        counts exactly the needles that were not clean in place."""
+        base = _make_mixed_ec(store, 3)
+        ecv = store.find_ec_volume(3)
+        want_copied = _SWEEP_CASES[case](ecv, base, monkeypatch)
+        live = sum(1 for z in ecv._sizes if z >= 0)
+        counted = _needle_counts()
+        got = scan_ec_volume_needles(ecv)
+        moved = {c: n - counted[c] for c, n in _needle_counts().items()}
+        want = _copied_scan(ecv)
+        assert got == want
+        assert got.needles_verified + got.skipped_remote == live
+        assert moved == {"in_place": got.needles_verified - want_copied,
+                         "copied": want_copied}
+
+    def test_ec_sweep_under_contention(self, store, monkeypatch):
+        """More workers than cores and a switch interval of 10 us: the
+        verdicts and their order are the copied path's."""
+        import sys
+        base = _make_mixed_ec(store, 3)
+        ecv = store.find_ec_volume(3)
+        for nid in (3, 9, 12):
+            _flip_in_record(ecv, base, nid, 16 + 4 + 100)
+        monkeypatch.setattr(scanner, "SWEEP_WORKERS", 16)
+        monkeypatch.setattr(scanner, "_HANDOVER_BYTES", 0)
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            got = scan_ec_volume_needles(ecv)
+        finally:
+            sys.setswitchinterval(old)
+        assert got.corrupt == [3, 9, 12]
+        assert got == _copied_scan(ecv)
+
+    def test_ec_sweep_buffer_is_allocated_once_a_thread(self, store,
+                                                        monkeypatch):
+        _make_mixed_ec(store, 3)
+        ecv = store.find_ec_volume(3)
+        made = []
+        new = scanner._new_buffer
+        monkeypatch.setattr(scanner, "_new_buffer",
+                            lambda n: made.append(n) or new(n))
+        res = scan_ec_volume_needles(ecv)
+        handed = sum(1 for z in ecv._sizes
+                     if actual_size(int(z)) >= scanner._HANDOVER_BYTES)
+        assert res.needles_verified == len(ecv._keys) and handed == 12
+        # one a worker that got a needle, one for the sweeping thread's
+        # small needles: not one a needle
+        assert 2 <= len(made) <= scanner.SWEEP_WORKERS + 1
+        assert sorted(set(made)) == [scanner._HANDOVER_BYTES,
+                                     actual_size((1 << 20) + 1 + 5)]
+
+    def test_ec_sweep_throttles_once_a_needle_with_its_length(self, store):
+        _make_mixed_ec(store, 3)
+        ecv = store.find_ec_volume(3)
+        ecv.delete_needle(4)
+
+        class Throttler:
+            calls = []
+
+            def maybe_slowdown(self, n):
+                self.calls.append(n)
+
+        res = scan_ec_volume_needles(ecv, throttler=Throttler())
+        assert Throttler.calls == [actual_size(int(z)) for z in ecv._sizes
+                                   if z >= 0]
+        assert sum(Throttler.calls) == res.bytes_scanned
+
+    def test_shard_read_into_equals_read_at(self, store):
+        base = _make_ec(store, 2)
+        ecv = store.find_ec_volume(2)
+        shard = ecv.shards[0]
+        for offset, length in ((0, 4096), (shard.size - 100, 4096),
+                               (shard.size + 8, 16), (77, 1)):
+            buf = bytearray(b"\xaa" * length)
+            want = shard.read_at(offset, length)
+            assert len(want) == max(0, min(length, shard.size - offset))
+            assert shard.read_into(offset, memoryview(buf)) == len(want)
+            assert buf[:len(want)] == want
+            assert buf[len(want):] == b"\xaa" * (length - len(want))
+        ecv.unmount_shard(0)   # closed under a reader that still holds it
+        with pytest.raises(TypeError) as at:
+            shard.read_at(0, 16)
+        with pytest.raises(TypeError) as into:
+            shard.read_into(0, memoryview(bytearray(16)))
+        assert str(into.value) == str(at.value)
 
 
 # -- fleet verify -------------------------------------------------------------

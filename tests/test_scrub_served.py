@@ -25,6 +25,7 @@ from seaweedfs_tpu.ops.rs_code import ReedSolomon
 from seaweedfs_tpu.shell import CommandError, Shell
 from seaweedfs_tpu.stats.metrics import (FleetStagingBuffersCounter,
                                          FleetVerifyBytesCounter,
+                                         ScrubNeedlesCounter,
                                          ScrubPhaseSecondsHistogram)
 from seaweedfs_tpu.stats import trace
 from tests.cluster_util import Cluster
@@ -101,6 +102,11 @@ def _phase_counts():
     return {p: ScrubPhaseSecondsHistogram.labels(p).count for p in PHASES}
 
 
+def _needle_counts():
+    return {c: ScrubNeedlesCounter.labels(c).value
+            for c in ("in_place", "copied")}
+
+
 @pytest.mark.parametrize("case", ["none", "data-shard", "parity-shard",
                                   "dead-space", "two-volumes"])
 def test_scrub_wait_reports_what_was_planted_and_repairs_it(served, case):
@@ -124,6 +130,7 @@ def test_scrub_wait_reports_what_was_planted_and_repairs_it(served, case):
         _plant(paths[vid][sid], offset)
     assert not trace.active()
     phases = _phase_counts()
+    needles = _needle_counts()
     device = FleetVerifyBytesCounter.labels("device").value
     host = FleetVerifyBytesCounter.labels("host").value
 
@@ -148,6 +155,15 @@ def test_scrub_wait_reports_what_was_planted_and_repairs_it(served, case):
     assert moved == {"scan": len(_plain_vids(c)), "scan_ec": len(vids),
                      "verify": 1,
                      "repair": len(planted), "reverify": len(planted)}
+    # the EC sweep declared every needle clean where it was read, but
+    # the one under a sector planted in a data shard's live bytes
+    # (shard 0 here), which the copied path called corrupt
+    swept = {c: n - needles[c] for c, n in _needle_counts().items()}
+    live = sum(c.volume_servers[0].store.find_ec_volume(vid).file_count()
+               for vid in vids)
+    assert swept == {
+        "copied": sum(1 for sid, _ in planted.values() if sid == 0),
+        "in_place": live - swept["copied"]} and swept["copied"] <= 1
     # the stripe verify and the re-verifies compared on the device
     assert FleetVerifyBytesCounter.labels("device").value - device == \
         DATA_SHARDS * sum(len(before[vid][0])
