@@ -66,7 +66,8 @@ from seaweedfs_tpu.resilience import failpoint as _failpoint
 from seaweedfs_tpu.stats import trace
 from seaweedfs_tpu.stats.metrics import (
     FleetDispatchBatchHistogram, FleetDispatchedBytesCounter,
-    FleetMeshFallbacksCounter, FleetReaderQueueGauge,
+    FleetMeshFallbacksCounter, FleetPassPartSecondsHistogram,
+    FleetPassSecondsHistogram, FleetReaderQueueGauge,
     FleetRebuildGroupsCounter, FleetRebuildVolumesCounter,
     FleetRebuiltBytesCounter, FleetStageSecondsHistogram,
     FleetStagingBuffersCounter, FleetVerifyBytesCounter,
@@ -120,6 +121,12 @@ _STAGE_HIST = {s: FleetStageSecondsHistogram.labels(s)
 _WAIT_HIST = {on: FleetWaitSecondsHistogram.labels(on)
               for on in ("reader", "retire_slot", "lane_from_pack",
                          "lane_from_retire", "staging")}
+# A pass's wall and its two ends, by the kind of pass.
+_PASSES = ("encode", "rebuild", "verify")
+_PASS_HIST = {kind: FleetPassSecondsHistogram.labels(kind)
+              for kind in _PASSES}
+_PART_HIST = {(kind, part): FleetPassPartSecondsHistogram.labels(kind, part)
+              for kind in _PASSES for part in ("fill", "drain")}
 _STAGING_HANDED = {state: FleetStagingBuffersCounter.labels(state)
                    for state in ("fresh", "reused")}
 _VERIFIED_BYTES = {where: FleetVerifyBytesCounter.labels(where)
@@ -135,6 +142,12 @@ class _StageTimer(trace.PhaseTimer):
     def __init__(self, stage: str, parent: Optional[int] = None, **tags):
         super().__init__(_STAGE_HIST[stage], "fleet." + stage, parent,
                          **tags)
+
+
+def _pass_part(kind: str, part: str) -> trace.PhaseTimer:
+    """Timer of one end of a pass (span `fleet.pass.<part>`, on the
+    packing thread under the pass's own span)."""
+    return trace.PhaseTimer(_PART_HIST[kind, part], "fleet.pass." + part)
 
 
 def _waiting(on: str) -> trace.PhaseTimer:
@@ -230,17 +243,7 @@ class TaggedPipeline:
             if item is None:
                 return
             if self._exc is not None:
-                # failed: keep draining, write nothing more — but let
-                # the handle release its resources (the mesh scheduler
-                # tracks in-flight buckets per handle)
-                abandon = getattr(item[0], "abandon", None)
-                if abandon is not None:
-                    try:
-                        abandon()
-                    # lint: swallow-ok(first error already latched; abandon is cleanup)
-                    except Exception:
-                        pass
-                continue
+                continue  # failed: keep draining, write nothing more
             handle, tagged, token = item
             try:
                 # the retire stage is where async dispatches actually
@@ -800,21 +803,30 @@ def fleet_write_ec_files(base_names: Sequence[str], backend: str = "auto",
     # a buffer is free again when the retire thread has the dispatch's
     # result (every transfer out of it is over) AND each span's
     # data-shard write and parity write have run on its lane
-    _staged_pass(trace.span("fleet.encode", volumes=len(alive),
-                            backend=backend),
+    _staged_pass("encode", dict(volumes=len(alive), backend=backend),
                  backend, device, encoders, readers, depth,
                  lanes=batch_rows * small_block,
                  per_buffer=batch_rows // span_rows, plan=plan(),
                  flush=flush, refs=lambda batch: 1 + 2 * len(batch.spans))
 
 
-def _staged_pass(root, backend: str, device, encoders: int, readers: int,
-                 depth: int, *, lanes: int, per_buffer: int, plan, flush,
+def _staged_pass(kind: str, tags: dict, backend: str, device,
+                 encoders: int, readers: int, depth: int, *, lanes: int,
+                 per_buffer: int, plan, flush,
                  refs: Callable[[_StagedBatch], int]) -> None:
-    """The loop an encode, a rebuild and a verify pass share, under the
-    span `root`: plan spans into staging buffers of `lanes`, have the reader
-    pool fill them ahead of the device, dispatch a buffer when its last
-    span is read, retire through a TaggedPipeline.
+    """The loop an encode, a rebuild and a verify pass share (`kind`),
+    timed as a whole under the span `fleet.<kind>` with `tags`: plan
+    spans into staging buffers of `lanes`, have the reader pool fill
+    them ahead of the device, dispatch a buffer when its last span is
+    read, retire through a TaggedPipeline.
+
+    The pass's timer opens before its threads and buffers are made and
+    closes after they are gone, so that what a pass costs to set up and
+    tear down is inside its wall. Two parts of it are timed on the
+    packing thread: `fill`, up to the first flush (nothing downstream
+    of the readers has work yet), and `drain`, from the last flush's
+    return (upstream has nothing left). What lies between is the steady
+    part, where the busiest stage sets the pace.
 
     `plan` yields (vol, width, n, read) in submission order: the span
     takes `width` lanes of a buffer, `n` of them go out to the volume's
@@ -825,76 +837,83 @@ def _staged_pass(root, backend: str, device, encoders: int, readers: int,
     batch's dispatch and queues its writes; `refs(batch)` is how many
     times what it queues will call `release`, the last of which frees
     the buffer."""
-    dispatcher = _Dispatcher(ReedSolomon(backend=backend), device=device,
-                             encoders=encoders)
-    # lint: thread-ok(per-pass reader pool; work items are explicit, no ambient request state)
-    pool = ThreadPoolExecutor(max_workers=max(1, readers),
-                              thread_name_prefix="fleet-read")
-    pipe = TaggedPipeline(depth=depth)
-    prefetch = max(readers, 2 * per_buffer)
-    # The pass's share of staging buffers is what the pipeline holds at
-    # once: upstream of the dispatch the prefetched spans' buffers (one
-    # more when they straddle), downstream `depth` queued dispatches,
-    # the one in the retire thread's hand, whose result is being copied
-    # into its last rows, and the one before it, whose result the
-    # writer lanes are reading (lanes that fall further behind than one
-    # dispatch hold the pass up here, as fleet.wait.staging, where it
-    # would next wait for a lane). Upstream never needs them all, so a
-    # pass out of buffers always has some coming back.
-    # A buffer is as wide as the dispatch layer takes for a dispatch of
-    # `lanes`; the plan fills `lanes` of it.
-    staging = _Staging(dispatcher.room(lanes),
-                       -(-prefetch // per_buffer) + 1 + depth + 1 + 1,
-                       pipe._raise_pending)
-    inflight: deque = deque()
-    filling: Optional[_StagedBatch] = None
-    root.__enter__()
-    token = root.token()
+    with (trace.PhaseTimer(_PASS_HIST[kind], "fleet." + kind, **tags) as root,
+          contextlib.ExitStack() as filling_part):
+        # closed by the first flush, or by a pass that never got there
+        filling_part.enter_context(_pass_part(kind, "fill"))
+        dispatcher = _Dispatcher(ReedSolomon(backend=backend), device=device,
+                                 encoders=encoders)
+        # lint: thread-ok(per-pass reader pool; work items are explicit, no ambient request state)
+        pool = ThreadPoolExecutor(max_workers=max(1, readers),
+                                  thread_name_prefix="fleet-read")
+        pipe = TaggedPipeline(depth=depth)
+        prefetch = max(readers, 2 * per_buffer)
+        # The pass's share of staging buffers is what the pipeline holds
+        # at once: upstream of the dispatch the prefetched spans' buffers
+        # (one more when they straddle), downstream `depth` queued
+        # dispatches, the one in the retire thread's hand, whose result
+        # is being copied into its last rows, and the one before it,
+        # whose result the writer lanes are reading (lanes that fall
+        # further behind than one dispatch hold the pass up here, as
+        # fleet.wait.staging, where it would next wait for a lane).
+        # Upstream never needs them all, so a pass out of buffers always
+        # has some coming back.
+        # A buffer is as wide as the dispatch layer takes for a dispatch
+        # of `lanes`; the plan fills `lanes` of it.
+        staging = _Staging(dispatcher.room(lanes),
+                           -(-prefetch // per_buffer) + 1 + depth + 1 + 1,
+                           pipe._raise_pending)
+        inflight: deque = deque()
+        filling: Optional[_StagedBatch] = None
+        token = root.token()
 
-    def fill() -> None:
-        nonlocal filling
-        while len(inflight) < prefetch:
-            nxt = next(plan, None)
-            if nxt is None:
-                break
-            v, width, n, read = nxt
-            if filling is None or filling.used + width > lanes:
-                filling = _StagedBatch(staging.acquire())
-            off = filling.used
-            filling.used += width
-            filling.spans.append((v, off, n))
-            inflight.append((filling, pool.submit(
-                _read_staged, read, v.base, filling.buf, off, token)))
-            # inc/dec deltas so concurrent schedulers SUM on the
-            # shared gauge instead of overwriting each other's depth
-            FleetReaderQueueGauge.inc()
+        def fill() -> None:
+            nonlocal filling
+            while len(inflight) < prefetch:
+                nxt = next(plan, None)
+                if nxt is None:
+                    break
+                v, width, n, read = nxt
+                if filling is None or filling.used + width > lanes:
+                    filling = _StagedBatch(staging.acquire())
+                off = filling.used
+                filling.used += width
+                filling.spans.append((v, off, n))
+                inflight.append((filling, pool.submit(
+                    _read_staged, read, v.base, filling.buf, off, token)))
+                # inc/dec deltas so concurrent schedulers SUM on the
+                # shared gauge instead of overwriting each other's depth
+                FleetReaderQueueGauge.inc()
 
-    try:
-        fill()
-        while inflight:
-            batch, fut = inflight.popleft()
-            FleetReaderQueueGauge.dec()
-            with _waiting("reader"):
-                fut.result()
-            fill()
-            # spans are planned in order, so a batch is complete when
-            # the next span read belongs to another (or none is left)
-            if not inflight or inflight[0][0] is not batch:
-                batch.refs = refs(batch)
-                flush(batch, dispatcher, pipe,
-                      functools.partial(staging.unref, batch))
-                FleetDispatchBatchHistogram.observe(len(batch.spans))
-                FleetDispatchedBytesCounter.inc(
-                    float(DATA_SHARDS * batch.used))
-    finally:
-        FleetReaderQueueGauge.dec(len(inflight))  # error path leftovers
-        pool.shutdown(wait=True)
         try:
-            pipe.drain()  # may re-raise the latched pipeline error
+            fill()
+            while inflight:
+                batch, fut = inflight.popleft()
+                FleetReaderQueueGauge.dec()
+                with _waiting("reader"):
+                    fut.result()
+                fill()
+                # spans are planned in order, so a batch is complete when
+                # the next span read belongs to another (or none is left)
+                if not inflight or inflight[0][0] is not batch:
+                    filling_part.close()
+                    batch.refs = refs(batch)
+                    flush(batch, dispatcher, pipe,
+                          functools.partial(staging.unref, batch))
+                    FleetDispatchBatchHistogram.observe(len(batch.spans))
+                    FleetDispatchedBytesCounter.inc(
+                        float(DATA_SHARDS * batch.used))
         finally:
-            dispatcher.close()
-            staging.close()
-            root.__exit__(None, None, None)
+            filling_part.close()
+            with _pass_part(kind, "drain"):
+                # error path leftovers
+                FleetReaderQueueGauge.dec(len(inflight))
+                pool.shutdown(wait=True)
+                try:
+                    pipe.drain()  # may re-raise the latched pipeline error
+                finally:
+                    dispatcher.close()
+                    staging.close()
 
 
 # --- fleet rebuild -----------------------------------------------------------
@@ -1068,9 +1087,9 @@ def _fleet_rebuild_group(present: List[int], missing: List[int],
 
     # a buffer is free again when the retire thread has the dispatch's
     # result AND each span's rebuilt shards are written out of it
-    _staged_pass(trace.span("fleet.rebuild", volumes=len(members),
-                            backend=backend, groups=groups, present=present,
-                            missing=missing),
+    _staged_pass("rebuild", dict(volumes=len(members), backend=backend,
+                                 groups=groups, present=present,
+                                 missing=missing),
                  backend, device, encoders, readers, depth,
                  lanes=per_batch * span, per_buffer=per_batch, plan=plan(),
                  flush=flush, refs=lambda batch: 1 + len(batch.spans))
@@ -1265,8 +1284,7 @@ def fleet_verify_ec_files(base_names: Sequence[str], backend: str = "auto",
         # a verify's buffer is free once its dispatch's input has been
         # read (the retire thread has the counts, or every host codec's
         # parity): what the lanes then read is not in it
-        _staged_pass(trace.span("fleet.verify", volumes=len(vols),
-                                backend=backend),
+        _staged_pass("verify", dict(volumes=len(vols), backend=backend),
                      backend, device, encoders, readers, depth,
                      lanes=per_batch * width, per_buffer=per_batch,
                      plan=plan(), flush=flush, refs=lambda batch: 1)

@@ -19,8 +19,20 @@ from seaweedfs_tpu.ec.ec_volume import EcVolume, EcShardNotFound
 from seaweedfs_tpu.ec.shard_bits import TOTAL_SHARDS
 from seaweedfs_tpu.ops.rs_code import ReedSolomon
 from seaweedfs_tpu.stats import trace
+from seaweedfs_tpu.stats.metrics import StoreEcSecondsHistogram
 from seaweedfs_tpu.storage.needle import Needle, NeedleError
 from seaweedfs_tpu.storage.store import Store
+
+# An EC call's wall by step; children resolved once at import
+# (labels() takes a lock per call).
+_STEP_HIST = {step: StoreEcSecondsHistogram.labels(step)
+              for step in ("freeze", "generate", "generate_batch",
+                           "write_ecx", "locate", "rebuild_batch")}
+
+
+def _step(step: str, **tags) -> trace.PhaseTimer:
+    """Timer of one step of a store EC call (span `store_ec.<step>`)."""
+    return trace.PhaseTimer(_STEP_HIST[step], "store_ec." + step, **tags)
 
 
 def _base_name(directory: str, collection: str, vid: int) -> str:
@@ -65,10 +77,11 @@ def generate_ec_shards(store: Store, vid: int, backend: str = "auto") -> str:
     v = store.find_volume(vid)
     if v is None:
         raise NeedleError(f"volume {vid} not found for ec encode")
-    v.read_only = True
-    v.sync()
+    with _step("freeze", volumes=1):
+        v.read_only = True
+        v.sync()
     base = v.file_name()
-    with trace.span("store_ec.generate", vid=vid):
+    with _step("generate", vid=vid):
         encoder.write_ec_files(base, backend=backend)
         encoder.write_sorted_file_from_idx(base)
     return base
@@ -97,11 +110,12 @@ def generate_ec_shards_batch(store: Store, vids: Sequence[int],
             raise NeedleError(f"volume {vid} not found for ec encode")
         vols.append((vid, v))
     bases: Dict[int, str] = {}
-    for vid, v in vols:
-        v.read_only = True
-        v.sync()
-        bases[vid] = v.file_name()
-    with trace.span("store_ec.generate_batch", volumes=len(bases)):
+    with _step("freeze", volumes=len(vols)):
+        for vid, v in vols:
+            v.read_only = True
+            v.sync()
+            bases[vid] = v.file_name()
+    with _step("generate_batch", volumes=len(bases)):
         mesh_fleet = fleet.mesh_fleet_or_none() \
             if mesh_cfg is not None else None
         if mesh_fleet is not None:
@@ -110,7 +124,7 @@ def generate_ec_shards_batch(store: Store, vids: Sequence[int],
         else:
             fleet.fleet_write_ec_files(list(bases.values()),
                                        backend=backend)
-        with trace.span("store_ec.write_ecx"):
+        with _step("write_ecx"):
             for base in bases.values():
                 encoder.write_sorted_file_from_idx(base)
     return bases
@@ -127,12 +141,14 @@ def rebuild_ec_shards_batch(store: Store, vids: Sequence[int],
     per volume (the serial reference). Returns {vid: rebuilt shard
     ids}."""
     bases: Dict[int, str] = {}
-    for vid in vids:
-        base = _find_ec_base(store, vid, collection)
-        if base is None:
-            raise EcShardNotFound(f"no local ec files for volume {vid}")
-        bases[vid] = base
-    with trace.span("store_ec.rebuild_batch", volumes=len(bases)):
+    with _step("locate", volumes=len(vids)):
+        for vid in vids:
+            base = _find_ec_base(store, vid, collection)
+            if base is None:
+                raise EcShardNotFound(
+                    f"no local ec files for volume {vid}")
+            bases[vid] = base
+    with _step("rebuild_batch", volumes=len(bases)):
         rebuilt = fleet.fleet_rebuild_ec_files(list(bases.values()),
                                                backend=backend)
     return {vid: rebuilt[base] for vid, base in bases.items()}

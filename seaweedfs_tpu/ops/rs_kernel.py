@@ -28,6 +28,12 @@ import functools
 import threading
 from typing import Optional
 
+try:  # POSIX only, and RUSAGE_THREAD Linux only
+    import resource
+    _RUSAGE_THREAD = getattr(resource, "RUSAGE_THREAD", None)
+except ImportError:
+    _RUSAGE_THREAD = None
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -35,7 +41,8 @@ import numpy as np
 from seaweedfs_tpu.ops import gf256
 from seaweedfs_tpu.stats import trace
 from seaweedfs_tpu.stats.metrics import (
-    RsDispatchSecondsHistogram, RsResultBuffersCounter, RsTailSlabsCounter)
+    RsDispatchMinorFaultsCounter, RsDispatchSecondsHistogram,
+    RsResultBuffersCounter, RsTailSlabsCounter)
 
 _BIT_SHIFTS = tuple(range(8))
 
@@ -55,10 +62,43 @@ _TAIL_PAD = {pad: RsTailSlabsCounter.labels(pad)
              for pad in ("in_place", "copied")}
 
 
+# The phases that touch a slab's memory also count the calling
+# thread's minor page faults (where the platform tells a thread's from
+# the process's): fresh pages zeroed by the kernel inside a phase show
+# here, faults on the runtime's own threads do not.
+_PHASE_FAULTS = {p: RsDispatchMinorFaultsCounter.labels(p)
+                 for p in ("place", "enqueue", "fetch", "unstage")} \
+    if _RUSAGE_THREAD is not None else {}
+
+
+class _FaultCountingTimer(trace.PhaseTimer):
+    """A dispatch phase's timer that also adds the calling thread's
+    minor page faults over the phase to the phase's counter; the two
+    reads lie outside the timed interval."""
+
+    __slots__ = ("_faults", "_minflt0")
+
+    def __init__(self, phase: str, **tags):
+        super().__init__(_PHASE_HIST[phase], "rs." + phase, **tags)
+        self._faults = _PHASE_FAULTS[phase]
+
+    def __enter__(self) -> "_FaultCountingTimer":
+        self._minflt0 = resource.getrusage(_RUSAGE_THREAD).ru_minflt
+        return super().__enter__()
+
+    def __exit__(self, *exc) -> bool:
+        suppress = super().__exit__(*exc)
+        self._faults.inc(float(
+            resource.getrusage(_RUSAGE_THREAD).ru_minflt - self._minflt0))
+        return suppress
+
+
 def _phase(phase: str, **tags) -> trace.PhaseTimer:
     """Timer of one dispatch phase; its span `rs.<phase>` nests under
     whatever span the calling thread has open (fleet.dispatch,
     fleet.retire, reads.decode, ...)."""
+    if phase in _PHASE_FAULTS:
+        return _FaultCountingTimer(phase, **tags)
     return trace.PhaseTimer(_PHASE_HIST[phase], "rs." + phase, **tags)
 
 # Where dispatched input bytes were placed: (platform, device id) ->

@@ -66,8 +66,7 @@ from seaweedfs_tpu.ops.rs_code import ReedSolomon, DATA_SHARDS, TOTAL_SHARDS
 from seaweedfs_tpu.resilience import deadline as deadline_mod
 from seaweedfs_tpu.stats import trace
 from seaweedfs_tpu.stats.metrics import (
-    FleetMeshBucketsCounter, FleetMeshFallbacksCounter,
-    FleetMeshInflightGauge)
+    FleetMeshBucketsCounter, FleetMeshFallbacksCounter)
 from seaweedfs_tpu.util import wlog
 
 log = wlog.logger("mesh")
@@ -378,31 +377,14 @@ class _SliceHandle:
     def __init__(self, raw, n_live: int):
         self._raw = raw
         self._n = n_live
-        self._retired = False
-
-    def _retire_once(self) -> None:
-        # result() and abandon() are both called only by the single
-        # retire thread, exactly once per handle — the flag guards the
-        # gauge against a double dec if that invariant ever slips
-        if not self._retired:
-            self._retired = True
-            FleetMeshInflightGauge.dec()
-
-    def abandon(self) -> None:
-        """Error drain: the retire loop skips result() after a latched
-        failure; the bucket still leaves the in-flight gauge."""
-        self._retire_once()
 
     def result(self) -> List:
-        try:
-            if isinstance(self._raw, tuple):  # chained: (counts, firsts)
-                parts = [np.asarray(o) for o in self._raw]
-                return [tuple(p[i] for p in parts)
-                        for i in range(self._n)]
-            out = np.asarray(self._raw)
-            return [out[i] for i in range(self._n)]
-        finally:
-            self._retire_once()
+        if isinstance(self._raw, tuple):  # chained: (counts, firsts)
+            parts = [np.asarray(o) for o in self._raw]
+            return [tuple(p[i] for p in parts)
+                    for i in range(self._n)]
+        out = np.asarray(self._raw)
+        return [out[i] for i in range(self._n)]
 
 
 class _JaxDispatch:
@@ -528,18 +510,13 @@ class _MeshRun:
         st.slots += bucket.shape[0]
         st.bytes_in += live_bytes
         self._buckets_counter.inc()
-        FleetMeshInflightGauge.inc()
         try:
             self._pipe.submit(handle, tagged, timeout_s=timeout_s)
         except queue.Full:
             self._abandoned = True
-            handle.abandon()  # never entered the pipe
             raise MeshDispatchTimeout(
                 f"mesh {st.op}: no bucket retired within "
                 f"{self._timeout_s}s ({st.buckets} dispatched)")
-        except BaseException:
-            handle.abandon()  # latched pipeline error: never retires
-            raise
 
     def write(self, tag: int, fn: Callable[[], None]) -> None:
         """Data-shard write on `tag`'s lane, stall-bounded like

@@ -318,6 +318,33 @@ FleetStageSecondsHistogram = REGISTRY.histogram(
 FleetWaitSecondsHistogram = REGISTRY.histogram(
     "SeaweedFS_fleet_wait_seconds",
     "fleet scheduler: time one thread blocked on another", ("on",))
+# One pass of the scheduler (ec/fleet._staged_pass) as a whole: the wall
+# its stage and wait seconds divide by. `pass` is encode | rebuild |
+# verify. A pass's parts: `fill` (the timer's start to the first
+# dispatch: threads and buffers set up, the readers fill the first
+# buffer, nothing downstream has work yet) and `drain` (the last
+# dispatch handed over to the timer's end: upstream has nothing left);
+# what lies between them is the steady part.
+_PASS_BUCKETS = (0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0,
+                 60.0, 300.0)
+FleetPassSecondsHistogram = REGISTRY.histogram(
+    "SeaweedFS_fleet_pass_seconds",
+    "fleet scheduler: wall time of one pass", ("pass",),
+    buckets=_PASS_BUCKETS)
+FleetPassPartSecondsHistogram = REGISTRY.histogram(
+    "SeaweedFS_fleet_pass_part_seconds",
+    "fleet scheduler: a pass's wall before its first dispatch (fill) "
+    "and after its last (drain)", ("pass", "part"),
+    buckets=_PASS_BUCKETS)
+# The store's EC calls (ec/store_ec.py) by step: freeze (read-only +
+# sync of every volume of a generate), generate / generate_batch (the
+# encode and, inside it, write_ecx: the sorted index), locate (finding
+# a rebuild's local EC files), rebuild_batch. generate_batch and
+# rebuild_batch hold the scheduler's pass; the others lie outside it.
+StoreEcSecondsHistogram = REGISTRY.histogram(
+    "SeaweedFS_store_ec_seconds",
+    "volume server store: wall time of an EC call by step", ("step",),
+    buckets=_PASS_BUCKETS)
 # Staging buffers handed to the encode scheduler's readers: `state` is
 # fresh (never written before: its first fill, and the first result copied
 # into its last rows, pay the page faults) or reused (touched by an earlier
@@ -366,6 +393,15 @@ FleetWriterBacklogGauge = REGISTRY.gauge(
 RsDispatchSecondsHistogram = REGISTRY.histogram(
     "SeaweedFS_rs_dispatch_seconds",
     "RS device dispatch: host-side time by phase", ("phase",))
+# Minor page faults of the CALLING thread inside a dispatch phase
+# (getrusage(RUSAGE_THREAD) around it): 4,096 a 16 MiB slab says the
+# phase is the kernel zeroing fresh pages on this thread, near 0 that
+# whatever faults there are fall on the runtime's own threads. No
+# children where the platform has no RUSAGE_THREAD.
+RsDispatchMinorFaultsCounter = REGISTRY.counter(
+    "SeaweedFS_rs_dispatch_minor_faults_total",
+    "RS device dispatch: minor page faults of the calling thread by "
+    "phase", ("phase",))
 # Where PendingApply.result() put a dispatch's result: `lent` (memory
 # the caller handed over with out=, touched before) or `fresh` (an
 # np.empty of its own: the copy out pays the page faults).
@@ -391,9 +427,6 @@ RsTailSlabsCounter = REGISTRY.counter(
 FleetMeshBucketsCounter = REGISTRY.counter(
     "SeaweedFS_fleet_mesh_buckets_total",
     "fixed-shape sharded buckets dispatched over the mesh", ("op",))
-FleetMeshInflightGauge = REGISTRY.gauge(
-    "SeaweedFS_fleet_mesh_inflight_buckets",
-    "mesh buckets uploaded/computing, not yet retired")
 FleetMeshFallbacksCounter = REGISTRY.counter(
     "SeaweedFS_fleet_mesh_fallbacks_total",
     "pod passes demoted to the per-device fleet schedulers",
@@ -720,6 +753,10 @@ ProcessThreadsGauge = REGISTRY.gauge(
 ProcessGcCollectionsGauge = REGISTRY.gauge(
     "SeaweedFS_process_gc_collections",
     "cumulative garbage collections across all generations")
+ProcessMinorFaultsGauge = REGISTRY.gauge(
+    "SeaweedFS_process_minor_faults",
+    "cumulative minor page faults of this process, all threads "
+    "(262,144 a GiB of fresh memory touched)")
 
 
 def _rss_bytes() -> float:
@@ -744,11 +781,20 @@ def _gc_collections() -> float:
     return float(sum(s.get("collections", 0) for s in gc.get_stats()))
 
 
+def _minor_faults() -> float:
+    try:
+        import resource
+    except ImportError:  # not a POSIX platform: nothing to read
+        return 0.0
+    return float(resource.getrusage(resource.RUSAGE_SELF).ru_minflt)
+
+
 def _register_process_metrics() -> None:
     ProcessRSSGauge.set_function(_rss_bytes)
     ProcessFdsGauge.set_function(_open_fds)
     ProcessThreadsGauge.set_function(lambda: float(threading.active_count()))
     ProcessGcCollectionsGauge.set_function(_gc_collections)
+    ProcessMinorFaultsGauge.set_function(_minor_faults)
 
 
 _register_process_metrics()
