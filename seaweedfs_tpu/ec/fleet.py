@@ -1128,7 +1128,10 @@ def fleet_verify_ec_files(base_names: Sequence[str], backend: str = "auto",
                           depth: int = FLEET_DEPTH,
                           encoders: int = FLEET_ENCODERS,
                           device=None,
-                          throttler=None) -> Dict[str, "VerifyResult"]:
+                          throttler=None,
+                          on_span: Optional[Callable[
+                              [str, int, int, np.ndarray], None]] = None
+                          ) -> Dict[str, "VerifyResult"]:
     """Verify EC stripe consistency for MANY volumes in one fused pass.
 
     The scrub scanner's compute path, on the encode and rebuild passes'
@@ -1144,7 +1147,14 @@ def fleet_verify_ec_files(base_names: Sequence[str], backend: str = "auto",
     corrupt DATA shard surfaces as all four parity shards disagreeing at
     the same offsets — scrub/planner.py). `throttler`
     (util.throttler.Throttler) paces the read side so a background
-    scrub stays inside its IO budget.
+    scrub stays inside its IO budget. `on_span(base, offset, valid,
+    rows)`, when given, is called on the volume's writer lane after each
+    span's compare, in offset order: rows [10, valid] are the span's
+    data-shard bytes at shard offsets [offset, offset + valid) as the
+    readers put them in the staging buffer (the scrub checks its
+    needles there instead of reading them again); the buffer is held
+    until every span's call has returned, as an encode's is for its
+    writes. Without it the buffer is free once the dispatch has read it.
     """
     if chunk is None:
         chunk = default_chunk_for(backend)
@@ -1260,6 +1270,14 @@ def fleet_verify_ec_files(base_names: Sequence[str], backend: str = "auto",
                       int(diff[0]) if len(diff) else len(stored))
             verified(v, valid)
 
+    check = counted if on_device else compare
+
+    def handed(v: _VolState, offset: int, rows: np.ndarray, out) -> None:
+        """On v's writer lane: the span's compare, then its data rows
+        to on_span."""
+        check(v, offset, out)
+        on_span(v.base, offset, rows.shape[1], rows)
+
     # batches are flushed in the plan's order: a volume's spans in turn
     offsets = [itertools.count(0, span) for _ in vols]
 
@@ -1270,10 +1288,16 @@ def fleet_verify_ec_files(base_names: Sequence[str], backend: str = "auto",
             handle = dispatcher.verify_lanes(batch.buf, cuts, release) \
                 if on_device else \
                 dispatcher.encode_lanes(batch.buf, cuts, release)
+        if on_span is None:
+            pipe.submit(handle, [
+                (v.tag, functools.partial(check, v, next(offsets[v.tag])))
+                for v, _, _ in batch.spans])
+            return
         pipe.submit(handle, [
-            (v.tag, functools.partial(counted if on_device else compare,
-                                      v, next(offsets[v.tag])))
-            for v, _, _ in batch.spans])
+            (v.tag, _then_release(functools.partial(
+                handed, v, next(offsets[v.tag]),
+                batch.buf[:DATA_SHARDS, off:off + valid]), release))
+            for v, off, valid in batch.spans])
 
     with contextlib.ExitStack() as opened:
         if not on_device:
@@ -1283,9 +1307,12 @@ def fleet_verify_ec_files(base_names: Sequence[str], backend: str = "auto",
                         open(shard_file_name(v.base, sid), "rb"))
         # a verify's buffer is free once its dispatch's input has been
         # read (the retire thread has the counts, or every host codec's
-        # parity): what the lanes then read is not in it
+        # parity): what the lanes then read is not in it — unless each
+        # span's data rows go to on_span there, after the compare
         _staged_pass("verify", dict(volumes=len(vols), backend=backend),
                      backend, device, encoders, readers, depth,
                      lanes=per_batch * width, per_buffer=per_batch,
-                     plan=plan(), flush=flush, refs=lambda batch: 1)
+                     plan=plan(), flush=flush,
+                     refs=(lambda batch: 1) if on_span is None
+                     else (lambda batch: 1 + len(batch.spans)))
     return results

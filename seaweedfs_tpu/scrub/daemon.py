@@ -7,14 +7,20 @@ mounted volume and EC volume:
 
   1. needle sweep per normal volume (scanner.scan_volume), corrupt
      needles re-fetched from replicas (planner.repair_needle);
-  2. needle sweep per EC volume over local shards, localizing bad
-     data shards by exclusion;
-  3. ONE fused stripe verify across ALL the server's EC volumes
+  2. ONE fused stripe verify across ALL the server's EC volumes
      (fleet_verify_ec_files), on the encode and rebuild passes' loop
      and staging buffers. On the jax backend the stored parity goes to
      the device beside the data shards, [14, lanes] a dispatch, and
      counts come back, not parity; host codecs compare on the writer
-     lanes;
+     lanes. The EC needle sweep rides it: each volume's .ecx is walked
+     before it (scanner.StagedSweep) and every live needle is checked
+     in the data-shard bytes the verify has staged, on the volume's
+     writer lane, so a pass reads its data shards once;
+  3. the rest of the EC needle sweep: the copied path for a needle the
+     staged check did not find clean, and a read from local shards of
+     every needle it could not see (a volume the verify declined, a
+     tiered shard, a needle over more than two spans, the mesh path),
+     localizing bad data shards by exclusion;
   4. per damaged EC volume: classify -> quarantine .corrupt ->
      fleet rebuild -> re-verify (a data repair un-contaminates the
      parity evidence; round two condemns genuinely bad parity).
@@ -395,23 +401,15 @@ class ScrubDaemon:
                 if only is None or vid in only]
         if not ecvs:
             return
-        damages: Dict[int, planner.EcDamage] = {}
+        # each volume's .ecx walked once, at the pass's start: its
+        # needles are checked in the bytes the stripe verify reads
+        staged: Dict[str, scanner.StagedSweep] = {}
         for vid, ecv in ecvs:
             self._checkpoint(vid)
-            scan = scanner.scan_ec_volume_needles(ecv, throttler=throttler)
+            with phase("scan_ec", vid=vid):
+                staged[ecv.base_name] = scanner.StagedSweep(ecv)
             res.verdicts.setdefault(vid, VolumeVerdict())
             res.ec_volumes += 1
-            res.bytes_scanned += scan.bytes_scanned
-            res.needles_verified += scan.needles_verified
-            ScrubScannedBytesCounter.inc(scan.bytes_scanned)
-            ScrubNeedlesVerifiedCounter.inc(scan.needles_verified)
-            if scan.corrupt:
-                log.warning("ec volume %d: %d needle(s) fail CRC "
-                            "(bad data shards: %s)", vid,
-                            len(scan.corrupt),
-                            sorted(scan.bad_data_shards) or "?")
-            damages[vid] = planner.EcDamage(
-                base=ecv.base_name, bad_data=scan.bad_data_shards)
         # ONE fused verify across the whole fleet of local EC volumes:
         # spans from every volume share RS dispatches (the tentpole)
         self._checkpoint(0)
@@ -426,7 +424,27 @@ class ScrubDaemon:
             else:
                 verified = fleet.fleet_verify_ec_files(
                     list(by_base), backend=self.backend,
-                    throttler=throttler)
+                    throttler=throttler,
+                    on_span=lambda base, offset, valid, rows:
+                    staged[base].take(offset, valid, rows))
+        # the needle sweep's rest: the copied path for what the staged
+        # check did not accept, the shard files for what it never saw
+        damages: Dict[int, planner.EcDamage] = {}
+        for vid, ecv in ecvs:
+            self._checkpoint(vid)
+            scan = scanner.scan_ec_volume_needles(
+                ecv, throttler=throttler, staged=staged[ecv.base_name])
+            res.bytes_scanned += scan.bytes_scanned
+            res.needles_verified += scan.needles_verified
+            ScrubScannedBytesCounter.inc(scan.bytes_scanned)
+            ScrubNeedlesVerifiedCounter.inc(scan.needles_verified)
+            if scan.corrupt:
+                log.warning("ec volume %d: %d needle(s) fail CRC "
+                            "(bad data shards: %s)", vid,
+                            len(scan.corrupt),
+                            sorted(scan.bad_data_shards) or "?")
+            damages[vid] = planner.EcDamage(
+                base=ecv.base_name, bad_data=scan.bad_data_shards)
         for base, vr in verified.items():
             vid, ecv = by_base[base]
             d = damages[vid]

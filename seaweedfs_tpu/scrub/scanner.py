@@ -7,13 +7,18 @@ Two surfaces, matching the two on-disk formats:
     are vacuum's business, not corruption) gets its masked CRC
     recomputed via the same `verify_needle_integrity` predicate the
     SEAWEED_VERIFY_READS read gate uses.
-  * EC volumes — needle-level: each live .ecx entry is read from
-    LOCAL shards into a worker's reused buffer and checked where it
-    lies (header, attributes, CRC over a view), several needles in
-    flight; a needle that does not come out clean there is re-assembled
-    and parsed the copied way, which alone calls it corrupt, and a
-    failure is localized to the data shard at fault by
-    single-shard-exclusion reconstruction;
+  * EC volumes — needle-level: in a full pass the daemon walks each
+    volume's .ecx once (StagedSweep) and checks every live needle in
+    the bytes the pass's stripe verify has already read into its
+    staging buffers, on the volume's writer lane; what that cannot see
+    (a volume the verify declines, a tiered shard, a needle spread
+    over more than two spans) is read from LOCAL shards into a
+    worker's reused buffer and checked where it lies, several needles
+    in flight. Both check a record with ONE test (header, attributes,
+    CRC chained over its pieces); a needle that does not come out
+    clean is re-assembled and parsed the copied way, which alone calls
+    it corrupt, and a failure is localized to the data shard at fault
+    by single-shard-exclusion reconstruction;
     stripe-level: `ec/fleet.fleet_verify_ec_files` re-encodes the data
     shards through the fused dispatcher and compares parity (that call
     is batched across many volumes by the daemon, not per-volume here).
@@ -32,19 +37,23 @@ import time
 from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
-from typing import List, Optional, Set, Tuple
+from typing import Iterator, List, Optional, Sequence, Set, Tuple
+
+import numpy as np
 
 from seaweedfs_tpu.ec.ec_volume import EcVolume
 from seaweedfs_tpu.ec.fleet import FLEET_READERS
 from seaweedfs_tpu.ec.shard_bits import DATA_SHARDS
+from seaweedfs_tpu.native import rs_native
 from seaweedfs_tpu.ops.rs_code import ReedSolomon
 from seaweedfs_tpu.scrub.phases import phase
-from seaweedfs_tpu.stats.metrics import (ScrubNeedlesCounter,
+from seaweedfs_tpu.stats.metrics import (ScrubNeedleSourceCounter,
+                                         ScrubNeedlesCounter,
                                          ScrubSweepSecondsHistogram)
 from seaweedfs_tpu.storage import types as t
 from seaweedfs_tpu.storage.needle import (VERSION3, DataCorruptionError,
                                           Needle, NeedleError, actual_size,
-                                          masked_crc,
+                                          mask_crc,
                                           verify_needle_integrity)
 from seaweedfs_tpu.storage.volume import Volume
 
@@ -112,6 +121,7 @@ SWEEP_WORKERS = FLEET_READERS
 # the filer's chunk; the workers' buffers are the sweep's whole memory,
 # SWEEP_WORKERS x this at most). A larger record takes the copied path,
 # one at a time as before the pool: it holds the record three times.
+# The staged check carries no record over this across a span's end.
 _BUFFER_CAP = 32 << 20
 
 # A record of at least this many bytes is handed to a worker; a smaller
@@ -124,11 +134,38 @@ _HANDOVER_BYTES = 256 << 10
 _NEEDLES = {c: ScrubNeedlesCounter.labels(c) for c in ("in_place", "copied")}
 _STEP = {s: ScrubSweepSecondsHistogram.labels(s)
          for s in ("read", "check", "copied")}
+_SOURCE = {s: ScrubNeedleSourceCounter.labels(s)
+           for s in ("staged", "carried", "read")}
+
+# One live needle of the walk: (.ecx position, key, size, placed, length),
+# placed = [(shard id, shard offset, bytes)] in record order.
+_Needle = Tuple[int, int, int, List[Tuple[int, int, int]], int]
+
+
+def _live_needles(ecv: EcVolume, version: int,
+                  res: EcNeedleScan) -> Iterator[_Needle]:
+    """The .ecx walk: every live needle whose intervals all lie on
+    shards this server holds, in .ecx order; `res.skipped_remote`
+    counts the others (their holder scrubs them)."""
+    for i in range(len(ecv._keys)):
+        try:
+            _, size, intervals = ecv.locate_index(i, version)
+        except NeedleError:
+            continue  # tombstoned (now, or under the sweep)
+        placed = [iv.to_shard_and_offset(ecv.large_block, ecv.small_block)
+                  + (iv.size,) for iv in intervals]
+        if any(sid not in ecv.shards for sid, _, _ in placed):
+            res.skipped_remote += 1
+            continue
+        yield i, int(ecv._keys[i]), size, placed, \
+            sum(ln for _, _, ln in placed)
 
 
 def scan_ec_volume_needles(ecv: EcVolume, version: int = 3,
                            throttler=None,
-                           rs: Optional[ReedSolomon] = None) -> EcNeedleScan:
+                           rs: Optional[ReedSolomon] = None,
+                           staged: Optional["StagedSweep"] = None
+                           ) -> EcNeedleScan:
     """CRC-verify every live .ecx needle assembled from LOCAL shards.
 
     A needle is read into a worker's buffer and checked where it lies
@@ -138,6 +175,11 @@ def scan_ec_volume_needles(ecv: EcVolume, version: int = 3,
     (RS reconstruction from the other shards); the exclusion that makes
     the CRC pass names the corrupt shard. Needles spanning shards this
     server doesn't hold are skipped (their holder scrubs them).
+
+    `staged`: the volume's needles as the pass's stripe verify saw them
+    (StagedSweep, after the verify). Those it found clean are counted
+    and not read again, those it found not clean go straight to the
+    copied path, and only the needles it never saw are read here.
     """
     res = EcNeedleScan()
     sweep = _EcSweep(ecv, version, rs)
@@ -154,21 +196,19 @@ def scan_ec_volume_needles(ecv: EcVolume, version: int = 3,
             found.append((i, key, bad))
 
     with phase("scan_ec", vid=ecv.volume_id):
+        if staged is None:
+            needles = _live_needles(ecv, version, res)
+        else:
+            res.skipped_remote = staged.skipped_remote
+            res.needles_verified = staged.clean + len(staged.unclean)
+            res.bytes_scanned = staged.clean_bytes
+            for i, key, _, placed, length in staged.unclean:
+                retire(sweep.copied(placed, length), i, key)
+            needles = staged.residual()
         try:
-            for i in range(len(ecv._keys)):
-                try:
-                    _, size, intervals = ecv.locate_index(i, version)
-                except NeedleError:
-                    continue  # tombstoned (now, or under the sweep)
-                placed = [iv.to_shard_and_offset(ecv.large_block,
-                                                 ecv.small_block) + (iv.size,)
-                          for iv in intervals]
-                if any(sid not in ecv.shards for sid, _, _ in placed):
-                    res.skipped_remote += 1
-                    continue
-                key = int(ecv._keys[i])
-                length = sum(ln for _, _, ln in placed)
+            for i, key, size, placed, length in needles:
                 res.needles_verified += 1
+                _SOURCE["read"].inc()
                 if throttler is not None:
                     # before the hand-over: a throttled pass overshoots
                     # by the needles in flight at most
@@ -222,6 +262,11 @@ class _EcSweep:
         if self._clean_in_place(key, size, placed, length):
             _NEEDLES["in_place"].inc()
             return length, None
+        return self.copied(placed, length)
+
+    def copied(self, placed,
+               length: int) -> Tuple[int, Optional[Set[int]]]:
+        """check_copied of one needle, counted and timed."""
         t0 = time.perf_counter()
         with self._oversize if length > _BUFFER_CAP \
                 else contextlib.nullcontext():
@@ -258,37 +303,189 @@ class _EcSweep:
         _STEP["read"].observe(t1 - t0)
         if at != length:
             return False
-        clean = _record_is_clean(rec, key, size, self.version)
+        clean = _record_is_clean([rec], key, size, self.version)
         _STEP["check"].observe(time.perf_counter() - t1)
         return clean
 
 
-def _record_is_clean(rec: memoryview, key: int, size: int,
-                    version: int) -> bool:
-    """Is `rec`, a whole stored record in a buffer, the needle the .ecx
-    entry (key, size) promises, with a payload that matches its stored
-    checksum? True only if the header's id and size are the entry's,
-    the attribute walk lands on the checksum and the CRC over the
-    payload's VIEW agrees: every such record parses under
-    Needle.from_bytes too. False says nothing: ask the copied path."""
+class StagedSweep:
+    """One EC volume's needle sweep inside the pass's stripe verify
+    (`fleet_verify_ec_files(on_span=)`), so that its data shards are
+    read once. Made before the verify: the .ecx walked once, as a
+    snapshot, each needle's placed intervals ordered by the lowest
+    shard offset they touch. During it, on the volume's writer lane,
+    every span of the verify hands `take` its data rows, and a needle
+    whose intervals all lie in the span is checked in them with the
+    sweep's one record test. A needle across the span's end has the
+    pieces before it COPIED into a spare buffer the volume's sweep
+    reuses span after span (the needles of one shard row, a few MiB;
+    holding the staging buffer instead would keep a whole [14, lanes]
+    stripe out of the verify's rotation for one more dispatch, and the
+    readers, the pass's busiest stage, would have one buffer fewer to
+    fill ahead; and where a pass has more volumes than a buffer has
+    spans, a volume's next span lies several dispatches on, so it
+    would pin several) and is checked when the next span of the volume
+    arrives on the same lane. The staged check only ACCEPTS: after the
+    verify, `scan_ec_volume_needles(staged=)` takes what it did not
+    find clean through the copied path and reads from disk what it
+    never saw: a needle on a tiered shard, one spread over more than
+    two spans, every needle of a volume the verify declined or of a
+    pass with no spans (the mesh's)."""
+
+    def __init__(self, ecv: EcVolume, version: int = 3):
+        self.version = version
+        walk = EcNeedleScan()
+        self._later: List[_Needle] = []   # for the disk sweep
+        ahead = []
+        for needle in _live_needles(ecv, version, walk):
+            placed = needle[3]
+            if any(ecv.shards[sid].is_remote for sid, _, _ in placed):
+                self._later.append(needle)
+                continue
+            ahead.append((min(off for _, off, _ in placed),
+                          max(off + ln for _, off, ln in placed), needle))
+        ahead.sort(key=lambda a: a[0])
+        self._ahead = deque(ahead)   # (lowest offset, end, needle)
+        self.skipped_remote = walk.skipped_remote
+        # needles across the last span's end: the pieces before it,
+        # views of _spare
+        self._carry: List[Tuple[_Needle, list]] = []
+        self._spare = np.empty(0, dtype=np.uint8)
+        self.clean = self.clean_bytes = 0
+        self.unclean: List[_Needle] = []   # for the copied path
+
+    def take(self, offset: int, valid: int, rows) -> None:
+        """One span of the verify, on the volume's writer lane, in
+        offset order: shard offsets [offset, offset + valid), rows[sid]
+        their bytes of data shard sid. Nothing of `rows` is kept after
+        this returns but copies."""
+        t0 = time.perf_counter()
+        end = offset + valid
+        for needle, before in self._carry:
+            self._check("carried", needle,
+                        _pieces(needle[3], rows, offset, before))
+        crossing = []
+        while self._ahead and self._ahead[0][0] < end:
+            _, hi, needle = self._ahead.popleft()
+            if hi <= end:
+                self._check("staged", needle,
+                            _pieces(needle[3], rows, offset))
+            elif hi <= end + valid and needle[4] <= _BUFFER_CAP:
+                # every span but a volume's last is `valid` wide, and
+                # nothing crosses the last one's end
+                crossing.append(needle)
+            else:
+                self._later.append(needle)
+        self._carry = self._copy_before(crossing, rows, offset, end)
+        _STEP["check"].observe(time.perf_counter() - t0)
+
+    def _copy_before(self, crossing: List[_Needle], rows, offset: int,
+                     end: int) -> list:
+        """(needle, its pieces before `end` as views of _spare) for each
+        needle of `crossing`. The carries before them are checked
+        already, so _spare is free to take these."""
+        cut = [[max(min(off + ln, end) - off, 0) for _, off, ln in needle[3]]
+               for needle in crossing]
+        need = sum(map(sum, cut))
+        if len(self._spare) < need:
+            self._spare = np.empty(max(need, 2 * len(self._spare)),
+                                   dtype=np.uint8)
+        carry, at = [], 0
+        for needle, lens in zip(crossing, cut):
+            before = []
+            for (sid, off, _), n in zip(needle[3], lens):
+                piece = self._spare[at:at + n]
+                piece[:] = rows[sid, off - offset:off - offset + n]
+                before.append(piece)
+                at += n
+            carry.append((needle, before))
+        return carry
+
+    def _check(self, source: str, needle: _Needle, pieces) -> None:
+        _, key, size, _, length = needle
+        _SOURCE[source].inc()
+        if _record_is_clean(pieces, key, size, self.version):
+            _NEEDLES["in_place"].inc()
+            self.clean += 1
+            self.clean_bytes += length
+        else:
+            self.unclean.append(needle)
+
+    def residual(self) -> List[_Needle]:
+        """After the verify: the needles the staged check never saw, in
+        .ecx order."""
+        return sorted(self._later
+                      + [needle for needle, _ in self._carry]
+                      + [needle for _, _, needle in self._ahead],
+                      key=lambda needle: needle[0])
+
+
+def _pieces(placed, rows, at: int, before: Sequence = ()) -> list:
+    """A record's bytes in order as pieces: views of the span `rows`,
+    which starts at shard offset `at`, after `before[j]`, what was
+    copied of interval j from the span before it."""
+    out = []
+    for j, (sid, off, ln) in enumerate(placed):
+        if before and len(before[j]):
+            out.append(before[j])
+        start = max(off, at)
+        if start < off + ln:
+            out.append(rows[sid, start - at:off + ln - at])
+    return out
+
+
+def _gather(pieces, start: int, stop: int) -> bytes:
+    """Bytes [start, stop) of the record `pieces` make."""
+    out, at = [], 0
+    for p in pieces:
+        n = len(p)
+        if at < stop and at + n > start:
+            out.append(bytes(p[max(start - at, 0):min(stop - at, n)]))
+        at += n
+    return b"".join(out)
+
+
+def _crc(pieces, start: int, stop: int) -> int:
+    """The raw CRC32C of bytes [start, stop) of the record `pieces`
+    make, chained over the pieces where they lie."""
+    c, at = 0, 0
+    for p in pieces:
+        n = len(p)
+        if at < stop and at + n > start:
+            c = rs_native.crc32c(p[max(start - at, 0):min(stop - at, n)], c)
+        at += n
+    return c
+
+
+def _record_is_clean(pieces, key: int, size: int, version: int) -> bool:
+    """Is the whole stored record that `pieces` make in order (buffers:
+    views of a read buffer or of a staging buffer's rows, or bytes) the
+    needle the .ecx entry (key, size) promises, with a payload that
+    matches its stored checksum? True only if the header's id and size
+    are the entry's, the attribute walk lands on the checksum and the
+    CRC chained over the payload's pieces agrees: every such record
+    parses under Needle.from_bytes too. False says nothing: ask the
+    copied path."""
     tail = t.NEEDLE_HEADER_SIZE + size   # the checksum's place
     data_at = meta_at = tail
-    if size > 0:
-        (data_size,) = struct.unpack_from(">I", rec, t.NEEDLE_HEADER_SIZE)
-        data_at = t.NEEDLE_HEADER_SIZE + 4
-        meta_at = data_at + data_size   # the flags byte, before `tail`
-        if meta_at >= tail:
-            return False
-    meta_end = tail + t.NEEDLE_CHECKSUM_SIZE + \
-        (t.TIMESTAMP_SIZE if version == VERSION3 else 0)
+    head = _gather(pieces, 0, t.NEEDLE_HEADER_SIZE + (4 if size > 0 else 0))
     try:
-        n = Needle.from_disk_meta(rec, bytes(rec[meta_at:meta_end]),
+        if size > 0:
+            (data_size,) = struct.unpack_from(">I", head,
+                                              t.NEEDLE_HEADER_SIZE)
+            data_at = t.NEEDLE_HEADER_SIZE + 4
+            meta_at = data_at + data_size   # the flags byte, before `tail`
+            if meta_at >= tail:
+                return False
+        meta_end = tail + t.NEEDLE_CHECKSUM_SIZE + \
+            (t.TIMESTAMP_SIZE if version == VERSION3 else 0)
+        n = Needle.from_disk_meta(head, _gather(pieces, meta_at, meta_end),
                                   meta_at - data_at, version)
     except PARSE_ERRORS:
         return False
     if n.id != key or n.size != size:
         return False
-    return size == 0 or n.checksum == masked_crc(rec[data_at:meta_at])
+    return size == 0 or n.checksum == mask_crc(_crc(pieces, data_at, meta_at))
 
 
 def check_copied(ecv: EcVolume, placed, version: int,

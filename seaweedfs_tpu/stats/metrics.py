@@ -460,15 +460,17 @@ ScrubPassSecondsHistogram = REGISTRY.histogram(
     "wall time of one full scrub pass",
     buckets=(0.01, 0.1, 1, 10, 60, 600, 3600, 6 * 3600, 24 * 3600))
 # One pass by phase: scan (needle sweep of normal volumes), scan_ec
-# (needle sweep of EC volumes over local shards), verify (the one fused
-# stripe verify), repair (quarantine + rebuild of one volume's condemned
+# (needle sweep of EC volumes over local shards: the .ecx walk before
+# the verify, what its staged bytes did not settle after it), verify
+# (the one fused stripe verify, the staged needle checks on its lanes),
+# repair (quarantine + rebuild of one volume's condemned
 # shards), reverify (the stripe verify that follows a repair).
 ScrubPhaseSecondsHistogram = REGISTRY.histogram(
     "SeaweedFS_scrub_phase_seconds",
     "scrub pass: wall time by phase", ("phase",))
-# The EC needle sweep, needle by needle: `in_place` (every interval
-# read into a worker's reused buffer, the record parsed and CRC'd
-# there, and found clean) or `copied` (everything else — a mismatch,
+# The EC needle sweep, needle by needle: `in_place` (the record parsed
+# and CRC'd where its bytes lie — read into a worker's reused buffer,
+# or staged by the pass's stripe verify — and found clean) or `copied` (everything else — a mismatch,
 # a torn or short record, a tiered shard, a needle over the buffer's
 # cap — goes through read_at + join + Needle.from_bytes, which alone
 # decides corrupt). Steps are observed once a needle on the worker
@@ -481,6 +483,16 @@ ScrubSweepSecondsHistogram = REGISTRY.histogram(
     "EC needle sweep: thread-seconds a needle by step (workers run "
     "side by side, so the sum may exceed the scan_ec phase's wall)",
     ("step",))
+# Where a swept EC needle's first check found its bytes: `staged` (whole
+# in one span of the pass's stripe verify, checked in its staging buffer
+# on the volume's writer lane), `carried` (across the end of one span:
+# the pieces before it copied, the rest checked in the next span) or
+# `read` (read from the shard files by the disk sweep). One count a
+# needle swept.
+ScrubNeedleSourceCounter = REGISTRY.counter(
+    "SeaweedFS_scrub_needle_source_total",
+    "EC needles swept by where their first check found their bytes",
+    ("source",))
 ScrubScanLagGauge = REGISTRY.gauge(
     "SeaweedFS_scrub_scan_lag_seconds",
     "seconds since the last completed scrub pass")

@@ -47,7 +47,12 @@ VERSION3 = 3
 
 def masked_crc(data: bytes) -> int:
     """CRC32C with the snappy rotation mask — the needle checksum."""
-    c = rs_native.crc32c(data)
+    return mask_crc(rs_native.crc32c(data))
+
+
+def mask_crc(c: int) -> int:
+    """The snappy rotation mask over a raw CRC32C: masked_crc of data
+    whose CRC was chained piece by piece (rs_native.crc32c(p, c))."""
     return (((c >> 15) | (c << 17)) + 0xA282EAD8) & 0xFFFFFFFF
 
 

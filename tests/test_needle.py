@@ -2,6 +2,7 @@
 
 import struct
 
+import numpy as np
 import pytest
 
 from seaweedfs_tpu.storage import types as t
@@ -117,6 +118,31 @@ def test_crc32c_over_a_buffer_equals_crc32c_over_its_bytes(length, kind):
     assert rs_native.crc32c(view, 0x1234) == \
         rs_native.crc32c(bytes(view), 0x1234)
     assert masked_crc(view) == masked_crc(bytes(view))
+
+
+def _bitwise_crc32c(data: bytes, crc: int = 0) -> int:
+    crc ^= 0xFFFFFFFF
+    for b in data:
+        crc ^= b
+        for _ in range(8):
+            crc = (crc >> 1) ^ (0x82F63B78 if crc & 1 else 0)
+    return crc ^ 0xFFFFFFFF
+
+
+@pytest.mark.parametrize("length", [3 * 8192 - 1, 3 * 8192, 3 * 8192 + 9,
+                                    2 * 3 * 8192 + 8 * 100 + 5])
+def test_crc32c_is_the_bitwise_definition_across_its_strides(length):
+    """The native CRC takes three blocks of 8 KiB side by side and joins
+    them: over lengths around its stride, from 0 and chained, it is the
+    bit-at-a-time definition."""
+    from seaweedfs_tpu.native import rs_native
+    data = np.random.default_rng(length).integers(
+        0, 256, length, dtype=np.uint8).tobytes()
+    assert rs_native.crc32c(data) == _bitwise_crc32c(data)
+    assert rs_native.crc32c(data, 0xDEADBEEF) == \
+        _bitwise_crc32c(data, 0xDEADBEEF)
+    head = rs_native.crc32c(data[:5000])
+    assert rs_native.crc32c(data[5000:], head) == rs_native.crc32c(data)
 
 
 def test_crc32c_makes_no_copy_of_a_writable_buffer():

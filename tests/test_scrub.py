@@ -13,7 +13,8 @@ from seaweedfs_tpu.scrub import (EcDamage, ScrubDaemon, classify_ec_damage,
                                  repair_ec_volume, repair_needle,
                                  scan_ec_volume_needles, scan_volume)
 from seaweedfs_tpu.scrub import scanner
-from seaweedfs_tpu.stats.metrics import ScrubNeedlesCounter
+from seaweedfs_tpu.stats.metrics import (ScrubNeedleSourceCounter,
+                                         ScrubNeedlesCounter)
 from seaweedfs_tpu.storage import volume as volume_mod
 from seaweedfs_tpu.storage.needle import (DataCorruptionError, Needle,
                                           NeedleError, actual_size,
@@ -132,6 +133,80 @@ def _copied_scan(ecv, version=3):
 def _needle_counts():
     return {c: ScrubNeedlesCounter.labels(c).value
             for c in ("in_place", "copied")}
+
+
+def _source_counts():
+    return {s: ScrubNeedleSourceCounter.labels(s).value
+            for s in ("staged", "carried", "read")}
+
+
+def _staged_scan(ecv, backend, chunk):
+    """The EC part of a full pass, on one volume: the .ecx walked, the
+    stripe verify with the needles checked in its staged bytes, then the
+    sweep's rest."""
+    staged = scanner.StagedSweep(ecv)
+    verified = fleet.fleet_verify_ec_files(
+        [ecv.base_name], backend=backend, chunk=chunk,
+        on_span=lambda base, offset, valid, rows:
+        staged.take(offset, valid, rows))
+    assert verified[ecv.base_name].verified
+    return scan_ec_volume_needles(ecv, staged=staged)
+
+
+def _sources_by_geometry(ecv, span):
+    """Where each live needle's bytes lie among spans of `span` shard
+    bytes: all in one, across one end, or further apart."""
+    found = {"staged": 0, "carried": 0, "read": 0}
+    for key, size in zip(ecv._keys.tolist(), ecv._sizes.tolist()):
+        if size < 0:
+            continue
+        placed = _placed(ecv, key)
+        first = min(off for _, off, _ in placed) // span
+        last = (max(off + ln for _, off, ln in placed) - 1) // span
+        found[("staged", "carried")[last - first]
+              if last - first < 2 else "read"] += 1
+    return found
+
+
+def _sector(path, offset):
+    """Every byte of one 4096-byte sector replaced by another value."""
+    with open(path, "r+b") as f:
+        f.seek(offset)
+        old = f.read(4096)
+        assert len(old) == 4096
+        f.seek(offset)
+        f.write(bytes((b + 1 + i % 200) % 256 for i, b in enumerate(old)))
+
+
+def _sector_in_payload(ecv, base):
+    sid, off, ln = _placed(ecv, 7)[0]
+    assert ln > 2 * 4096
+    _sector(encoder.shard_file_name(base, sid), off + 100)
+    return [7], {sid}
+
+
+def _sector_in_dead_space(ecv, base):
+    # row 1 holds needles on shards 0-2 only: shard 9 is padding there
+    path = encoder.shard_file_name(base, 9)
+    _sector(path, os.path.getsize(path) - 2 * 4096)
+    return [], set()
+
+
+def _tombstones(ecv, base):
+    ecv.delete_needle(5)
+    ecv.delete_needle(14)
+    return [], set()
+
+
+# what is planted -> (corrupt needles, bad data shards) of the sweep
+_STAGED_CASES = {
+    "clean": lambda ecv, base: ([], set()),
+    "tombstoned": _tombstones,
+    "sector-in-payload": _sector_in_payload,
+    "sector-in-dead-space": _sector_in_dead_space,
+    "sector-in-parity": lambda ecv, base: _sector(
+        encoder.shard_file_name(base, 12), 3 * 4096) or ([], set()),
+}
 
 
 def _case_flip(nid, at_from_size):
@@ -335,6 +410,89 @@ class TestScanner:
         assert Throttler.calls == [actual_size(int(z)) for z in ecv._sizes
                                    if z >= 0]
         assert sum(Throttler.calls) == res.bytes_scanned
+
+    # -- the staged sweep: needles checked in the stripe verify's bytes --------
+
+    @pytest.mark.parametrize("backend, row", [
+        ("numpy", 1 << 21),    # one span a shard: every needle whole in it
+        ("numpy", 700_000),    # three: every 1 MiB needle across an end
+        ("numpy", 1 << 16),    # 32: a 1 MiB needle over more than two
+        ("jax", 700_000),      # counts from the device, spans of 4 KiB blocks
+    ])
+    @pytest.mark.parametrize("case", sorted(_STAGED_CASES))
+    def test_staged_sweep_gives_what_the_disk_sweep_gives(
+            self, store, case, backend, row):
+        """The needles checked in the bytes the stripe verify staged (on
+        the volume's writer lane, across a span's end from a copy) give
+        the EcNeedleScan the disk sweep and the copied path give: a
+        sector in a live needle's payload is found and named by its
+        data shard, one in dead space or a parity shard by no needle.
+        Each needle is counted once by where its bytes were found, as
+        the verify's spans cut it."""
+        base = _make_mixed_ec(store, 3)
+        ecv = store.find_ec_volume(3)
+        planted = _STAGED_CASES[case](ecv, base)
+        counted = _needle_counts()
+        sources = _source_counts()
+        got = _staged_scan(ecv, backend, 10 * row)
+        moved = {c: n - counted[c] for c, n in _needle_counts().items()}
+        found = {s: n - sources[s] for s, n in _source_counts().items()}
+        want = _copied_scan(ecv)
+        assert got == want == scan_ec_volume_needles(ecv)
+        assert (got.corrupt, got.bad_data_shards) == planted
+        assert moved == {"in_place": got.needles_verified - len(planted[0]),
+                         "copied": len(planted[0])}
+        span, _ = fleet._stacked_spans(10 * row, [ecv.shard_size])
+        assert found == _sources_by_geometry(ecv, span)
+        assert found["carried"] > 0 or row != 700_000
+        assert found["read"] > 0 or row != 1 << 16
+
+    def test_a_volume_the_verify_declines_is_swept_from_disk(self, store):
+        """A data shard gone: the stripe verify declines the volume, no
+        span of it is staged, and its sweep reads every needle from the
+        local shards, as before; the other volume's are not read."""
+        _make_ec(store, 2)
+        base = _make_mixed_ec(store, 3)
+        ecv = store.find_ec_volume(3)
+        ecv.unmount_shard(3)
+        os.remove(encoder.shard_file_name(base, 3))
+        sources = _source_counts()
+        res = ScrubDaemon(store, backend="numpy").run_pass()
+        found = {s: n - sources[s] for s, n in _source_counts().items()}
+        local = sum(1 for nid in ecv._keys.tolist()
+                    if all(sid != 3 for sid, _, _ in _placed(ecv, nid)))
+        assert 0 < local < len(ecv._keys)
+        assert found == {"staged": 25, "carried": 0, "read": local}
+        assert res.needles_verified == 25 + local
+        assert res.corruptions_found == 0
+
+    @pytest.mark.parametrize("backend", ["numpy", "jax"])
+    def test_a_staged_check_reads_no_buffer_handed_out_again(
+            self, store, monkeypatch, backend):
+        """Every staging buffer is overwritten the moment its last
+        reader lets it go: the needles still come out clean where the
+        verify staged them, so no check read a buffer after that (the
+        pieces carried across a span's end are copies)."""
+        monkeypatch.setattr(fleet, "_IDLE_STAGING", fleet._IdleStaging())
+        unref = fleet._Staging.unref
+
+        def poisoned(staging, batch):
+            with staging._cond:
+                if batch.refs == 1:
+                    batch.buf[:] = 0xA5
+            unref(staging, batch)
+
+        monkeypatch.setattr(fleet._Staging, "unref", poisoned)
+        _make_mixed_ec(store, 3)
+        ecv = store.find_ec_volume(3)
+        counted = _needle_counts()
+        sources = _source_counts()
+        got = _staged_scan(ecv, backend, 10 * 700_000)
+        assert _needle_counts()["copied"] == counted["copied"]
+        found = {s: n - sources[s] for s, n in _source_counts().items()}
+        assert found["carried"] > 0 and found["read"] == 0
+        assert got.needles_verified == found["staged"] + found["carried"]
+        assert got == _copied_scan(ecv)
 
     def test_shard_read_into_equals_read_at(self, store):
         base = _make_ec(store, 2)

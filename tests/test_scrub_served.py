@@ -25,6 +25,7 @@ from seaweedfs_tpu.ops.rs_code import ReedSolomon
 from seaweedfs_tpu.shell import CommandError, Shell
 from seaweedfs_tpu.stats.metrics import (FleetStagingBuffersCounter,
                                          FleetVerifyBytesCounter,
+                                         ScrubNeedleSourceCounter,
                                          ScrubNeedlesCounter,
                                          ScrubPhaseSecondsHistogram)
 from seaweedfs_tpu.stats import trace
@@ -107,6 +108,11 @@ def _needle_counts():
             for c in ("in_place", "copied")}
 
 
+def _source_counts():
+    return {s: ScrubNeedleSourceCounter.labels(s).value
+            for s in ("staged", "carried", "read")}
+
+
 @pytest.mark.parametrize("case", ["none", "data-shard", "parity-shard",
                                   "dead-space", "two-volumes"])
 def test_scrub_wait_reports_what_was_planted_and_repairs_it(served, case):
@@ -131,6 +137,7 @@ def test_scrub_wait_reports_what_was_planted_and_repairs_it(served, case):
     assert not trace.active()
     phases = _phase_counts()
     needles = _needle_counts()
+    sources = _source_counts()
     device = FleetVerifyBytesCounter.labels("device").value
     host = FleetVerifyBytesCounter.labels("host").value
 
@@ -150,9 +157,10 @@ def test_scrub_wait_reports_what_was_planted_and_repairs_it(served, case):
         differ = np.flatnonzero(kept != before[vid][sid])
         assert len(differ) == SECTOR and differ[0] == offset
         os.remove(paths[vid][sid] + ".corrupt")
-    # every phase of the pass is observed with the span ring off
+    # every phase of the pass is observed with the span ring off: an EC
+    # volume's sweep twice, its .ecx walk before the verify and its rest
     moved = {p: n - phases[p] for p, n in _phase_counts().items()}
-    assert moved == {"scan": len(_plain_vids(c)), "scan_ec": len(vids),
+    assert moved == {"scan": len(_plain_vids(c)), "scan_ec": 2 * len(vids),
                      "verify": 1,
                      "repair": len(planted), "reverify": len(planted)}
     # the EC sweep declared every needle clean where it was read, but
@@ -164,6 +172,11 @@ def test_scrub_wait_reports_what_was_planted_and_repairs_it(served, case):
     assert swept == {
         "copied": sum(1 for sid, _ in planted.values() if sid == 0),
         "in_place": live - swept["copied"]} and swept["copied"] <= 1
+    # ... and every one of them in the bytes the stripe verify staged:
+    # one server holds every shard, so nothing was read a second time
+    found = {s: n - sources[s] for s, n in _source_counts().items()}
+    assert found["read"] == 0
+    assert found["staged"] + found["carried"] == live
     # the stripe verify and the re-verifies compared on the device
     assert FleetVerifyBytesCounter.labels("device").value - device == \
         DATA_SHARDS * sum(len(before[vid][0])
