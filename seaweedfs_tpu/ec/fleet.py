@@ -54,7 +54,7 @@ import threading
 from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, FrozenSet, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -69,8 +69,9 @@ from seaweedfs_tpu.stats.metrics import (
     FleetMeshFallbacksCounter, FleetPassPartSecondsHistogram,
     FleetPassSecondsHistogram, FleetReaderQueueGauge,
     FleetRebuildGroupsCounter, FleetRebuildVolumesCounter,
-    FleetRebuiltBytesCounter, FleetStageSecondsHistogram,
-    FleetStagingBuffersCounter, FleetVerifyBytesCounter,
+    FleetRebuiltBytesCounter, FleetRemoteReadSecondsHistogram,
+    FleetStageSecondsHistogram, FleetStagingBuffersCounter,
+    FleetVerifyBytesCounter, FleetVerifyRowBytesCounter,
     FleetWaitSecondsHistogram, FleetWriterBacklogGauge)
 
 
@@ -131,6 +132,9 @@ _STAGING_HANDED = {state: FleetStagingBuffersCounter.labels(state)
                    for state in ("fresh", "reused")}
 _VERIFIED_BYTES = {where: FleetVerifyBytesCounter.labels(where)
                    for where in ("device", "host")}
+_ROW_BYTES = {source: FleetVerifyRowBytesCounter.labels(source)
+              for source in ("local", "remote")}
+_REMOTE_READ = FleetRemoteReadSecondsHistogram.labels()
 
 
 class _StageTimer(trace.PhaseTimer):
@@ -924,7 +928,9 @@ def fleet_rebuild_ec_files(base_names: Sequence[str], backend: str = "auto",
                            readers: int = FLEET_READERS,
                            depth: int = FLEET_DEPTH,
                            encoders: int = FLEET_ENCODERS,
-                           device=None) -> Dict[str, List[int]]:
+                           device=None,
+                           remote: Optional[Dict[str, RemoteRows]] = None
+                           ) -> Dict[str, List[int]]:
     """Cross-volume batched `rebuild_ec_files`.
 
     Volumes sharing a (present, missing) signature share one decode
@@ -935,12 +941,17 @@ def fleet_rebuild_ec_files(base_names: Sequence[str], backend: str = "auto",
     width and trimmed on writeback. Returns
     {base_name: rebuilt shard ids} (empty list where nothing was
     missing).
+
+    `remote` (with `wanted`): for a volume with fewer than ten shard
+    files here, the rows other servers hold; the fewest of them that
+    make ten survivors are pulled from their holders, span by span.
     """
     if chunk is None:
         chunk = default_chunk_for(backend)
     rebuilt: Dict[str, List[int]] = {}
     groups: Dict[Tuple[Tuple[int, ...], Tuple[int, ...]],
                  List[Tuple[str, int]]] = {}
+    pulled: Dict[str, RemoteRows] = {}
     for base in base_names:
         present = [i for i in range(TOTAL_SHARDS)
                    if os.path.exists(shard_file_name(base, i))]
@@ -950,10 +961,18 @@ def fleet_rebuild_ec_files(base_names: Sequence[str], backend: str = "auto",
         rebuilt[base] = missing
         if not missing:
             continue
+        far = (remote or {}).get(base)
+        if far is not None and len(present) < DATA_SHARDS:
+            pull = [s for s in sorted(far.sids)
+                    if s not in present and s not in missing]
+            pull = pull[:DATA_SHARDS - len(present)]
+            pulled[base] = far = RemoteRows(far.vid, frozenset(pull),
+                                            far.shard_size, far.fill)
+            present = sorted(present + pull)
         if len(present) < DATA_SHARDS:
             raise ValueError(
                 f"cannot rebuild {base}: only {len(present)} shards present")
-        shard_size = os.path.getsize(shard_file_name(base, present[0]))
+        shard_size = _shard_size(base, present[0], pulled.get(base))
         groups.setdefault((tuple(present), tuple(missing)),
                           []).append((base, shard_size))
     for (present, missing), members in groups.items():
@@ -962,7 +981,7 @@ def fleet_rebuild_ec_files(base_names: Sequence[str], backend: str = "auto",
                                    for sid in missing]):
             _fleet_rebuild_group(list(present), list(missing), members,
                                  backend, chunk, readers, depth, encoders,
-                                 device, len(groups))
+                                 device, len(groups), pulled)
         FleetRebuildGroupsCounter.inc()
         FleetRebuildVolumesCounter.inc(float(len(members)))
     return rebuilt
@@ -1050,10 +1069,62 @@ def _read_rows_into(base: str, rows: Sequence[Tuple[int, int]],
         lanes[got:] = 0
 
 
+@dataclass(frozen=True)
+class RemoteRows:
+    """The rows of one EC volume that a pass reads from the servers
+    that hold them, not from this server's files: shards `sids`, each
+    `shard_size` bytes. `fill(sid, offset, view)` places shard `sid`'s
+    bytes from `offset` on in `view` (from a holder's VolumeEcShardRead
+    stream) and returns how many it placed, fewer only where the
+    holder's shard ends; it raises OSError when no holder gives them."""
+
+    vid: int
+    sids: FrozenSet[int]
+    shard_size: int
+    fill: Callable[[int, int, memoryview], int]
+
+
+def _fill_remote_rows(remote: RemoteRows, rows: Sequence[Tuple[int, int]],
+                      shard_size: int, offset: int, span: int, width: int,
+                      buf: np.ndarray, off: int) -> None:
+    """_read_rows_into for rows of shards other servers hold: each row's
+    lanes filled from its holder, the rest zeroed as there. Timed a row
+    at a time (span `fleet.read.remote`, inside the reader's
+    `fleet.read`)."""
+    want = min(span, max(shard_size - offset, 0))
+    for row, sid in rows:
+        lanes = buf[row, off:off + width]
+        got = 0
+        if want > 0:
+            with trace.PhaseTimer(_REMOTE_READ,
+                                  "fleet.read.remote", vid=remote.vid,
+                                  shard=sid, bytes=want):
+                got = remote.fill(sid, offset, memoryview(lanes[:want]))
+        lanes[got:] = 0
+
+
+def _read_mixed_rows(base: str, rows: Sequence[Tuple[int, int]],
+                     shard_size: int, offset: int, span: int, width: int,
+                     buf: np.ndarray, off: int, remote: RemoteRows) -> None:
+    """One span's rows, each from this server's shard file or, for the
+    shards in `remote`, from their holder."""
+    _read_rows_into(base, [(r, s) for r, s in rows if s not in remote.sids],
+                    shard_size, offset, span, width, buf, off)
+    _fill_remote_rows(remote, [(r, s) for r, s in rows if s in remote.sids],
+                      shard_size, offset, span, width, buf, off)
+
+
+def _shard_size(base: str, sid: int, remote: Optional[RemoteRows]) -> int:
+    if remote is not None and sid in remote.sids:
+        return remote.shard_size
+    return os.path.getsize(shard_file_name(base, sid))
+
+
 def _fleet_rebuild_group(present: List[int], missing: List[int],
                          members: List[Tuple[str, int]], backend: str,
                          chunk: int, readers: int, depth: int,
-                         encoders: int, device, groups: int) -> None:
+                         encoders: int, device, groups: int,
+                         pulled: Dict[str, RemoteRows]) -> None:
     # creating the output files is write-side IO, timed as in encode
     with _StageTimer("write", setup=len(members)):
         for base, _ in members:
@@ -1069,9 +1140,14 @@ def _fleet_rebuild_group(present: List[int], missing: List[int],
     def plan():
         for v, row0, _rows in _round_robin_spans(vols, 1):
             offset = row0 * span
-            yield v, span, min(span, v.dat_size - offset), functools.partial(
+            far = pulled.get(v.base)
+            read = functools.partial(
                 _read_present_span_into, v.base, present, v.dat_size,
-                offset, span)
+                offset, span) if far is None else functools.partial(
+                _read_mixed_rows, v.base,
+                list(enumerate(present[:DATA_SHARDS])), v.dat_size, offset,
+                span, span, remote=far)
+            yield v, span, min(span, v.dat_size - offset), read
 
     def flush(batch: _StagedBatch, dispatcher: _Dispatcher,
               pipe: TaggedPipeline, release: Callable[[], None]) -> None:
@@ -1106,7 +1182,8 @@ class VerifyResult:
     the first differing shard offset per shard. `missing` lists shard
     files absent on disk — those are known damage (the rebuild path's
     job), not verification subjects. A volume with any data shard
-    missing cannot be re-encoded and is reported with verified=False.
+    missing cannot be re-encoded and is reported with verified=False,
+    as is one a remote row of which no holder gave.
     """
 
     parity_mismatch: Dict[int, int] = field(default_factory=dict)
@@ -1130,7 +1207,8 @@ def fleet_verify_ec_files(base_names: Sequence[str], backend: str = "auto",
                           device=None,
                           throttler=None,
                           on_span: Optional[Callable[
-                              [str, int, int, np.ndarray], None]] = None
+                              [str, int, int, np.ndarray], None]] = None,
+                          remote: Optional[Dict[str, RemoteRows]] = None
                           ) -> Dict[str, "VerifyResult"]:
     """Verify EC stripe consistency for MANY volumes in one fused pass.
 
@@ -1155,6 +1233,18 @@ def fleet_verify_ec_files(base_names: Sequence[str], backend: str = "auto",
     needles there instead of reading them again); the buffer is held
     until every span's call has returned, as an encode's is for its
     writes. Without it the buffer is free once the dispatch has read it.
+
+    `remote`: for a volume whose shards are spread over servers, the
+    rows of the shards it has no file of here (RemoteRows): the readers
+    fill those rows of the same lanes from the holders, and the rest of
+    the pass is as for a volume whose shards are all here. A remote row
+    that comes back short reads as zeros past its end, as a short file
+    does on the device. A host codec compares a remote parity row in
+    the staging buffer, which is then held for the volume's lanes too.
+    A remote row that no holder gives (`fill` raises OSError) declines
+    its volume alone, with no evidence kept (verified=False, as for a
+    missing data shard): its later spans pull nothing and reach neither
+    the compare nor on_span, and the other volumes' pass goes on.
     """
     if chunk is None:
         chunk = default_chunk_for(backend)
@@ -1162,10 +1252,17 @@ def fleet_verify_ec_files(base_names: Sequence[str], backend: str = "auto",
     results: Dict[str, VerifyResult] = {}
     sized: List[Tuple[str, int]] = []  # (base, shard size) to verify
     short: List[str] = []
+    spread: Dict[str, RemoteRows] = {}  # the remote rows a pass reads
+    unreached: set = set()  # volumes a remote row of which no holder gave
     for base in base_names:
         r = results[base] = VerifyResult()
         present = [i for i in range(TOTAL_SHARDS)
                    if os.path.exists(shard_file_name(base, i))]
+        far = (remote or {}).get(base)
+        if far is not None:
+            far = RemoteRows(far.vid, frozenset(far.sids - set(present)),
+                             far.shard_size, far.fill)
+            present = sorted(set(present) | far.sids)
         r.missing = [i for i in range(TOTAL_SHARDS) if i not in present]
         parity = [i for i in present if i >= DATA_SHARDS]
         if any(i < DATA_SHARDS for i in r.missing) or not parity:
@@ -1174,10 +1271,12 @@ def fleet_verify_ec_files(base_names: Sequence[str], backend: str = "auto",
             r.verified = False
             continue
         r.parity_checked = parity
-        size = os.path.getsize(shard_file_name(base, 0))
+        if far is not None and far.sids:
+            spread[base] = far
+        size = _shard_size(base, 0, far)
         if on_device and any(
                 os.path.getsize(shard_file_name(base, sid)) < size
-                for sid in parity):
+                for sid in parity if far is None or sid not in far.sids):
             short.append(base)
         else:
             sized.append((base, size))
@@ -1190,7 +1289,7 @@ def fleet_verify_ec_files(base_names: Sequence[str], backend: str = "auto",
         # is held to its files by a host codec, which is exact.
         results.update(fleet_verify_ec_files(
             short, readers=readers, depth=depth, encoders=encoders,
-            throttler=throttler))
+            throttler=throttler, remote=remote))
     if not any(size for _, size in sized):
         return results  # nothing to read: no pool, no buffer
     span, per_batch = _stacked_spans(chunk, [size for _, size in sized])
@@ -1205,6 +1304,13 @@ def fleet_verify_ec_files(base_names: Sequence[str], backend: str = "auto",
             for tag, (base, size) in enumerate(sized)]
     where = _VERIFIED_BYTES["device" if on_device else "host"]
 
+    def far_parity(v: _VolState) -> List[int]:
+        """The parity rows a host codec compares in the buffer."""
+        far = spread.get(v.base)
+        return [] if on_device or far is None else \
+            [sid for sid in results[v.base].parity_checked
+             if sid in far.sids]
+
     def plan():
         for v, row0, _rows in _round_robin_spans(vols, 1):
             offset = row0 * span
@@ -1214,10 +1320,35 @@ def fleet_verify_ec_files(base_names: Sequence[str], backend: str = "auto",
                 # span costs 10 data reads plus its parity reads
                 throttler.maybe_slowdown(span * (DATA_SHARDS + len(parity)))
             # the device is handed the stored parity beside the data
-            shards = list(range(DATA_SHARDS)) + (parity if on_device else [])
-            yield v, width, min(span, v.dat_size - offset), functools.partial(
-                _read_rows_into, v.base, [(sid, sid) for sid in shards],
-                v.dat_size, offset, span, width)
+            shards = list(range(DATA_SHARDS)) + \
+                (parity if on_device else far_parity(v))
+            rows = [(sid, sid) for sid in shards]
+            valid = min(span, v.dat_size - offset)
+            far = spread.get(v.base)
+            if far is None:
+                _ROW_BYTES["local"].inc(float(valid * len(rows)))
+                read = functools.partial(_read_rows_into, v.base, rows,
+                                         v.dat_size, offset, span, width)
+            else:
+                pulled = sum(sid in far.sids for sid in shards)
+                _ROW_BYTES["local"].inc(float(valid * (len(rows) - pulled)))
+                _ROW_BYTES["remote"].inc(float(valid * pulled))
+                read = functools.partial(read_spread, v.base, rows,
+                                         v.dat_size, offset, far)
+            yield v, width, valid, read
+
+    def read_spread(base: str, rows, size: int, offset: int,
+                    far: RemoteRows, buf: np.ndarray, off: int) -> None:
+        """A span of a volume with remote rows; one that no holder gives
+        declines the volume: its lanes are zeroed, and pulled no more."""
+        if base not in unreached:
+            try:
+                _read_mixed_rows(base, rows, size, offset, span, width,
+                                 buf, off, remote=far)
+                return
+            except OSError:
+                unreached.add(base)
+        buf[:, off:off + width] = 0
 
     def tally(v: _VolState, sid: int, offset: int, bad: int,
               first: int) -> None:
@@ -1237,6 +1368,8 @@ def fleet_verify_ec_files(base_names: Sequence[str], backend: str = "auto",
     def counted(v: _VolState, offset: int, out) -> None:
         """On v's writer lane: the device's counts of one span, each
         [4, blocks]."""
+        if v.base in unreached:
+            return
         with _StageTimer("verify", vol=os.path.basename(v.base)):
             counts, firsts = out
             for sid in results[v.base].parity_checked:
@@ -1253,15 +1386,22 @@ def fleet_verify_ec_files(base_names: Sequence[str], backend: str = "auto",
     # thousands of syscalls per volume once large fleets shrink the span
     parity_files: Dict[Tuple[str, int], object] = {}
 
-    def compare(v: _VolState, offset: int, out: np.ndarray) -> None:
+    def compare(v: _VolState, offset: int, out: np.ndarray,
+                stripe: Optional[np.ndarray] = None) -> None:
         """On v's writer lane: a host codec's parity [4, span] against
-        the stored one."""
+        the stored one, read from its file or, for a remote row, from
+        the span's `stripe` of the staging buffer."""
+        if v.base in unreached:
+            return
         with _StageTimer("verify", vol=os.path.basename(v.base)):
             valid = min(span, v.dat_size - offset)
             for sid in results[v.base].parity_checked:
-                f = parity_files[v.base, sid]
-                f.seek(offset)
-                stored = np.frombuffer(f.read(valid), dtype=np.uint8)
+                f = parity_files.get((v.base, sid))
+                if f is None:
+                    stored = stripe[sid, :valid]
+                else:
+                    f.seek(offset)
+                    stored = np.frombuffer(f.read(valid), dtype=np.uint8)
                 diff = np.nonzero(
                     out[sid - DATA_SHARDS, :len(stored)] != stored)[0]
                 # a truncated parity shard lacks bytes the data shards say
@@ -1272,11 +1412,21 @@ def fleet_verify_ec_files(base_names: Sequence[str], backend: str = "auto",
 
     check = counted if on_device else compare
 
-    def handed(v: _VolState, offset: int, rows: np.ndarray, out) -> None:
+    def holds(v: _VolState) -> bool:
+        """Whether v's lane closures read the staging buffer."""
+        return on_span is not None or bool(far_parity(v))
+
+    def handed(v: _VolState, offset: int, stripe: np.ndarray, out) -> None:
         """On v's writer lane: the span's compare, then its data rows
         to on_span."""
-        check(v, offset, out)
-        on_span(v.base, offset, rows.shape[1], rows)
+        if v.base in unreached:
+            return
+        if on_device:
+            counted(v, offset, out)
+        else:
+            compare(v, offset, out, stripe)
+        if on_span is not None:
+            on_span(v.base, offset, stripe.shape[1], stripe[:DATA_SHARDS])
 
     # batches are flushed in the plan's order: a volume's spans in turn
     offsets = [itertools.count(0, span) for _ in vols]
@@ -1288,31 +1438,33 @@ def fleet_verify_ec_files(base_names: Sequence[str], backend: str = "auto",
             handle = dispatcher.verify_lanes(batch.buf, cuts, release) \
                 if on_device else \
                 dispatcher.encode_lanes(batch.buf, cuts, release)
-        if on_span is None:
-            pipe.submit(handle, [
-                (v.tag, functools.partial(check, v, next(offsets[v.tag])))
-                for v, _, _ in batch.spans])
-            return
         pipe.submit(handle, [
             (v.tag, _then_release(functools.partial(
                 handed, v, next(offsets[v.tag]),
-                batch.buf[:DATA_SHARDS, off:off + valid]), release))
+                batch.buf[:, off:off + valid]), release)) if holds(v) else
+            (v.tag, functools.partial(check, v, next(offsets[v.tag])))
             for v, off, valid in batch.spans])
 
     with contextlib.ExitStack() as opened:
         if not on_device:
             for v in vols:  # one by one: a failed open() closes the earlier
                 for sid in results[v.base].parity_checked:
+                    if sid in far_parity(v):
+                        continue
                     parity_files[v.base, sid] = opened.enter_context(
                         open(shard_file_name(v.base, sid), "rb"))
         # a verify's buffer is free once its dispatch's input has been
         # read (the retire thread has the counts, or every host codec's
         # parity): what the lanes then read is not in it — unless each
-        # span's data rows go to on_span there, after the compare
+        # span's data rows go to on_span there, after the compare, or a
+        # host codec compares a remote parity row there
         _staged_pass("verify", dict(volumes=len(vols), backend=backend),
                      backend, device, encoders, readers, depth,
                      lanes=per_batch * width, per_buffer=per_batch,
                      plan=plan(), flush=flush,
-                     refs=(lambda batch: 1) if on_span is None
-                     else (lambda batch: 1 + len(batch.spans)))
+                     refs=lambda batch: 1 + sum(
+                         holds(v) for v, _, _ in batch.spans))
+    for base in unreached:
+        results[base] = VerifyResult(missing=results[base].missing,
+                                     verified=False)
     return results

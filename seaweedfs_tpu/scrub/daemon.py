@@ -5,10 +5,23 @@ IO — until start() is called (the scrub-disabled perf gate in
 tests/test_perf_gates.py holds the server to that). A pass walks every
 mounted volume and EC volume:
 
+  0. one verifier an EC volume: its shards are spread over servers, and
+     in a pass that every holder runs (volume.scrub fanned out to all
+     servers, or every server's scheduled pass) exactly one holder
+     verifies it — of its holders in url order, the (vid mod their
+     number)-th, or the first after it that answers — from the master's
+     shard locations (`peers`), which every holder reads alike. The
+     other holders defer the volume (SeaweedFS_scrub_ec_volumes_total
+     {role}): they neither verify it nor sweep its needles, and their
+     report does not name it. A pass started on this server alone
+     (`alone`: volume.scrub -node) defers nothing. The verifier reads
+     the rows it lacks from their holders; a volume one of whose rows
+     no holder gives is declined (not held against its parity), and
+     the pass goes on;
   1. needle sweep per normal volume (scanner.scan_volume), corrupt
      needles re-fetched from replicas (planner.repair_needle);
-  2. ONE fused stripe verify across ALL the server's EC volumes
-     (fleet_verify_ec_files), on the encode and rebuild passes' loop
+  2. ONE fused stripe verify across ALL the EC volumes the server
+     verifies (fleet_verify_ec_files), on the encode and rebuild passes' loop
      and staging buffers. On the jax backend the stored parity goes to
      the device beside the data shards, [14, lanes] a dispatch, and
      counts come back, not parity; host codecs compare on the writer
@@ -17,13 +30,15 @@ mounted volume and EC volume:
      in the data-shard bytes the verify has staged, on the volume's
      writer lane, so a pass reads its data shards once;
   3. the rest of the EC needle sweep: the copied path for a needle the
-     staged check did not find clean, and a read from local shards of
-     every needle it could not see (a volume the verify declined, a
-     tiered shard, a needle over more than two spans, the mesh path),
-     localizing bad data shards by exclusion;
+     staged check did not find clean, and a read (from the shard or its
+     holder) of every needle it could not see (a volume the verify
+     declined, a tiered shard, a needle over more than two spans, the
+     mesh path), localizing bad data shards by exclusion;
   4. per damaged EC volume: classify -> quarantine .corrupt ->
      fleet rebuild -> re-verify (a data repair un-contaminates the
-     parity evidence; round two condemns genuinely bad parity).
+     parity evidence; round two condemns genuinely bad parity). A
+     condemned shard another server holds is rebuilt BY that holder,
+     in place (repair_held, asked through `peers`): shards never move.
 
 Each step is a phase of SeaweedFS_scrub_phase_seconds (scan, scan_ec,
 verify, repair, reverify) and, while the span ring is on, a span
@@ -40,11 +55,12 @@ Prometheus families.
 
 from __future__ import annotations
 
+import functools
 import threading
 import time
 import weakref
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional, Sequence
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 from seaweedfs_tpu.ec import fleet
 from seaweedfs_tpu.scrub import planner, scanner
@@ -52,14 +68,18 @@ from seaweedfs_tpu.scrub.phases import phase
 from seaweedfs_tpu.stats import trace
 from seaweedfs_tpu.stats.metrics import (
     ScrubCorruptionsFoundCounter, ScrubCorruptionsRepairedCounter,
-    ScrubNeedlesVerifiedCounter, ScrubPassSecondsHistogram,
-    ScrubScanLagGauge, ScrubScannedBytesCounter,
+    ScrubEcVolumesCounter, ScrubNeedlesVerifiedCounter,
+    ScrubPassSecondsHistogram, ScrubScanLagGauge, ScrubScannedBytesCounter,
     ScrubStripesVerifiedCounter, ScrubUnrecoverableCounter)
 from seaweedfs_tpu.storage.store import Store
 from seaweedfs_tpu.util import wlog
 from seaweedfs_tpu.util.throttler import Throttler
 
 log = wlog.logger("scrub")
+
+# children resolved once at import: labels() takes a lock per call
+_EC_VOLUMES = {role: ScrubEcVolumesCounter.labels(role)
+               for role in ("verified", "deferred")}
 
 
 @dataclass
@@ -106,7 +126,8 @@ class ScrubDaemon:
                  replica_fetch: Optional[Callable] = None,
                  export_lag: bool = True,
                  on_repair: Optional[Callable[[int], None]] = None,
-                 mesh_cfg: Optional[dict] = None):
+                 mesh_cfg: Optional[dict] = None,
+                 peers=None):
         self.store = store
         self.mbps = mbps
         self.backend = backend
@@ -121,6 +142,14 @@ class ScrubDaemon:
         # volume server hangs read-cache invalidation here so a repair
         # can never serve a pre-repair cached blob
         self.on_repair = on_repair
+        # the other servers, as the volume server sees them: `url` (this
+        # server's), `locations(vid)` ({shard id: [holder urls]}, asked
+        # of the master afresh), `alive(url)`, `fill(vid, sid, offset,
+        # view)` (a row from a holder, fleet.RemoteRows.fill), `reader(
+        # vid)` (bytes of an interval from a holder, or None) and
+        # `repair(url, vid, sids)` (a holder rebuilds condemned shards
+        # it holds). None: every EC volume here is this server's alone
+        self.peers = peers
         self._lock = threading.Lock()
         self._thread: Optional[threading.Thread] = None  # guarded_by(self._lock)
         self._resume = threading.Event()
@@ -136,6 +165,7 @@ class ScrubDaemon:
         # BEFORE the thread spawns — happens-before via Thread.start)
         self._pass_volume_ids: Optional[List[int]] = None  # guarded_by(self._lock, writes)
         self._pass_mbps: Optional[float] = None  # guarded_by(self._lock, writes)
+        self._pass_alone = False  # guarded_by(self._lock, writes)
         self._state = "idle"  # guarded_by(self._lock, writes)
         self.current_volume_id = 0
         self.passes_completed = 0
@@ -165,11 +195,12 @@ class ScrubDaemon:
 
     def start(self, volume_ids: Optional[Sequence[int]] = None,
               throttle_mbps: Optional[float] = None,
-              full: bool = False) -> bool:
+              full: bool = False, alone: bool = False) -> bool:
         """Begin a pass (or resume a paused one). Returns False when a
         pass is already running un-paused — and in that case changes
         NOTHING (a rejected start must not retarget or re-budget the
-        running work)."""
+        running work). `alone`: no other holder runs this pass, so it
+        verifies every EC volume held here and defers none."""
         with self._lock:
             if self._stopping:
                 return False
@@ -188,6 +219,7 @@ class ScrubDaemon:
             self._pass_volume_ids = list(volume_ids) if volume_ids else None
             self._pass_mbps = throttle_mbps \
                 if throttle_mbps is not None and throttle_mbps > 0 else None
+            self._pass_alone = alone
             self._state = "running"
             self._resume.set()
             # lint: thread-ok(scrub daemon paced by -scrubMBps; no request context)
@@ -277,16 +309,18 @@ class ScrubDaemon:
         # durability, never the other way). No-op context when QoS off.
         from seaweedfs_tpu import qos
         vids, mbps = self._pass_volume_ids, self._pass_mbps
+        alone = self._pass_alone
         res = None                   # a pass that ended, not yet said so
         while not self._stopping:
             try:
                 with qos.internal_context():
-                    res, exc = self._sweep(vids, mbps)
+                    res, exc = self._sweep(vids, mbps, alone)
             except ScrubPaused:
                 return
             if exc is not None:
                 log.error("scrub pass failed", exc_info=exc)
-            vids, mbps = None, None  # later passes: whole store, server budget
+            # later passes: whole store, server budget, every server's
+            vids, mbps, alone = None, None, False
             if self.interval_s <= 0:
                 break
             self._pass_ended(res)
@@ -305,16 +339,17 @@ class ScrubDaemon:
             self._pass_ended(res)
 
     def run_pass(self, volume_ids: Optional[Sequence[int]] = None,
-                 mbps: Optional[float] = None) -> PassResult:
+                 mbps: Optional[float] = None,
+                 alone: bool = False) -> PassResult:
         """One synchronous sweep over everything mounted locally."""
-        res, exc = self._sweep(volume_ids, mbps)
+        res, exc = self._sweep(volume_ids, mbps, alone)
         self._pass_ended(res)
         if exc is not None:
             raise exc
         return res
 
     def _sweep(self, volume_ids: Optional[Sequence[int]],
-               mbps: Optional[float]):
+               mbps: Optional[float], alone: bool = False):
         """-> (the pass's report, what it raised or None). A pass that
         raised ended too, as failed: whoever waits for it learns so
         instead of reading an idle daemon. A stop (ScrubPaused) is no
@@ -327,7 +362,7 @@ class ScrubDaemon:
         try:
             with trace.span("scrub.pass"):
                 self._scan_volumes(res, throttler, only)
-                self._scan_ec_volumes(res, throttler, only)
+                self._scan_ec_volumes(res, throttler, only, alone)
         except ScrubPaused:
             raise
         except Exception as e:
@@ -394,13 +429,86 @@ class ScrubDaemon:
                             f"volume {vid}: needle {n.id:x} corrupt, "
                             f"no healthy replica")
 
-    def _scan_ec_volumes(self, res: PassResult, throttler, only) -> None:
+    # -- EC volumes: who verifies what -------------------------------------
+
+    def _holders(self, vid: int, ecv) -> Dict[int, List[str]]:
+        """{shard id: holder urls} of one EC volume, this server's own
+        shards among them whatever the master has heard yet."""
+        me = self.peers.url if self.peers is not None else ""
+        locs = {} if self.peers is None else {
+            sid: list(urls) for sid, urls in self.peers.locations(vid).items()}
+        for sid in ecv.shards:
+            if me not in locs.setdefault(sid, []):
+                locs[sid].append(me)
+        return locs
+
+    def _verifier(self, vid: int, locs: Dict[int, List[str]], alive) -> str:
+        """The one holder that verifies volume `vid` this pass, by the
+        rule every holder applies to the same locations: of the holders
+        in url order, the (vid mod their number)-th, or the first after
+        it that answers. Consecutive ids on one set of holders take
+        each holder in turn."""
+        me = self.peers.url if self.peers is not None else ""
+        holders = sorted({url for urls in locs.values() for url in urls})
+        for j in range(len(holders)):
+            url = holders[(vid + j) % len(holders)]
+            if url == me or alive(url):
+                return url
+        return me
+
+    def _remote_rows(self, vid: int, ecv, locs: Dict[int, List[str]]
+                     ) -> Optional[fleet.RemoteRows]:
+        """The rows of the shards of `vid` that other servers hold."""
+        if self.peers is None:
+            return None
+        far = frozenset(sid for sid, urls in locs.items()
+                        if sid not in ecv.shards
+                        and any(u != self.peers.url for u in urls))
+        if not far:
+            return None
+        return fleet.RemoteRows(vid, far, ecv.shard_size,
+                                functools.partial(self.peers.fill, vid))
+
+    def _mine(self, ecvs, alone: bool
+              ) -> Tuple[list, Dict[int, Dict[int, List[str]]]]:
+        """The EC volumes this server verifies this pass, with their
+        holders; the rest are deferred to their verifier (none when the
+        pass runs here `alone`)."""
+        answered: Dict[str, bool] = {}   # one probe a peer a pass
+
+        def alive(url: str) -> bool:
+            if url not in answered:
+                answered[url] = self.peers.alive(url)
+            return answered[url]
+
+        me = self.peers.url if self.peers is not None else ""
+        mine, holders = [], {}
+        for vid, ecv in ecvs:
+            locs = self._holders(vid, ecv)
+            if not alone and self._verifier(vid, locs, alive) != me:
+                _EC_VOLUMES["deferred"].inc()
+                continue
+            _EC_VOLUMES["verified"].inc()
+            mine.append((vid, ecv))
+            holders[vid] = locs
+        return mine, holders
+
+    def _scan_ec_volumes(self, res: PassResult, throttler, only,
+                         alone: bool = False) -> None:
         ecvs = [(vid, ecv)
                 for loc in self.store.locations
                 for vid, ecv in list(loc.ec_volumes.items())
                 if only is None or vid in only]
         if not ecvs:
             return
+        ecvs, holders = self._mine(ecvs, alone)
+        if not ecvs:
+            return
+        remote = {}
+        for vid, ecv in ecvs:
+            rows = self._remote_rows(vid, ecv, holders[vid])
+            if rows is not None:
+                remote[ecv.base_name] = rows
         # each volume's .ecx walked once, at the pass's start: its
         # needles are checked in the bytes the stripe verify reads
         staged: Dict[str, scanner.StagedSweep] = {}
@@ -410,13 +518,13 @@ class ScrubDaemon:
                 staged[ecv.base_name] = scanner.StagedSweep(ecv)
             res.verdicts.setdefault(vid, VolumeVerdict())
             res.ec_volumes += 1
-        # ONE fused verify across the whole fleet of local EC volumes:
-        # spans from every volume share RS dispatches (the tentpole)
+        # ONE fused verify across the whole fleet of EC volumes this
+        # server verifies: spans from every volume share RS dispatches
         self._checkpoint(0)
         by_base = {ecv.base_name: (vid, ecv) for vid, ecv in ecvs}
         with phase("verify", volumes=len(by_base)):
             mesh_fleet = fleet.mesh_fleet_or_none() \
-                if self.mesh_cfg is not None else None
+                if self.mesh_cfg is not None and not remote else None
             if mesh_fleet is not None:
                 verified = mesh_fleet.pod_verify_ec_files(
                     list(by_base), backend=self.backend,
@@ -424,16 +532,18 @@ class ScrubDaemon:
             else:
                 verified = fleet.fleet_verify_ec_files(
                     list(by_base), backend=self.backend,
-                    throttler=throttler,
+                    throttler=throttler, remote=remote,
                     on_span=lambda base, offset, valid, rows:
                     staged[base].take(offset, valid, rows))
         # the needle sweep's rest: the copied path for what the staged
-        # check did not accept, the shard files for what it never saw
+        # check did not accept, the shard files (or their holders) for
+        # what it never saw
         damages: Dict[int, planner.EcDamage] = {}
         for vid, ecv in ecvs:
             self._checkpoint(vid)
             scan = scanner.scan_ec_volume_needles(
-                ecv, throttler=throttler, staged=staged[ecv.base_name])
+                ecv, throttler=throttler, staged=staged[ecv.base_name],
+                fetch=self._fetch(vid))
             res.bytes_scanned += scan.bytes_scanned
             res.needles_verified += scan.needles_verified
             ScrubScannedBytesCounter.inc(scan.bytes_scanned)
@@ -447,27 +557,55 @@ class ScrubDaemon:
                 base=ecv.base_name, bad_data=scan.bad_data_shards)
         for base, vr in verified.items():
             vid, ecv = by_base[base]
+            if not vr.verified and base in remote:
+                log.warning("ec volume %d: not held against its parity "
+                            "this pass: a row's holder did not answer", vid)
             d = damages[vid]
             d.parity_mismatch = dict(vr.parity_mismatch)
             d.first_mismatch = dict(vr.first_mismatch)
             d.parity_checked = list(vr.parity_checked)
             # a shard file gone while this server still has it mounted
-            # is local damage; shards living on OTHER servers are just
-            # absent here and theirs to scrub
+            # is local damage; a shard no server holds is ec.rebuild's
             d.missing = [s for s in vr.missing if s in ecv.shards]
             res.stripes_verified += vr.spans
             res.bytes_scanned += vr.bytes_verified
             ScrubStripesVerifiedCounter.inc(vr.spans)
             ScrubScannedBytesCounter.inc(vr.bytes_verified)
         for vid, ecv in ecvs:
-            self._repair_ec(vid, ecv, damages[vid], res)
+            self._repair_ec(vid, ecv, damages[vid], res, holders[vid],
+                            remote.get(ecv.base_name))
+
+    def _fetch(self, vid: int) -> Optional[Callable]:
+        return None if self.peers is None else self.peers.reader(vid)
+
+    def repair_held(self, vid: int, sids: Sequence[int]) -> List[int]:
+        """A verifier's call (VolumeEcShardsRebuild with
+        condemned_shard_ids): shards `sids` of EC volume `vid`, held
+        here, failed its pass. Quarantine and rebuild them where they
+        are, from the survivors here and, pulled, other holders'; the
+        rebuilt shard ids. Raises KeyError for a shard not held here."""
+        ecv = self.store.find_ec_volume(vid)
+        if ecv is None or not set(sids) <= set(ecv.shards):
+            raise KeyError(f"ec volume {vid}: shards {sorted(sids)} "
+                           "are not all held here")
+        rows = self._remote_rows(vid, ecv, self._holders(vid, ecv))
+        rebuilt = planner.repair_ec_volume(
+            ecv.base_name, list(sids), backend=self.backend,
+            unmount=ecv.unmount_shard, remount=ecv.mount_shard,
+            remote=rows)
+        if self.on_repair is not None:
+            self.on_repair(vid)
+        return rebuilt
 
     def _repair_ec(self, vid: int, ecv, damage: planner.EcDamage,
-                   res: PassResult, rounds: int = 2) -> None:
+                   res: PassResult, locs: Dict[int, List[str]],
+                   remote: Optional[fleet.RemoteRows],
+                   rounds: int = 2) -> None:
         """Classify -> quarantine -> rebuild -> re-verify, at most
         `rounds` times (round one clears data damage, whose recomputed
         parity contaminated round-zero evidence; round two then judges
-        the parity shards on their own)."""
+        the parity shards on their own). A condemned shard another
+        server holds is rebuilt by that holder."""
         for _ in range(rounds):
             checked = set(damage.parity_checked)
             if not damage.bad_data and len(checked) >= 2 and \
@@ -483,7 +621,7 @@ class ScrubDaemon:
                 damage.bad_data |= planner.localize_from_parity_deltas(
                     damage.base, sorted(set(damage.first_mismatch
                                             .values())),
-                    parity_ids=sorted(checked))
+                    parity_ids=sorted(checked), fetch=self._fetch(vid))
             verdict, bad = planner.classify_ec_damage(damage)
             if verdict == "clean":
                 return
@@ -505,10 +643,19 @@ class ScrubDaemon:
             self._checkpoint(vid)
             log.warning("ec volume %d: rebuilding %s shard(s) %s",
                         vid, verdict, bad)
+            here = [s for s in bad if s in ecv.shards or not any(
+                u != self.peers.url for u in locs.get(s, ()))] \
+                if self.peers is not None else bad
             try:
-                planner.repair_ec_volume(
-                    damage.base, bad, backend=self.backend,
-                    unmount=ecv.unmount_shard, remount=ecv.mount_shard)
+                if here:
+                    planner.repair_ec_volume(
+                        damage.base, here, backend=self.backend,
+                        unmount=ecv.unmount_shard, remount=ecv.mount_shard,
+                        remote=remote)
+                for url, sids in _by_holder(
+                        [s for s in bad if s not in here], locs,
+                        self.peers.url if self.peers else "").items():
+                    self.peers.repair(url, vid, sids)
             except (ValueError, OSError) as e:
                 res.unrecoverable += len(bad)
                 res.verdicts[vid].unrecoverable += len(bad)
@@ -520,7 +667,8 @@ class ScrubDaemon:
             if self.on_repair is not None:
                 self.on_repair(vid)
             vr = planner.verify_ec_repair(damage.base,
-                                          backend=self.backend)
+                                          backend=self.backend,
+                                          remote=remote)
             res.stripes_verified += vr.spans
             ScrubStripesVerifiedCounter.inc(vr.spans)
             for k in kinds:
@@ -541,3 +689,15 @@ class ScrubDaemon:
                 return
         log.error("ec volume %d: still inconsistent after %d repair "
                   "rounds", vid, rounds)
+
+
+def _by_holder(sids: Sequence[int], locs: Dict[int, List[str]],
+               me: str) -> Dict[str, List[int]]:
+    """{holder url: the shards of `sids` it holds}, this server left
+    out: each copy of a condemned shard is rebuilt where it lies."""
+    out: Dict[str, List[int]] = {}
+    for sid in sids:
+        for url in locs.get(sid, ()):
+            if url != me:
+                out.setdefault(url, []).append(sid)
+    return out

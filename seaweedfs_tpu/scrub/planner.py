@@ -22,7 +22,10 @@ Classification uses both evidence streams the scanner produces:
 Repair is quarantine-then-rebuild: each condemned .ecNN is renamed to
 .ecNN.corrupt (never deleted — the operator's forensic copy), then the
 fleet rebuild path reconstructs it from the surviving >=10 shards,
-byte-identical to the original. RS(10,4) caps repairable damage at 4
+byte-identical to the original. It runs on the server that holds the
+condemned shard: survivors it lacks are pulled from their holders
+(`remote`), so a shard never moves. The volume's verifier asks a holder
+for it over gRPC (VolumeEcShardsRebuild with condemned_shard_ids). RS(10,4) caps repairable damage at 4
 shards per volume; anything past that is unrecoverable and stays
 quarantine-free so whatever still reads, still reads.
 
@@ -59,21 +62,30 @@ class EcDamage:
     missing: List[int] = field(default_factory=list)
 
 
-def _shard_byte(base: str, sid: int, offset: int) -> int:
-    with open(shard_file_name(base, sid), "rb") as f:
-        f.seek(offset)
-        b = f.read(1)
+def _shard_byte(base: str, sid: int, offset: int,
+                fetch: Optional[Callable] = None) -> int:
+    """One byte of shard `sid`: from its file here, else from a holder
+    (`fetch(sid, offset, length) -> bytes or None`)."""
+    path = shard_file_name(base, sid)
+    if fetch is not None and not os.path.exists(path):
+        b = fetch(sid, offset, 1)
+    else:
+        with open(path, "rb") as f:
+            f.seek(offset)
+            b = f.read(1)
     return b[0] if b else 0
 
 
 def localize_from_parity_deltas(base: str, offsets,
-                                parity_ids=None) -> Set[int]:
+                                parity_ids=None,
+                                fetch: Optional[Callable] = None) -> Set[int]:
     """Syndrome probe: name the single corrupt DATA shard behind an
     every-parity-stream mismatch (see module docstring). Probes one
     byte column per offset over the parity shards actually present
     (`parity_ids`, default all four); returns the data shards
     unambiguously identified (empty = not single-shard damage — a
-    single parity row can never discriminate, so it returns nothing)."""
+    single parity row can never discriminate, so it returns nothing).
+    A shard with no file here is read through `fetch`."""
     from seaweedfs_tpu.ops import gf256
     from seaweedfs_tpu.ops.rs_code import coding_matrix
     m = coding_matrix()
@@ -81,13 +93,14 @@ def localize_from_parity_deltas(base: str, offsets,
         list(range(DATA_SHARDS, TOTAL_SHARDS))
     culprits: Set[int] = set()
     for offset in offsets:
-        col = [_shard_byte(base, d, offset) for d in range(DATA_SHARDS)]
+        col = [_shard_byte(base, d, offset, fetch)
+               for d in range(DATA_SHARDS)]
         delta = {}
         for sid in parity_ids:
             acc = 0
             for d in range(DATA_SHARDS):
                 acc ^= int(gf256.GF_MUL_TABLE[m[sid, d], col[d]])
-            delta[sid] = acc ^ _shard_byte(base, sid, offset)
+            delta[sid] = acc ^ _shard_byte(base, sid, offset, fetch)
         if not all(delta.values()):
             continue  # some parity agrees here: not a data-shard error
         cands = [d for d in range(DATA_SHARDS)
@@ -143,13 +156,16 @@ def repair_ec_volume(base: str, bad_shards: List[int],
                      backend: str = "auto",
                      unmount: Optional[Callable[[int], None]] = None,
                      remount: Optional[Callable[[int], None]] = None,
+                     remote: Optional["fleet.RemoteRows"] = None,
                      ) -> List[int]:
     """Quarantine + rebuild the condemned shards of one volume.
 
     unmount/remount hooks let the store drop its open fd on a shard
     before the rename and re-open it after the rebuild (a mounted
-    EcVolumeShard holds the old inode otherwise). Returns the rebuilt
-    shard ids; raises if fewer than DATA_SHARDS survivors remain.
+    EcVolumeShard holds the old inode otherwise). `remote`: the rows of
+    the shards other servers hold, for survivors this server lacks.
+    Returns the rebuilt shard ids; raises if fewer than DATA_SHARDS
+    survivors remain.
     """
     with phase("repair", base=os.path.basename(base),
                shards=len(bad_shards)):
@@ -158,19 +174,25 @@ def repair_ec_volume(base: str, bad_shards: List[int],
                 unmount(sid)
             quarantine_shard(base, sid)
         rebuilt = fleet.fleet_rebuild_ec_files(
-            [base], backend=backend, wanted=list(bad_shards))[base]
+            [base], backend=backend, wanted=list(bad_shards),
+            remote=None if remote is None else {base: remote})[base]
         for sid in bad_shards:
             if remount is not None:
                 remount(sid)
         return rebuilt
 
 
-def verify_ec_repair(base: str, backend: str = "auto") -> "fleet.VerifyResult":
+def verify_ec_repair(base: str, backend: str = "auto",
+                     remote: Optional["fleet.RemoteRows"] = None
+                     ) -> "fleet.VerifyResult":
     """Post-repair stripe verify of ONE volume (the daemon's second
     evidence round: after a data-shard rebuild, any parity mismatch
-    that remains is genuine parity damage)."""
+    that remains is genuine parity damage), its remote rows from their
+    holders."""
     with phase("reverify", base=os.path.basename(base)):
-        return fleet.fleet_verify_ec_files([base], backend=backend)[base]
+        return fleet.fleet_verify_ec_files(
+            [base], backend=backend,
+            remote=None if remote is None else {base: remote})[base]
 
 
 def repair_needle(v: Volume, corrupt: Needle,

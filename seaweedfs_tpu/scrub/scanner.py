@@ -7,14 +7,16 @@ Two surfaces, matching the two on-disk formats:
     are vacuum's business, not corruption) gets its masked CRC
     recomputed via the same `verify_needle_integrity` predicate the
     SEAWEED_VERIFY_READS read gate uses.
-  * EC volumes — needle-level: in a full pass the daemon walks each
+  * EC volumes — needle-level, on the one server that verifies the
+    volume (scrub/daemon.py): in a full pass the daemon walks each
     volume's .ecx once (StagedSweep) and checks every live needle in
     the bytes the pass's stripe verify has already read into its
-    staging buffers, on the volume's writer lane; what that cannot see
-    (a volume the verify declines, a tiered shard, a needle spread
-    over more than two spans) is read from LOCAL shards into a
-    worker's reused buffer and checked where it lies, several needles
-    in flight. Both check a record with ONE test (header, attributes,
+    staging buffers — rows of this server's shards and rows pulled
+    from their holders alike — on the volume's writer lane; what that
+    cannot see (a volume the verify declines, a tiered shard, a needle
+    spread over more than two spans) is read into a worker's reused
+    buffer, from the local shard or from its holder (`fetch`), and
+    checked where it lies, several needles in flight. Both check a record with ONE test (header, attributes,
     CRC chained over its pieces); a needle that does not come out
     clean is re-assembled and parsed the copied way, which alone calls
     it corrupt, and a failure is localized to the data shard at fault
@@ -37,11 +39,11 @@ import time
 from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
-from typing import Iterator, List, Optional, Sequence, Set, Tuple
+from typing import Callable, Iterator, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
-from seaweedfs_tpu.ec.ec_volume import EcVolume
+from seaweedfs_tpu.ec.ec_volume import EcShardNotFound, EcVolume
 from seaweedfs_tpu.ec.fleet import FLEET_READERS
 from seaweedfs_tpu.ec.shard_bits import DATA_SHARDS
 from seaweedfs_tpu.native import rs_native
@@ -103,13 +105,12 @@ def scan_volume(v: Volume, throttler=None) -> NeedleScan:
 
 @dataclass
 class EcNeedleScan:
-    """One EC volume's needle sweep over local shards."""
+    """One EC volume's needle sweep."""
 
     bytes_scanned: int = 0
     needles_verified: int = 0
     corrupt: List[int] = field(default_factory=list)   # needle ids
     bad_data_shards: Set[int] = field(default_factory=set)
-    skipped_remote: int = 0   # needles touching non-local shards
 
 
 # Needles in flight in the EC sweep. Its workers read shard files, so
@@ -142,11 +143,10 @@ _SOURCE = {s: ScrubNeedleSourceCounter.labels(s)
 _Needle = Tuple[int, int, int, List[Tuple[int, int, int]], int]
 
 
-def _live_needles(ecv: EcVolume, version: int,
-                  res: EcNeedleScan) -> Iterator[_Needle]:
-    """The .ecx walk: every live needle whose intervals all lie on
-    shards this server holds, in .ecx order; `res.skipped_remote`
-    counts the others (their holder scrubs them)."""
+def _live_needles(ecv: EcVolume, version: int) -> Iterator[_Needle]:
+    """The .ecx walk: every live needle, in .ecx order, wherever its
+    intervals lie — the server that verifies a volume checks all of
+    its needles, and the other holders none."""
     for i in range(len(ecv._keys)):
         try:
             _, size, intervals = ecv.locate_index(i, version)
@@ -154,9 +154,6 @@ def _live_needles(ecv: EcVolume, version: int,
             continue  # tombstoned (now, or under the sweep)
         placed = [iv.to_shard_and_offset(ecv.large_block, ecv.small_block)
                   + (iv.size,) for iv in intervals]
-        if any(sid not in ecv.shards for sid, _, _ in placed):
-            res.skipped_remote += 1
-            continue
         yield i, int(ecv._keys[i]), size, placed, \
             sum(ln for _, _, ln in placed)
 
@@ -164,17 +161,20 @@ def _live_needles(ecv: EcVolume, version: int,
 def scan_ec_volume_needles(ecv: EcVolume, version: int = 3,
                            throttler=None,
                            rs: Optional[ReedSolomon] = None,
-                           staged: Optional["StagedSweep"] = None
+                           staged: Optional["StagedSweep"] = None,
+                           fetch: Optional[Callable] = None
                            ) -> EcNeedleScan:
-    """CRC-verify every live .ecx needle assembled from LOCAL shards.
+    """CRC-verify every live .ecx needle of the volume.
 
     A needle is read into a worker's buffer and checked where it lies
     (`_EcSweep`); one that is not clean there goes through the copied
     path, and a CRC failure is localized by single-shard exclusion:
     re-read the needle with each touched data shard treated as missing
     (RS reconstruction from the other shards); the exclusion that makes
-    the CRC pass names the corrupt shard. Needles spanning shards this
-    server doesn't hold are skipped (their holder scrubs them).
+    the CRC pass names the corrupt shard. An interval on a shard this
+    server does not hold is read from its holder, `fetch(sid, offset,
+    length) -> bytes or None` (the server's remote shard reader), and
+    is reconstructed from the other shards where that gives nothing.
 
     `staged`: the volume's needles as the pass's stripe verify saw them
     (StagedSweep, after the verify). Those it found clean are counted
@@ -182,7 +182,7 @@ def scan_ec_volume_needles(ecv: EcVolume, version: int = 3,
     copied path, and only the needles it never saw are read here.
     """
     res = EcNeedleScan()
-    sweep = _EcSweep(ecv, version, rs)
+    sweep = _EcSweep(ecv, version, rs, fetch)
     found: List[Tuple[int, int, Set[int]]] = []  # (.ecx position, key, bad)
     inflight: deque = deque()
     pool = ThreadPoolExecutor(max_workers=SWEEP_WORKERS,
@@ -197,9 +197,8 @@ def scan_ec_volume_needles(ecv: EcVolume, version: int = 3,
 
     with phase("scan_ec", vid=ecv.volume_id):
         if staged is None:
-            needles = _live_needles(ecv, version, res)
+            needles = _live_needles(ecv, version)
         else:
-            res.skipped_remote = staged.skipped_remote
             res.needles_verified = staged.clean + len(staged.unclean)
             res.bytes_scanned = staged.clean_bytes
             for i, key, _, placed, length in staged.unclean:
@@ -248,10 +247,11 @@ class _EcSweep:
     to `check_copied`, which alone decides corrupt and names shards."""
 
     def __init__(self, ecv: EcVolume, version: int,
-                 rs: Optional[ReedSolomon]):
+                 rs: Optional[ReedSolomon], fetch: Optional[Callable]):
         self.ecv = ecv
         self.version = version
         self.rs = rs
+        self.fetch = fetch
         self._mine = threading.local()   # .view: this thread's buffer
         self._oversize = threading.Lock()
 
@@ -270,7 +270,8 @@ class _EcSweep:
         t0 = time.perf_counter()
         with self._oversize if length > _BUFFER_CAP \
                 else contextlib.nullcontext():
-            got, bad = check_copied(self.ecv, placed, self.version, self.rs)
+            got, bad = check_copied(self.ecv, placed, self.version, self.rs,
+                                    self.fetch)
         _STEP["copied"].observe(time.perf_counter() - t0)
         _NEEDLES["copied"].inc()
         return got, bad
@@ -289,14 +290,21 @@ class _EcSweep:
     def _clean_in_place(self, key: int, size: int, placed,
                         length: int) -> bool:
         shards = self.ecv.shards
-        if length > _BUFFER_CAP or \
-                any(shards[sid].is_remote for sid, _, _ in placed):
+        if length > _BUFFER_CAP or any(_tiered(self.ecv, sid)
+                                       for sid, _, _ in placed):
             return False
         rec = self._buffer(length)
         t0 = time.perf_counter()
         at = 0
         for sid, off, ln in placed:
-            if shards[sid].read_into(off, rec[at:at + ln]) != ln:
+            shard = shards.get(sid)
+            if shard is None:
+                piece = _piece(self.ecv, sid, off, ln, self.fetch, self.rs)
+                rec[at:at + len(piece)] = piece
+                got = len(piece)
+            else:
+                got = shard.read_into(off, rec[at:at + ln])
+            if got != ln:
                 break  # a truncated shard: the copied path's evidence
             at += ln
         t1 = time.perf_counter()
@@ -334,19 +342,17 @@ class StagedSweep:
 
     def __init__(self, ecv: EcVolume, version: int = 3):
         self.version = version
-        walk = EcNeedleScan()
         self._later: List[_Needle] = []   # for the disk sweep
         ahead = []
-        for needle in _live_needles(ecv, version, walk):
+        for needle in _live_needles(ecv, version):
             placed = needle[3]
-            if any(ecv.shards[sid].is_remote for sid, _, _ in placed):
+            if any(_tiered(ecv, sid) for sid, _, _ in placed):
                 self._later.append(needle)
                 continue
             ahead.append((min(off for _, off, _ in placed),
                           max(off + ln for _, off, ln in placed), needle))
         ahead.sort(key=lambda a: a[0])
         self._ahead = deque(ahead)   # (lowest offset, end, needle)
-        self.skipped_remote = walk.skipped_remote
         # needles across the last span's end: the pieces before it,
         # views of _spare
         self._carry: List[Tuple[_Needle, list]] = []
@@ -488,22 +494,51 @@ def _record_is_clean(pieces, key: int, size: int, version: int) -> bool:
     return size == 0 or n.checksum == mask_crc(_crc(pieces, data_at, meta_at))
 
 
+def _tiered(ecv: EcVolume, sid: int) -> bool:
+    """Shard `sid` is this server's and lives in a cloud backend."""
+    shard = ecv.shards.get(sid)
+    return shard is not None and shard.is_remote
+
+
+def _piece(ecv: EcVolume, sid: int, off: int, ln: int,
+           fetch: Optional[Callable], rs: Optional[ReedSolomon]) -> bytes:
+    """Bytes [off, off + ln) of shard `sid`: this server's copy, else
+    its holder's (`fetch`), else reconstructed from the shards that
+    can be reached; b"" when none of that gives them."""
+    shard = ecv.shards.get(sid)
+    if shard is not None:
+        return shard.read_at(off, ln)
+    data = fetch(sid, off, ln) if fetch is not None else None
+    if data is not None and len(data) == ln:
+        return data
+    try:
+        return ecv._recover_interval(sid, off, ln, fetch, rs or ReedSolomon())
+    except EcShardNotFound:
+        return b""
+
+
 def check_copied(ecv: EcVolume, placed, version: int,
-                 rs: Optional[ReedSolomon]) -> Tuple[int, Optional[Set[int]]]:
+                 rs: Optional[ReedSolomon],
+                 fetch: Optional[Callable] = None
+                 ) -> Tuple[int, Optional[Set[int]]]:
     """One needle the copied way — read_at, join, Needle.from_bytes —
     which decides corrupt: (bytes read, None if the record parses and
-    its CRC holds, else the data shards `_localize_bad_shard` names)."""
-    blob = b"".join(ecv.shards[sid].read_at(off, ln)
+    its CRC holds, else the data shards `_localize_bad_shard` names).
+    An interval on a shard another server holds is read through
+    `fetch`."""
+    blob = b"".join(_piece(ecv, sid, off, ln, fetch, rs)
                     for sid, off, ln in placed)
     try:
         Needle.from_bytes(blob, version)
     except PARSE_ERRORS:  # CRC mismatch or a torn/short parse
-        return len(blob), _localize_bad_shard(ecv, placed, version, rs)
+        return len(blob), _localize_bad_shard(ecv, placed, version, rs,
+                                              fetch)
     return len(blob), None
 
 
 def _localize_bad_shard(ecv: EcVolume, placed, version: int,
-                        rs: Optional[ReedSolomon]) -> Set[int]:
+                        rs: Optional[ReedSolomon],
+                        fetch: Optional[Callable] = None) -> Set[int]:
     """Which single data shard, if excluded and RS-reconstructed,
     makes the needle's CRC pass? Empty set = not localizable this way
     (multi-shard damage, or parity too corrupt to reconstruct with) —
@@ -516,9 +551,9 @@ def _localize_bad_shard(ecv: EcVolume, placed, version: int,
             for sid, off, ln in placed:
                 if sid == suspect:
                     pieces.append(ecv._recover_interval(sid, off, ln,
-                                                        None, rs))
+                                                        fetch, rs))
                 else:
-                    pieces.append(ecv.shards[sid].read_at(off, ln))
+                    pieces.append(_piece(ecv, sid, off, ln, fetch, rs))
             Needle.from_bytes(b"".join(pieces), version)
         except PARSE_ERRORS:
             continue

@@ -67,6 +67,13 @@ EC_REFRESH_FULL_S = 37 * 60.0
 # forgets the vid immediately (same invalidate-on-failure discipline as
 # _ec_locations)
 REPLICA_REFRESH_S = 30.0
+# A read of shard bytes from their holder (VolumeEcShardRead) fails after
+# this: a hung peer must fail the row, not pin its caller
+REMOTE_SHARD_READ_S = 15.0
+# A scrub verifier's probe of an earlier holder, and its wait for a
+# holder's rebuild of a condemned shard
+SCRUB_PROBE_S = 10.0
+SCRUB_REPAIR_S = 300.0
 
 
 class VolumeServer:
@@ -155,7 +162,7 @@ class VolumeServer:
             interval_s=scrub_interval_s,
             replica_fetch=self._fetch_needle_from_replica,
             on_repair=self._invalidate_volume_cache,
-            mesh_cfg=self.ec_mesh_cfg)
+            mesh_cfg=self.ec_mesh_cfg, peers=_ScrubPeers(self))
         self.scrub_interval_s = scrub_interval_s
         self.volume_size_limit = 30 << 30
         self.compact_states: Dict[int, vacuum_mod.CompactState] = {}
@@ -809,6 +816,17 @@ class VolumeServer:
         return volume_server_pb2.VolumeEcShardsGenerateResponse()
 
     def VolumeEcShardsRebuild(self, request, context):
+        if request.condemned_shard_ids:
+            # a scrub verifier's: rebuild shards held here in place
+            try:
+                sids = self.scrub.repair_held(
+                    request.volume_id, list(request.condemned_shard_ids))
+            except KeyError as e:
+                context.abort(grpc.StatusCode.NOT_FOUND, str(e))
+            except (ValueError, OSError) as e:
+                context.abort(grpc.StatusCode.FAILED_PRECONDITION, str(e))
+            return volume_server_pb2.VolumeEcShardsRebuildResponse(
+                rebuilt_shard_ids=sids)
         # every listed volume (an old client's volume_id alone: a list
         # of one) in one pass of the fleet scheduler, fused by
         # (present, missing) signature
@@ -916,7 +934,7 @@ class VolumeServer:
         started = self.scrub.start(
             volume_ids=list(request.volume_ids) or None,
             throttle_mbps=request.throttle_mbps or None,
-            full=request.full)
+            full=request.full, alone=request.alone)
         return volume_server_pb2.VolumeScrubStartResponse(
             started=started, passes_ended=ended)
 
@@ -1087,7 +1105,7 @@ class VolumeServer:
 
     def _make_remote_reader(self, vid: int):
         def fetch_shard(url: str, shard_id: int, offset: int,
-                        length: int) -> bytes:
+                        length: int, exact: bool) -> bytes:
             # deadline: a hung peer must fail this row, not pin
             # the caller (the decode fleet's dispatcher rides
             # this reader — head-of-line blocking is fatal there)
@@ -1096,14 +1114,17 @@ class VolumeServer:
                           volume_server_pb2.VolumeEcShardReadRequest(
                               volume_id=vid, shard_id=shard_id,
                               offset=offset, size=length),
-                          timeout=15)]
+                          timeout=REMOTE_SHARD_READ_S)]
             data = b"".join(chunks)
-            if len(data) != length:
+            if exact and len(data) != length:
                 raise EcShardNotFound(
                     f"vid {vid} shard {shard_id}: short remote read")
             return data
 
-        def remote_reader(shard_id: int, offset: int, length: int):
+        def remote_reader(shard_id: int, offset: int, length: int,
+                          exact: bool = True):
+            """`length` bytes of the shard from a holder, or None; with
+            `exact` False, fewer where the holder's shard ends."""
             urls = _breaker.sort_candidates(
                 [u for u in self._ec_shard_locations(vid).get(shard_id, [])
                  if u != self.url])
@@ -1114,7 +1135,8 @@ class VolumeServer:
                 try:
                     return self.hedger.fetch(
                         [lambda u=u: fetch_shard(u, shard_id, offset,
-                                                 length) for u in urls])
+                                                 length, exact)
+                         for u in urls])
                 except _deadline.DeadlineExceeded:
                     # a spent budget is the CLIENT's state, not
                     # evidence against these shard locations — never
@@ -1125,7 +1147,8 @@ class VolumeServer:
             else:
                 for url in urls:
                     try:
-                        return fetch_shard(url, shard_id, offset, length)
+                        return fetch_shard(url, shard_id, offset, length,
+                                           exact)
                     except (grpc.RpcError, EcShardNotFound):
                         continue
             if tried:
@@ -1141,10 +1164,13 @@ class VolumeServer:
             return None
         return remote_reader
 
-    def _ec_shard_locations(self, vid: int) -> Dict[int, List[str]]:
+    def _ec_shard_locations(self, vid: int, fresh: bool = False
+                            ) -> Dict[int, List[str]]:
+        """{shard id: holder urls} of an EC volume, from the master,
+        cached as the reference does; `fresh`: ask the master now."""
         now = time.monotonic()
         cached = self._ec_locations.get(vid)
-        if cached is not None:
+        if cached is not None and not fresh:
             ts, locs = cached
             n_known = len(locs)
             if n_known >= TOTAL_SHARDS:
@@ -1304,6 +1330,55 @@ class VolumeServer:
 
 
 # -- HTTP layer ---------------------------------------------------------------
+
+
+class _ScrubPeers:
+    """What the scrub daemon sees of the other volume servers, through
+    this one (scrub/daemon.ScrubDaemon `peers`)."""
+
+    def __init__(self, vs: VolumeServer):
+        self._vs = vs
+
+    @property
+    def url(self) -> str:
+        return self._vs.url
+
+    def locations(self, vid: int) -> Dict[int, List[str]]:
+        # asked afresh: every holder applies the verifier rule to the
+        # master's present view
+        return self._vs._ec_shard_locations(vid, fresh=True)
+
+    def alive(self, url: str) -> bool:
+        try:
+            volume_stub(url).VolumeServerStatus(
+                volume_server_pb2.VolumeServerStatusRequest(),
+                timeout=SCRUB_PROBE_S)
+        except grpc.RpcError:
+            return False
+        return True
+
+    def fill(self, vid: int, sid: int, offset: int, view: memoryview) -> int:
+        """Shard `sid`'s bytes from `offset` on into `view`, from a
+        holder; the count, short only where the holder's shard ends."""
+        data = self.reader(vid)(sid, offset, len(view), exact=False)
+        if data is None:
+            raise ConnectionError(
+                f"vid {vid} shard {sid}: no holder gave the bytes")
+        view[:len(data)] = data
+        return len(data)
+
+    def reader(self, vid: int):
+        return self._vs._make_remote_reader(vid)
+
+    def repair(self, url: str, vid: int, sids: List[int]) -> None:
+        try:
+            volume_stub(url).VolumeEcShardsRebuild(
+                volume_server_pb2.VolumeEcShardsRebuildRequest(
+                    volume_id=vid, condemned_shard_ids=sids),
+                timeout=SCRUB_REPAIR_S)
+        except grpc.RpcError as e:
+            raise OSError(f"{url}: rebuild of volume {vid} shards "
+                          f"{sids}: {e}") from e
 
 
 def parse_byte_range(rng: str, total: int) -> Tuple[int, int]:

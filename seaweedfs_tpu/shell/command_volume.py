@@ -471,7 +471,9 @@ def volume_scrub(env: CommandEnv, argv: List[str], out) -> None:
     """Control the per-server scrub daemon (seaweedfs_tpu/scrub/):
     start a verification pass (the default), pause a running one, or
     print each server's ledger. Without -node the action fans out to
-    every volume server in the topology. With -wait a start returns
+    every volume server in the topology, and each EC volume is verified
+    by one of its holders; a pass started with -node verifies every EC
+    volume that server holds. With -wait a start returns
     when the pass has ended on every server, and prints each server's
     ledger and one line a volume the pass covered: `clean`, what it
     rebuilt or rewrote, or `unrecoverable`; a pass that failed fails
@@ -515,7 +517,7 @@ def volume_scrub(env: CommandEnv, argv: List[str], out) -> None:
                 volume_server_pb2.VolumeScrubStartRequest(
                     volume_ids=[args.volumeId] if args.volumeId else [],
                     throttle_mbps=args.throttleMBps,
-                    full=args.full))
+                    full=args.full, alone=bool(args.node)))
             out.write(f"{url}: "
                       f"{'scrub started' if r.started else 'scrub already running'}\n")
             started.append((url, r.passes_ended))

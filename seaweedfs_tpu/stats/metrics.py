@@ -382,6 +382,21 @@ FleetVerifyBytesCounter = REGISTRY.counter(
     "SeaweedFS_fleet_verify_bytes_total",
     "data-shard bytes whose stripes a fleet verify pass held against "
     "the stored parity", ("where",))
+# Shard bytes a verify pass's readers put into its staging buffers, by
+# where they came from: `local` (the server's own shard files) or
+# `remote` (a row of a shard another server holds, filled from that
+# holder's VolumeEcShardRead stream). Counted as planned, a span's
+# rows at a time.
+FleetVerifyRowBytesCounter = REGISTRY.counter(
+    "SeaweedFS_fleet_verify_row_bytes_total",
+    "shard bytes a fleet verify pass staged, by source", ("source",))
+# Thread-seconds of remote row fills (span fleet.read.remote, under the
+# reader's fleet.read): one observation a row of a span pulled from its
+# holder, by a verify or a rebuild pass.
+FleetRemoteReadSecondsHistogram = REGISTRY.histogram(
+    "SeaweedFS_fleet_remote_read_seconds",
+    "fleet scheduler: seconds to fill one staging row from a shard "
+    "another server holds")
 FleetWriterBacklogGauge = REGISTRY.gauge(
     "SeaweedFS_fleet_writer_lane_backlog",
     "writes queued on one writer lane", ("lane",))
@@ -493,6 +508,13 @@ ScrubNeedleSourceCounter = REGISTRY.counter(
     "SeaweedFS_scrub_needle_source_total",
     "EC needles swept by where their first check found their bytes",
     ("source",))
+# EC volumes a pass met, by this server's role for each: `verified`
+# (the one holder that verifies the volume this pass: its stripes and
+# every needle) or `deferred` (another holder is the verifier).
+ScrubEcVolumesCounter = REGISTRY.counter(
+    "SeaweedFS_scrub_ec_volumes_total",
+    "EC volumes a scrub pass verified or deferred to another holder",
+    ("role",))
 ScrubScanLagGauge = REGISTRY.gauge(
     "SeaweedFS_scrub_scan_lag_seconds",
     "seconds since the last completed scrub pass")

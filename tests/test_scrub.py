@@ -118,9 +118,6 @@ def _copied_scan(ecv, version=3):
             placed = _placed(ecv, key)
         except NeedleError:
             continue
-        if any(sid not in ecv.shards for sid, _, _ in placed):
-            res.skipped_remote += 1
-            continue
         got, bad = scanner.check_copied(ecv, placed, version, None)
         res.bytes_scanned += got
         res.needles_verified += 1
@@ -354,7 +351,7 @@ class TestScanner:
         moved = {c: n - counted[c] for c, n in _needle_counts().items()}
         want = _copied_scan(ecv)
         assert got == want
-        assert got.needles_verified + got.skipped_remote == live
+        assert got.needles_verified == live
         assert moved == {"in_place": got.needles_verified - want_copied,
                          "copied": want_copied}
 
@@ -449,8 +446,9 @@ class TestScanner:
 
     def test_a_volume_the_verify_declines_is_swept_from_disk(self, store):
         """A data shard gone: the stripe verify declines the volume, no
-        span of it is staged, and its sweep reads every needle from the
-        local shards, as before; the other volume's are not read."""
+        span of it is staged, and its sweep reads every needle from
+        disk — those with bytes on the lost shard reconstructed from the
+        others; the other volume's are not read."""
         _make_ec(store, 2)
         base = _make_mixed_ec(store, 3)
         ecv = store.find_ec_volume(3)
@@ -459,11 +457,12 @@ class TestScanner:
         sources = _source_counts()
         res = ScrubDaemon(store, backend="numpy").run_pass()
         found = {s: n - sources[s] for s, n in _source_counts().items()}
+        live = sum(1 for z in ecv._sizes if z >= 0)
         local = sum(1 for nid in ecv._keys.tolist()
                     if all(sid != 3 for sid, _, _ in _placed(ecv, nid)))
-        assert 0 < local < len(ecv._keys)
-        assert found == {"staged": 25, "carried": 0, "read": local}
-        assert res.needles_verified == 25 + local
+        assert 0 < local < live
+        assert found == {"staged": 25, "carried": 0, "read": live}
+        assert res.needles_verified == 25 + live
         assert res.corruptions_found == 0
 
     @pytest.mark.parametrize("backend", ["numpy", "jax"])
